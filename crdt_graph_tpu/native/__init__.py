@@ -9,6 +9,7 @@ is unavailable, so the native layer is an accelerator, never a requirement.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -22,32 +23,43 @@ from ..codec.packed import (DEFAULT_MAX_DEPTH, PackedOps, _bucket,
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "fastcodec.cpp")
-_SO = os.path.join(_HERE, "_fastcodec.so")
 
 _mod = None
 _build_error: Optional[str] = None
 
 
-def _try_import():
+def _so_path() -> str:
+    """The extension built from ``_SRC``'s CONTENT: its name carries the
+    source's hash, so a copied tree (whose mtimes say nothing) never
+    loads a ``.so`` built from other source."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"_fastcodec.{digest}.so")
+
+
+def _try_import(so: str):
     """Load the extension by file path — no sys.path mutation."""
     global _mod
     spec = importlib.util.spec_from_file_location(
-        "crdt_graph_tpu.native._fastcodec", _SO)
+        "crdt_graph_tpu.native._fastcodec", so)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     _mod = mod
     return _mod
 
 
-def _build() -> None:
+def _build(so: str) -> None:
     include = sysconfig.get_path("include")
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-           f"-I{include}", _SRC, "-o", _SO]
+           f"-I{include}", _SRC, "-o", tmp]
     if os.environ.get("GRAFT_NATIVE_ASAN"):
         # memory-safety fuzz build (scripts/fuzz_native.py re-execs with
         # libasan LD_PRELOADed so the sanitized .so loads into CPython)
         cmd[1:1] = ["-fsanitize=address", "-fno-omit-frame-pointer", "-g"]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
+    # atomic: a concurrent builder (test workers) never sees half a file
+    os.replace(tmp, so)
 
 
 def load(rebuild: bool = False):
@@ -58,10 +70,10 @@ def load(rebuild: bool = False):
     if _build_error is not None and not rebuild:
         return None
     try:
-        if rebuild or not os.path.exists(_SO) or \
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            _build()
-        return _try_import()
+        so = _so_path()
+        if rebuild or not os.path.exists(so):
+            _build(so)
+        return _try_import(so)
     except Exception as e:   # missing compiler, sandboxed fs, …
         _build_error = str(e)
         return None

@@ -33,24 +33,21 @@ tests/test_fused_resolve.py.
 """
 from __future__ import annotations
 
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import jaxcompat
+from . import pallas_mode
 
 TILE = 1024        # output rows per grid step
 SPAN = TILE + 128  # plane rows DMA'd per tile (starts floor to 128)
 MAX_LANES = 512    # widest limb plane worth staging through VMEM
-
-try:  # pallas is TPU/Mosaic; keep importable on bare CPU builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+KERNEL_NAME = "fused_resolve_rows"     # plane_rows
+KERNEL_NAME2 = "fused_resolve_rows2"   # plane_rows2
 
 
 def _lax_rows(plane: jax.Array, idx: jax.Array) -> jax.Array:
@@ -58,53 +55,75 @@ def _lax_rows(plane: jax.Array, idx: jax.Array) -> jax.Array:
     return plane[idx]
 
 
-if HAVE_PALLAS:
-    def _kernel(starts_ref, idx_ref, plane_hbm, out_ref, scratch, sem):
-        i = pl.program_id(0)
-        # starts arrive pre-divided by 128: multiplying back inside the
-        # kernel lets Mosaic PROVE the dynamic DMA offset is aligned
-        # (an opaque prefetched scalar fails that proof) — the same
-        # trick as mono_gather, applied to the SUBLANE (row) dim
-        r0 = starts_ref[i] * 128
-        copy = pltpu.make_async_copy(
-            plane_hbm.at[pl.ds(r0, SPAN), :], scratch, sem)
-        copy.start()
-        copy.wait()
-        off = idx_ref[...] - r0            # [TILE] ∈ [0, SPAN)
-        onehot = (off[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
-        vals_f = scratch[...].astype(jnp.float32)          # [SPAN, C4]
-        # full-f32 MXU passes: every operand is a 16-bit limb < 2^16,
-        # products/sums stay below 2^24 — exact (mono_gather's guard
-        # bound, satisfied by construction here)
-        out = jax.lax.dot_general(
-            onehot, vals_f, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)           # [TILE, C4]
-        out_ref[...] = out.astype(jnp.int32)
+def _limb_plane(plane: jax.Array, span: int) -> jax.Array:
+    """int64 ``[R, C]`` → i32 ``[R + pad, 4C → 128k]`` 16-bit limbs
+    (exact in f32).  Lanes pad to a multiple of 128: Mosaic refuses a
+    DMA window whose lane width is not tile-aligned, and XLA's HBM
+    layout pads the minor dim to 128 anyway, so the padding costs no
+    memory.  Rows pad so the last tile's ``span``-window DMA stays in
+    bounds."""
+    r, c = plane.shape
+    limbs = jnp.stack(
+        [((plane >> s) & 0xFFFF).astype(jnp.int32)
+         for s in (0, 16, 32, 48)], axis=-1).reshape(r, 4 * c)
+    return jnp.pad(limbs, ((0, span + (-r) % 8), (0, -(4 * c) % 128)))
 
-    def _pallas_call(limbs_pad, idx_pad, starts, c4, tiles, interpret):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(tiles,),
-            in_specs=[
-                # idx rides 1-D (TILE,) blocks — lane dim multiple of
-                # 128, matching XLA's s32[N] layout (mono_gather note)
-                pl.BlockSpec((TILE,), lambda i, starts: (i,)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((SPAN, c4), jnp.int32),
-                pltpu.SemaphoreType.DMA,
-            ],
-        )
-        return pl.pallas_call(
-            _kernel,
-            out_shape=jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
-            grid_spec=grid_spec,
-            interpret=interpret,
-        )(starts, idx_pad, limbs_pad)
+
+def _repack(o: jax.Array, t: int, c: int) -> jax.Array:
+    """Kernel output limbs ``[T_pad, 128k]`` → int64 ``[t, c]``."""
+    v = o[:t, :4 * c].astype(jnp.int64).reshape(t, c, 4)
+    return (v[:, :, 0] | (v[:, :, 1] << 16) |
+            (v[:, :, 2] << 32) | (v[:, :, 3] << 48))
+
+
+def _kernel(starts_ref, idx_ref, plane_hbm, out_ref, scratch, sem):
+    i = pl.program_id(0)
+    # starts arrive pre-divided by 128: multiplying back inside the
+    # kernel lets Mosaic PROVE the dynamic DMA offset is aligned
+    # (an opaque prefetched scalar fails that proof) — the same
+    # trick as mono_gather, applied to the SUBLANE (row) dim
+    r0 = starts_ref[i] * 128
+    copy = pltpu.make_async_copy(
+        plane_hbm.at[pl.ds(r0, SPAN), :], scratch, sem)
+    copy.start()
+    copy.wait()
+    off = idx_ref[...] - r0            # [TILE] ∈ [0, SPAN)
+    onehot = (off[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
+    vals_f = scratch[...].astype(jnp.float32)          # [SPAN, C4]
+    # full-f32 MXU passes: every operand is a 16-bit limb < 2^16,
+    # products/sums stay below 2^24 — exact (mono_gather's guard
+    # bound, satisfied by construction here)
+    out = jax.lax.dot_general(
+        onehot, vals_f, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)           # [TILE, C4]
+    out_ref[...] = out.astype(jnp.int32)
+
+
+def _pallas_call(limbs_pad, idx_pad, starts, c4, tiles, interpret):
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(tiles,),
+        in_specs=[
+            # idx rides 1-D (TILE,) blocks — lane dim multiple of
+            # 128, matching XLA's s32[N] layout (mono_gather note)
+            pl.BlockSpec((TILE,), lambda i, starts: (i,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((SPAN, c4), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+        ],
+    )
+    return pl.pallas_call(
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(starts, idx_pad, limbs_pad)
 
 
 HOP_J = 1152       # 2nd-hop locality bound, rounded to a lane tile
@@ -127,80 +146,80 @@ def halo_window_ok(idx: jax.Array, w: int, halo: int,
     return jnp.all(exempt | in_win)
 
 
-if HAVE_PALLAS:
-    def _kernel2(starts_ref, idx_ref, plane_hbm, out_ref, out2_ref,
-                 scr_a, scr_b, sem_a, sem_b, *, hop_col, r_rows):
-        """Two dependent bounded-span row gathers in one VMEM pass: the
-        first hop exactly as :func:`_kernel`; the hop index then
-        re-packs from the gathered row's ``hop_col`` limbs IN REGISTER
-        and drives a second one-hot contraction over a wider window
-        whose start derives from the first (the HOP_J locality bound
-        the wrapper verified)."""
-        i = pl.program_id(0)
-        r0 = starts_ref[i] * 128
-        rb = starts_ref[pl.num_programs(0) + i] * 128
-        ca = pltpu.make_async_copy(
-            plane_hbm.at[pl.ds(r0, SPAN), :], scr_a, sem_a)
-        ca.start()
-        cb = pltpu.make_async_copy(
-            plane_hbm.at[pl.ds(rb, SPAN2), :], scr_b, sem_b)
-        cb.start()
-        ca.wait()
-        off = idx_ref[...] - r0            # [TILE] ∈ [0, SPAN)
-        onehot = (off[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
-        vals_a = scr_a[...].astype(jnp.float32)        # [SPAN, C4]
-        g = jax.lax.dot_general(
-            onehot, vals_a, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
-        out_ref[...] = g
-        # hop index from the gathered row's limb pair (i32 bit-exact:
-        # the hop column stores row indices < 2^31, or -1)
-        hop = (g[:, 4 * hop_col + 1] << 16) | g[:, 4 * hop_col]
-        valid2 = hop >= 0
-        i2 = jnp.clip(hop, jnp.int32(0), jnp.int32(r_rows - 1))
-        off2 = i2 - rb
-        cb.wait()
-        onehot2 = ((off2[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (TILE, SPAN2), 1)) &
-            valid2[:, None]).astype(jnp.float32)
-        vals_b = scr_b[...].astype(jnp.float32)        # [SPAN2, C4]
-        out2_ref[...] = jax.lax.dot_general(
-            onehot2, vals_b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+def _kernel2(starts_ref, idx_ref, plane_hbm, out_ref, out2_ref,
+             scr_a, scr_b, sem_a, sem_b, *, hop_col, r_rows):
+    """Two dependent bounded-span row gathers in one VMEM pass: the
+    first hop exactly as :func:`_kernel`; the hop index then
+    re-packs from the gathered row's ``hop_col`` limbs IN REGISTER
+    and drives a second one-hot contraction over a wider window
+    whose start derives from the first (the HOP_J locality bound
+    the wrapper verified)."""
+    i = pl.program_id(0)
+    r0 = starts_ref[i] * 128
+    rb = starts_ref[pl.num_programs(0) + i] * 128
+    ca = pltpu.make_async_copy(
+        plane_hbm.at[pl.ds(r0, SPAN), :], scr_a, sem_a)
+    ca.start()
+    cb = pltpu.make_async_copy(
+        plane_hbm.at[pl.ds(rb, SPAN2), :], scr_b, sem_b)
+    cb.start()
+    ca.wait()
+    off = idx_ref[...] - r0            # [TILE] ∈ [0, SPAN)
+    onehot = (off[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
+    vals_a = scr_a[...].astype(jnp.float32)        # [SPAN, C4]
+    g = jax.lax.dot_general(
+        onehot, vals_a, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+    out_ref[...] = g
+    # hop index from the gathered row's limb pair (i32 bit-exact:
+    # the hop column stores row indices < 2^31, or -1)
+    hop = (g[:, 4 * hop_col + 1] << 16) | g[:, 4 * hop_col]
+    valid2 = hop >= 0
+    i2 = jnp.clip(hop, jnp.int32(0), jnp.int32(r_rows - 1))
+    off2 = i2 - rb
+    cb.wait()
+    onehot2 = ((off2[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (TILE, SPAN2), 1)) &
+        valid2[:, None]).astype(jnp.float32)
+    vals_b = scr_b[...].astype(jnp.float32)        # [SPAN2, C4]
+    out2_ref[...] = jax.lax.dot_general(
+        onehot2, vals_b, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
 
-    def _pallas_call2(limbs_pad, idx_pad, starts2, c4, tiles, hop_col,
-                      r_rows, interpret):
-        import functools
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(tiles,),
-            in_specs=[
-                pl.BlockSpec((TILE,), lambda i, starts: (i,)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=[
-                pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
-                pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((SPAN, c4), jnp.int32),
-                pltpu.VMEM((SPAN2, c4), jnp.int32),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_kernel2, hop_col=hop_col, r_rows=r_rows),
-            out_shape=[
-                jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
-                jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
-            ],
-            grid_spec=grid_spec,
-            interpret=interpret,
-        )(starts2, idx_pad, limbs_pad)
+
+def _pallas_call2(limbs_pad, idx_pad, starts2, c4, tiles, hop_col,
+                  r_rows, interpret):
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(tiles,),
+        in_specs=[
+            pl.BlockSpec((TILE,), lambda i, starts: (i,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
+            pl.BlockSpec((TILE, c4), lambda i, starts: (i, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((SPAN, c4), jnp.int32),
+            pltpu.VMEM((SPAN2, c4), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA,
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel2, hop_col=hop_col, r_rows=r_rows),
+        out_shape=[
+            jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
+            jax.ShapeDtypeStruct((tiles * TILE, c4), jnp.int32),
+        ],
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=KERNEL_NAME2,
+    )(starts2, idx_pad, limbs_pad)
 
 
 def _lax_rows2(plane: jax.Array, idx: jax.Array, hop_col: int):
@@ -233,14 +252,8 @@ def plane_rows2(plane: jax.Array, idx: jax.Array, hop_col: int,
     r, c = plane.shape
     t = idx.shape[0]
     c4 = 4 * c
-    if use_pallas and os.environ.get("GRAFT_PALLAS_INTERPRET") == "1":
-        interpret = True
-    if use_pallas is None:
-        use_pallas = HAVE_PALLAS and not interpret and \
-            jax.default_backend() == "tpu" and \
-            os.environ.get("GRAFT_NO_PALLAS") != "1"
-    if not (use_pallas or interpret) or not HAVE_PALLAS or \
-            plane.dtype != jnp.int64 or c4 > MAX_LANES:
+    run, interpret = pallas_mode.select(use_pallas, interpret)
+    if not run or plane.dtype != jnp.int64 or c4 > MAX_LANES:
         return _lax_rows2(plane, idx, hop_col)
     from ..utils import hostenv
     if not hostenv.flag_on("GRAFT_FUSED_SUPEROP"):
@@ -249,8 +262,7 @@ def plane_rows2(plane: jax.Array, idx: jax.Array, hop_col: int,
         # gather — so a superop problem on a live chip can be disabled
         # without also giving up the host winner-election/parent_row
         # resolution (GRAFT_FUSED_RESOLVE gates those)
-        g = plane_rows(plane, idx, use_pallas=use_pallas,
-                       interpret=interpret)
+        g = plane_rows(plane, idx, use_pallas=True, interpret=interpret)
         hop = g[:, hop_col]
         i2 = jnp.clip(hop, 0, r - 1).astype(jnp.int32)
         g2 = jnp.where((hop >= 0)[:, None], _lax_rows(plane, i2), 0)
@@ -274,20 +286,12 @@ def plane_rows2(plane: jax.Array, idx: jax.Array, hop_col: int,
     both = jnp.concatenate([starts, starts2])
 
     def _pallas2(_):
-        limbs = jnp.stack(
-            [((plane >> s) & 0xFFFF).astype(jnp.int32)
-             for s in (0, 16, 32, 48)], axis=-1).reshape(r, c4)
-        row_pad = SPAN2 + (-r) % 8
-        limbs_pad = jnp.pad(limbs, ((0, row_pad), (0, 0)))
-        with jaxcompat.enable_x64(False):
-            o1, o2 = _pallas_call2(limbs_pad, idx_pad, both, c4, tiles,
-                                   hop_col, r, interpret)
-
-        def _repack(o):
-            v = o[:t].astype(jnp.int64).reshape(t, c, 4)
-            return (v[:, :, 0] | (v[:, :, 1] << 16) |
-                    (v[:, :, 2] << 32) | (v[:, :, 3] << 48))
-        return _repack(o1), _repack(o2)
+        limbs_pad = _limb_plane(plane, SPAN2)
+        with jax.enable_x64(False):
+            o1, o2 = _pallas_call2(limbs_pad, idx_pad, both,
+                                   limbs_pad.shape[1], tiles, hop_col, r,
+                                   interpret)
+        return _repack(o1, t, c), _repack(o2, t, c)
 
     def _hop1(_):
         # hop locality violated (or fragmented): first hop keeps its
@@ -312,14 +316,8 @@ def plane_rows(plane: jax.Array, idx: jax.Array,
     r, c = plane.shape
     t = idx.shape[0]
     c4 = 4 * c
-    if use_pallas and os.environ.get("GRAFT_PALLAS_INTERPRET") == "1":
-        interpret = True
-    if use_pallas is None:
-        use_pallas = HAVE_PALLAS and not interpret and \
-            jax.default_backend() == "tpu" and \
-            os.environ.get("GRAFT_NO_PALLAS") != "1"
-    if not (use_pallas or interpret) or not HAVE_PALLAS or \
-            plane.dtype != jnp.int64 or c4 > MAX_LANES:
+    run, interpret = pallas_mode.select(use_pallas, interpret)
+    if not run or plane.dtype != jnp.int64 or c4 > MAX_LANES:
         return _lax_rows(plane, idx)
 
     tiles = -(-t // TILE)
@@ -335,22 +333,14 @@ def plane_rows(plane: jax.Array, idx: jax.Array,
                       jnp.int32(SPAN))
 
     def _pallas(_):
-        # int64 → four 16-bit limbs per column (exact in f32); rows
-        # padded so the last tile's SPAN-window DMA stays in bounds
-        limbs = jnp.stack(
-            [((plane >> s) & 0xFFFF).astype(jnp.int32)
-             for s in (0, 16, 32, 48)], axis=-1).reshape(r, c4)
-        row_pad = SPAN + (-r) % 8
-        limbs_pad = jnp.pad(limbs, ((0, row_pad), (0, 0)))
+        limbs_pad = _limb_plane(plane, SPAN)
         # every operand is explicit i32; trace the call under x32 like
         # mono_gather (x64 tracing emits grid ops Mosaic cannot
         # legalize) — caller dtypes are unaffected
-        with jaxcompat.enable_x64(False):
-            out = _pallas_call(limbs_pad, idx_pad, starts, c4, tiles,
-                               interpret)
-        o = out[:t].astype(jnp.int64).reshape(t, c, 4)
-        return (o[:, :, 0] | (o[:, :, 1] << 16) |
-                (o[:, :, 2] << 32) | (o[:, :, 3] << 48))
+        with jax.enable_x64(False):
+            out = _pallas_call(limbs_pad, idx_pad, starts,
+                               limbs_pad.shape[1], tiles, interpret)
+        return _repack(out, t, c)
 
     return lax.cond(span_ok, _pallas, lambda _: _lax_rows(plane, idx),
                     None)

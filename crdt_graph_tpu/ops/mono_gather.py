@@ -21,24 +21,18 @@ reference semantics; CPU/interpret tests pin kernel == fallback.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import jaxcompat
+from . import pallas_mode
 
 TILE = 1024      # tokens per grid step (matches XLA's s32[N] T(1024) layout)
 SPAN = TILE + 128  # values rows DMA'd per tile (≥ TILE+128: aligned starts)
 
-try:  # pallas is TPU/Mosaic; keep importable on bare CPU builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
-
 F24 = 1 << 24    # float32 exact-integer bound
+KERNEL_NAME = "mono_gather"
 
 
 def _lax_gather(values: jax.Array, rid: jax.Array) -> jax.Array:
@@ -46,57 +40,58 @@ def _lax_gather(values: jax.Array, rid: jax.Array) -> jax.Array:
     return values[:, rid]
 
 
-if HAVE_PALLAS:
-    def _kernel(starts_ref, rid_ref, vals_hbm, out_ref, scratch, sem):
-        i = pl.program_id(0)
-        # starts arrive pre-divided by 128: multiplying back inside the
-        # kernel lets Mosaic PROVE the dynamic DMA offset is 128-aligned
-        # (an opaque prefetched scalar fails that proof)
-        r0 = starts_ref[i] * 128
-        copy = pltpu.make_async_copy(
-            vals_hbm.at[:, pl.ds(r0, SPAN)], scratch, sem)
-        copy.start()
-        copy.wait()
-        # off[t] = rid[t] - r0 ∈ [0, TILE+127] (starts floor to a lane
-        # tile), which is why SPAN must be ≥ TILE+128; one-hot over SPAN
-        off = rid_ref[...] - r0
-        onehot = (off[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
-        vals_f = scratch[...].astype(jnp.float32)          # [V, SPAN]
-        # HIGHEST: the MXU's default bf16 passes truncate >2^8-magnitude
-        # ints (caught live: 91158 read back as 91136); full-f32 passes
-        # keep every product/sum exact below 2^24
-        out = jax.lax.dot_general(
-            vals_f, onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)           # [V, TILE]
-        out_ref[...] = out.astype(jnp.int32)
+def _kernel(starts_ref, rid_ref, vals_hbm, out_ref, scratch, sem):
+    i = pl.program_id(0)
+    # starts arrive pre-divided by 128: multiplying back inside the
+    # kernel lets Mosaic PROVE the dynamic DMA offset is 128-aligned
+    # (an opaque prefetched scalar fails that proof)
+    r0 = starts_ref[i] * 128
+    copy = pltpu.make_async_copy(
+        vals_hbm.at[:, pl.ds(r0, SPAN)], scratch, sem)
+    copy.start()
+    copy.wait()
+    # off[t] = rid[t] - r0 ∈ [0, TILE+127] (starts floor to a lane
+    # tile), which is why SPAN must be ≥ TILE+128; one-hot over SPAN
+    off = rid_ref[...] - r0
+    onehot = (off[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (TILE, SPAN), 1)).astype(jnp.float32)
+    vals_f = scratch[...].astype(jnp.float32)          # [V, SPAN]
+    # HIGHEST: the MXU's default bf16 passes truncate >2^8-magnitude
+    # ints (caught live: 91158 read back as 91136); full-f32 passes
+    # keep every product/sum exact below 2^24
+    out = jax.lax.dot_general(
+        vals_f, onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)           # [V, TILE]
+    out_ref[...] = out.astype(jnp.int32)
 
-    def _pallas_call(vals_pad, rid_pad, starts, v8, tiles, interpret):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(tiles,),
-            in_specs=[
-                # rid rides 1-D: a (TILE,) block keeps the lane dim at a
-                # multiple of 128 and matches XLA's s32[N] T(1024) layout
-                # (Mosaic requires last-two block dims ≡ 0 mod (8, 128) or
-                # full — a (1, TILE) block over [tiles, TILE] fails on
-                # real TPU lowering; caught on first live-chip run)
-                pl.BlockSpec((TILE,), lambda i, starts: (i,)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((v8, TILE), lambda i, starts: (0, i)),
-            scratch_shapes=[
-                pltpu.VMEM((v8, SPAN), jnp.int32),
-                pltpu.SemaphoreType.DMA,
-            ],
-        )
-        return pl.pallas_call(
-            _kernel,
-            out_shape=jax.ShapeDtypeStruct((v8, tiles * TILE), jnp.int32),
-            grid_spec=grid_spec,
-            interpret=interpret,
-        )(starts, rid_pad, vals_pad)
+
+def _pallas_call(vals_pad, rid_pad, starts, v8, tiles, interpret):
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(tiles,),
+        in_specs=[
+            # rid rides 1-D: a (TILE,) block keeps the lane dim at a
+            # multiple of 128 and matches XLA's s32[N] T(1024) layout
+            # (Mosaic requires last-two block dims ≡ 0 mod (8, 128) or
+            # full — a (1, TILE) block over [tiles, TILE] fails on
+            # real TPU lowering; caught on first live-chip run)
+            pl.BlockSpec((TILE,), lambda i, starts: (i,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((v8, TILE), lambda i, starts: (0, i)),
+        scratch_shapes=[
+            pltpu.VMEM((v8, SPAN), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+        ],
+    )
+    return pl.pallas_call(
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((v8, tiles * TILE), jnp.int32),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(starts, rid_pad, vals_pad)
 
 
 def monotone_gather(values: jax.Array, rid: jax.Array,
@@ -112,21 +107,10 @@ def monotone_gather(values: jax.Array, rid: jax.Array,
     """
     v, r = values.shape
     t = rid.shape[0]
-    # test hook: run the Mosaic kernel through the interpreter on CPU so
-    # the full merge kernel can be exercised with the pallas path green
-    # without a chip (tests/test_mono_gather.py)
-    if use_pallas and os.environ.get("GRAFT_PALLAS_INTERPRET") == "1":
-        interpret = True
-    if use_pallas is None:
-        # GRAFT_NO_PALLAS=1 is the operational kill-switch (e.g. if the
-        # experimental backend's Mosaic lowering misbehaves mid-bench)
-        use_pallas = HAVE_PALLAS and not interpret and \
-            jax.default_backend() == "tpu" and \
-            os.environ.get("GRAFT_NO_PALLAS") != "1"
+    run, interpret = pallas_mode.select(use_pallas, interpret)
     # shape-derived exactness guard: token ids < T, run values < R;
     # weights are bounded by T as well (prefix sums of 0/1 weights)
-    if not (use_pallas or interpret) or not HAVE_PALLAS or \
-            max(r, t) >= F24 or v > 8:
+    if not run or max(r, t) >= F24 or v > 8:
         return _lax_gather(values, rid)
 
     tiles = -(-t // TILE)
@@ -143,6 +127,6 @@ def monotone_gather(values: jax.Array, rid: jax.Array,
     # x64 emits index/grid ops Mosaic cannot legalize ('func.func'), so
     # scope it to x32 — caller dtypes are unaffected (no-op when x64 is
     # already off)
-    with jaxcompat.enable_x64(False):
+    with jax.enable_x64(False):
         out = _pallas_call(vals_pad, rid_pad, starts, v8, tiles, interpret)
     return out[:v, :t]

@@ -28,24 +28,20 @@ the lax path.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import jaxcompat
+from . import pallas_mode
 
 TILE = 2048      # stream elements per grid step, as an (8, 256) block
+KERNEL_NAME = "tour_scan_prefix"
+RING_KERNEL_NAME = "opsaxis_ring_carry"
 ROWS, LANES = 8, 256
-
-try:  # pallas is TPU/Mosaic; keep importable on bare CPU builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 
 def _lax_prefix(boundary: jax.Array,
@@ -55,59 +51,62 @@ def _lax_prefix(boundary: jax.Array,
     return lax.cumsum(boundary), lax.cumsum(weights, axis=1)
 
 
-if HAVE_PALLAS:
-    def _kernel(seg_starts, x_ref, o_ref, carry):
-        """One (8, 256) tile: in-tile inclusive prefix + carry."""
-        i = pl.program_id(0)
-        # carry resets at each segment's static first tile
-        reset = (i == seg_starts[0])
-        for s in seg_starts[1:]:
-            reset = reset | (i == s)
+def _kernel(seg_starts, x_ref, o_ref, carry):
+    """One (8, 256) tile: in-tile inclusive prefix + carry."""
+    i = pl.program_id(0)
+    # carry resets at each segment's static first tile
+    reset = (i == seg_starts[0])
+    for s in seg_starts[1:]:
+        reset = reset | (i == s)
 
-        @pl.when(reset)
-        def _init():
-            carry[0] = jnp.int32(0)
+    @pl.when(reset)
+    def _init():
+        carry[0] = jnp.int32(0)
 
-        x = x_ref[...].astype(jnp.float32)            # [8, 256] of 0/1
-        tri = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) <=
-               jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
-               ).astype(jnp.float32)
-        row_pref = jax.lax.dot_general(
-            x, tri, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)      # [8, 256] incl.
-        totals = row_pref[:, LANES - 1:LANES]         # [8, 1]
-        # exclusive prefix over the 8 row totals (strict lower-tri)
-        strict = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 1) <
-                  jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 0)
-                  ).astype(jnp.float32)
-        offs = jax.lax.dot_general(
-            strict, totals, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)      # [8, 1]
-        pref = (row_pref + offs).astype(jnp.int32)
-        o_ref[...] = pref + carry[0]
-        carry[0] = carry[0] + jnp.sum(x_ref[...])     # + tile total
+    x = x_ref[...].astype(jnp.float32)            # [8, 256] of 0/1
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) <=
+           jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+           ).astype(jnp.float32)
+    row_pref = jax.lax.dot_general(
+        x, tri, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)      # [8, 256] incl.
+    totals = row_pref[:, LANES - 1:LANES]         # [8, 1]
+    # exclusive prefix over the 8 row totals (strict lower-tri)
+    strict = (jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 1) <
+              jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 0)
+              ).astype(jnp.float32)
+    offs = jax.lax.dot_general(
+        strict, totals, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)      # [8, 1]
+    pref = (row_pref + offs).astype(jnp.int32)
+    o_ref[...] = pref + carry[0]
+    # + tile total; summed in f32 (exact: ≤ 2048 addends of 0/1)
+    # — an integer jnp.sum promotes to i64 under a global x64
+    # config, which Mosaic cannot lower
+    carry[0] = carry[0] + jnp.sum(x).astype(jnp.int32)
 
-    def _pallas_call(stream2d, seg_starts, tiles, interpret):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
-            grid=(tiles,),
-            in_specs=[pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-            scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        )
-        # the "scan" in the name is LOAD-BEARING for the cost model:
-        # utils/chainaudit bills sequential-scan kernels by their full
-        # stream length (every element is serially swept), not by the
-        # output row count like the bounded-span gather kernels
-        return pl.pallas_call(
-            functools.partial(_kernel, seg_starts),
-            out_shape=jax.ShapeDtypeStruct(stream2d.shape, jnp.int32),
-            grid_spec=grid_spec,
-            interpret=interpret,
-            name="tour_scan_prefix",
-        )(stream2d)
+
+def _pallas_call(stream2d, seg_starts, tiles, interpret):
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0,
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+    )
+    # the "scan" in the name is LOAD-BEARING for the cost model:
+    # utils/chainaudit bills sequential-scan kernels by their full
+    # stream length (every element is serially swept), not by the
+    # output row count like the bounded-span gather kernels
+    return pl.pallas_call(
+        functools.partial(_kernel, seg_starts),
+        out_shape=jax.ShapeDtypeStruct(stream2d.shape, jnp.int32),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(stream2d)
 
 
 def _pad_tile(x: jax.Array) -> jax.Array:
@@ -200,7 +199,7 @@ def sharded_prefix_sums(boundary: jax.Array, weights: jax.Array, *,
     return ob, ow
 
 
-# ---- pallas ring-carry exchange (staged for the TPU grant) -------------
+# ---- pallas ring-carry exchange (not yet selected by any caller) -------
 #
 # The ``ring_exclusive`` above is lax.ppermute so the 8-device
 # host-platform CPU mesh executes it for real in tier-1.  On a real TPU
@@ -209,63 +208,67 @@ def sharded_prefix_sums(boundary: jax.Array, weights: jax.Array, *,
 # pattern): each device pushes its carry vector to its right neighbour
 # k-1 times, accumulating the exclusive prefix in VMEM — one kernel
 # launch instead of log2(k)+1 XLA collectives.  Validated in interpret
-# mode where the installed jax supports interpreting remote DMAs
-# (tests/test_opsaxis.py::test_pallas_ring_carry_interpret skips
-# otherwise); priced on chip by the staged probe in
-# scripts/tpu_next_grant.sh.
+# mode on the CPU mesh (tests/test_opsaxis.py) and compiled for a
+# v5e:2x2 mesh (tests/test_tpu_compile.py); its chip time against
+# ``ring_exclusive`` is not measured yet.
 
-if HAVE_PALLAS:
-    def _ring_carry_kernel(x_ref, o_ref, comm, send_sem, recv_sem, *,
-                           k: int, axis: str):
-        my = lax.axis_index(axis)
-        acc = jnp.zeros_like(x_ref[...])
-        comm[0] = x_ref[...]
-        for step in range(k - 1):
-            s, r = step % 2, (step + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm.at[s], dst_ref=comm.at[r],
-                send_sem=send_sem.at[s], recv_sem=recv_sem.at[r],
-                device_id=(my + 1) % k,
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
-            rdma.start()
-            rdma.wait()
-            # the received buffer IS the next hop's send slot (the s/r
-            # alternation relays it onward); after ``step+1`` hops it
-            # holds the carry ORIGINATED by the device step+1 to our
-            # ring-left, which contributes iff that sender index is
-            # below ours (exclusive prefix, ring-ordered)
-            acc = acc + jnp.where(my >= step + 1, comm[r], 0)
-        o_ref[...] = acc
 
-    def ring_exclusive_pallas(vals: jax.Array, k: int,
-                              interpret: bool = False,
-                              axis: str = "ops") -> jax.Array:
-        """Pallas twin of :func:`ring_exclusive` (add only), for use
-        inside shard_map over ``axis``.  Lanes pad to the 128-lane
-        tile; the comm buffer double-buffers so hop N+1's send never
-        overwrites hop N's payload before it is consumed.  Validated
-        in interpret mode on the CPU mesh (the installed jax's
-        remote-DMA discharge rule executes the ring for real —
-        tests/test_opsaxis.py); priced on chip by the staged probe in
-        scripts/tpu_next_grant.sh."""
-        import functools
-        lanes = vals.shape[0]
-        pad = -lanes % 128
-        x = jnp.pad(vals.astype(jnp.int32), (0, pad)).reshape(1, -1)
+def _ring_carry_kernel(x_ref, o_ref, comm, send_sem, recv_sem, *,
+                       k: int, axis: str):
+    my = lax.axis_index(axis)
+    right, left = (my + 1) % k, (my + k - 1) % k
+    # neighbours enter the kernel before anyone writes into their VMEM
+    barrier = pltpu.get_barrier_semaphore()
+    for nb in (left, right):
+        pltpu.semaphore_signal(barrier, 1, device_id=nb,
+                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_wait(barrier, 2)
+    acc = jnp.zeros_like(x_ref[...])
+    comm[0] = x_ref[...]
+    for step in range(k - 1):
+        # hop ``step`` relays slot ``step`` into the right neighbour's
+        # slot ``step + 1``: every slot is written once per call, so a
+        # device running ahead never overwrites a payload its
+        # neighbour has not relayed yet
+        rdma = pltpu.make_async_remote_copy(
+            src_ref=comm.at[step], dst_ref=comm.at[step + 1],
+            send_sem=send_sem.at[step], recv_sem=recv_sem.at[step],
+            device_id=right,
+            device_id_type=pltpu.DeviceIdType.LOGICAL)
+        rdma.start()
+        rdma.wait()
+        # after ``step+1`` hops the slot holds the carry ORIGINATED by
+        # the device step+1 to our ring-left, which contributes iff
+        # that sender index is below ours (exclusive prefix)
+        acc = acc + jnp.where(my >= step + 1, comm[step + 1], 0)
+    o_ref[...] = acc
+
+
+def ring_exclusive_pallas(vals: jax.Array, k: int,
+                          interpret: bool = False,
+                          axis: str = "ops") -> jax.Array:
+    """Pallas twin of :func:`ring_exclusive` (add only), for use
+    inside shard_map over ``axis``.  Lanes pad to the 128-lane
+    tile; the comm buffer holds one slot per hop."""
+    lanes = vals.shape[0]
+    pad = -lanes % 128
+    x = jnp.pad(vals.astype(jnp.int32), (0, pad)).reshape(1, -1)
+    with jax.enable_x64(False):     # x64 index math fails to lower
         out = pl.pallas_call(
             functools.partial(_ring_carry_kernel, k=k, axis=axis),
             out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
-                pltpu.VMEM((2,) + x.shape, jnp.int32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((k,) + x.shape, jnp.int32),
+                pltpu.SemaphoreType.DMA((k - 1,)),
+                pltpu.SemaphoreType.DMA((k - 1,)),
             ],
-            interpret=interpret,
-            name="opsaxis_ring_carry",
+            compiler_params=pltpu.CompilerParams(collective_id=0),
+            interpret=pltpu.InterpretParams() if interpret else False,
+            name=RING_KERNEL_NAME,
         )(x)
-        return out.reshape(-1)[:lanes]
+    return out.reshape(-1)[:lanes]
 
 
 def prefix_sums(boundary: jax.Array, weights: jax.Array,
@@ -278,14 +281,8 @@ def prefix_sums(boundary: jax.Array, weights: jax.Array,
     convention (None = auto: Mosaic on TPU, lax elsewhere)."""
     t = boundary.shape[0]
     kw, m = weights.shape
-    if use_pallas and os.environ.get("GRAFT_PALLAS_INTERPRET") == "1":
-        interpret = True
-    if use_pallas is None:
-        use_pallas = HAVE_PALLAS and not interpret and \
-            jax.default_backend() == "tpu" and \
-            os.environ.get("GRAFT_NO_PALLAS") != "1"
-    if not (use_pallas or interpret) or not HAVE_PALLAS or \
-            kw > 3 or t < TILE:
+    run, interpret = pallas_mode.select(use_pallas, interpret)
+    if not run or kw > 3 or t < TILE:
         return _lax_prefix(boundary, weights)
 
     segs = [_pad_tile(boundary.astype(jnp.int32))] + \
@@ -296,7 +293,7 @@ def prefix_sums(boundary: jax.Array, weights: jax.Array,
         b += s.shape[0]
     stream = jnp.concatenate(segs)
     tiles = stream.shape[0] // TILE
-    with jaxcompat.enable_x64(False):
+    with jax.enable_x64(False):
         out = _pallas_call(stream.reshape(tiles * ROWS, LANES),
                            tuple(starts), tiles, interpret)
     out = out.reshape(-1)

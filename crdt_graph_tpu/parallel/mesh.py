@@ -31,8 +31,8 @@ Entry points:
 This module is the auto-partitioned path (whole-array kernel + input
 shardings; XLA chooses the collectives).  The EXPLICIT schedule — per-
 shard local resolution with hand-placed pmin/all_gather boundary
-exchange, which moves ~2x fewer bytes in ~3x fewer collectives (measured:
-SWEEP_CPU_r04.jsonl) — lives in :mod:`crdt_graph_tpu.parallel.shard`;
+exchange, which moved ~2x fewer bytes in ~3x fewer collectives on the
+round-4 CPU mesh — lives in :mod:`crdt_graph_tpu.parallel.shard`;
 both are pinned bit-identical to the single-device kernel.
 """
 from __future__ import annotations
@@ -48,7 +48,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..codec.packed import KIND_PAD, PackedOps
 from ..codec.packed import pad_arrays as packed_pad_arrays
 from ..ops import merge as merge_mod
-from ..utils import jaxcompat
 from ..ops.merge import NodeTable
 
 DOCS_AXIS = "docs"
@@ -103,7 +102,7 @@ def sharded_materialize(ops: Dict[str, np.ndarray], mesh: Mesh) -> NodeTable:
 
     if jax.config.jax_enable_x64:
         return run()
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         return run()
 
 
@@ -172,7 +171,7 @@ def batched_materialize(ops: Dict[str, np.ndarray], mesh: Mesh,
 
     if jax.config.jax_enable_x64:
         return run()
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         return run()
 
 

@@ -43,10 +43,10 @@ design the round-5 doc committed:
 
 On the 8-device host-platform CPU mesh every collective executes for
 real (lax.ppermute/psum/all_gather — tier-1 runs this path); the pallas
-``make_async_remote_copy`` ring variant of the carry exchange is
-validated in interpret mode where supported and staged for the TPU
-grant (ops/tour_scan.ring_exclusive_pallas,
-scripts/tpu_next_grant.sh).
+``make_async_remote_copy`` ring variant of the carry exchange
+(ops/tour_scan.ring_exclusive_pallas) is validated in interpret mode and
+compiles for a v5e:2x2 mesh (tests/test_tpu_compile.py); no caller
+selects it yet.
 
 What stays replicated per device, disclosed: all ELEMENTWISE M-wide
 arithmetic (the cost model bills memory ops, not elementwise lanes —
@@ -82,7 +82,7 @@ from ..codec import packed as packed_mod
 from ..ops import fused_resolve, merge as merge_mod
 from ..ops import tour_scan
 from ..ops.merge import NodeTable
-from ..utils import hostenv, jaxcompat
+from ..utils import hostenv
 
 AXIS = "ops"
 
@@ -329,7 +329,7 @@ def _opsaxis_jit(device_ops, mesh: Mesh, cols, hints,
 
     spec = tuple(P(AXIS) if device_ops[c].ndim == 1 else P(AXIS, None)
                  for c in cols)
-    fn = jaxcompat.shard_map(body, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec,
                              out_specs=P(), check_vma=False)
     return fn(*[device_ops[c] for c in cols])
 
@@ -396,7 +396,7 @@ def materialize(ops: Dict[str, np.ndarray], k: Optional[int] = None,
         }
     if jax.config.jax_enable_x64:
         return run()
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         return run()
 
 
@@ -460,7 +460,7 @@ def _audit_traced(shapes: Dict[str, jax.ShapeDtypeStruct], k: int,
         return _opsaxis_jit.__wrapped__(device_ops, mesh, cols, hints,
                                         no_deletes)
 
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         audit = chainaudit.count_mwide(fn, shapes)
     m = shapes["kind"].shape[0] + 2
     budget = _ceil_div(m, k) + HALO

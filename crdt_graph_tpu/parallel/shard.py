@@ -71,7 +71,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..codec.packed import KIND_ADD, KIND_DELETE
 from ..ops import merge as merge_mod
 from ..ops.merge import BIG, IPOS, NodeTable
-from ..utils import jaxcompat
 from .mesh import OPS_AXIS, _pad_ops_to, round_up
 
 # op columns crossing the shard_map boundary, in positional order
@@ -197,7 +196,7 @@ def _shard_materialize_jit(device_ops, mesh: Mesh, hints: str,
                              hints == "exhaustive")
     spec = [P(OPS_AXIS) if device_ops[c].ndim == 1 else P(OPS_AXIS, None)
             for c in _COLS]
-    resolve = jaxcompat.shard_map(body, mesh=mesh, in_specs=tuple(spec),
+    resolve = jax.shard_map(body, mesh=mesh, in_specs=tuple(spec),
                             out_specs=P(), check_vma=False)
     gathered, sel, hints_ok = resolve(*[device_ops[c] for c in _COLS])
     if hints == "exhaustive":
@@ -239,7 +238,7 @@ def shard_materialize(ops: Dict[str, np.ndarray], mesh: Mesh,
 
     if jax.config.jax_enable_x64:
         return run()
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         return run()
 
 

@@ -1,8 +1,8 @@
 """Trace-time chain auditor: counts and PRICES the M-wide memory ops of
 a jitted function — the merge kernel's CI-pinned performance budget.
 
-The round-5 on-chip cost model (docs/TPU_PROFILE.md §3-4,
-PRIMS_TPU_r05.txt) is: every 1M-wide random-access memory op — gather,
+The round-5 on-chip cost model (docs/TPU_PROFILE.md §3-4; measured on
+the kernel as it stood before PRs 1-20) is: every 1M-wide random-access memory op — gather,
 scatter, sort, scan — costs ~6 ms of device time on v5e, and the merge
 kernel is a dependency chain of them.  Round 6 pinned the raw count
 (≤16); round 7 (ISSUE 3) lowers the budget to ≤10 and upgrades the
@@ -65,7 +65,7 @@ _CALLS = ("pjit", "closed_call", "core_call", "remat", "remat2",
 _COLLECTIVES_P = ("all_gather", "ppermute", "psum", "pmin", "pmax",
                   "all_to_all", "reduce_scatter")
 
-MODELED_MS_PER_OP = 6.0   # measured: PRIMS_TPU_r05.txt while-loop row
+MODELED_MS_PER_OP = 6.0   # round-5 chip primitives, while-loop row
 
 # CI budget: fast-path M-wide memory ops on the production (device)
 # trace — round 6 pinned 16; the round-7 fusions bring the trace under

@@ -21,9 +21,8 @@ measures the simulation, not the slice — the committed CLAIM is the
 audited per-shard width (≤ ceil(M/8) + halo) and the collective-byte
 count, both attached from utils/chainaudit v3; the wall-clock A/B is
 committed either way as a broken-path tripwire (a hang, a pathological
-fallback, or a silently-widened shard shows up here long before a TPU
-grant would).  The on-chip twin is staged in
-scripts/tpu_next_grant.sh.
+fallback, or a silently-widened shard shows up here long before a chip
+run would).  The on-chip check is ``python chip_smoke.py --chips 4``.
 
 Usage: python scripts/bench_opsaxis_headline.py [n_ops] [repeats] [out]
 """
@@ -128,8 +127,7 @@ def run(n_ops: int = 1_000_000, repeats: int = 3,
                  "wall-clock measures the simulation (SHARD_TAIL.md "
                  "section 2/6 anti-correlation); the audited per-shard "
                  "width + collective bytes are the committed claim, "
-                 "and the on-chip A/B is staged in "
-                 "scripts/tpu_next_grant.sh"),
+                 "and the on-chip check is chip_smoke.py --chips 4"),
     }
     with open(out_path, "w") as f:
         json.dump(out, f, indent=1)

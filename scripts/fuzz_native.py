@@ -47,7 +47,6 @@ def main(budget_s: float) -> None:
     scratch = tempfile.mkdtemp(prefix="fuzz_native_")
     shutil.copy(native._SRC, os.path.join(scratch, "fastcodec.cpp"))
     native._SRC = os.path.join(scratch, "fastcodec.cpp")
-    native._SO = os.path.join(scratch, "_fastcodec.so")
     mod = native.load(rebuild=True)
     if mod is None:
         print("build failed:", native._build_error)

@@ -8,7 +8,7 @@ trace's chain ≤ ~10 such ops with modeled ms ≤ 70.
 utils/chainaudit.py counts and prices them at TRACE time; this suite
 turns the budget into a regression gate — any future kernel change
 that re-adds an M-wide pass to the config-5 production trace fails
-tier-1 instead of surfacing in the next grant window's profile.
+tier-1 instead of surfacing in the next chip run's profile.
 
 Two traces are pinned: the DEVICE trace (use_pallas=True — the pallas
 superops with their in-trace fallback conds, what runs on TPU) at

@@ -3,8 +3,7 @@
 the pallas resolution path green — across every sweep shape, including
 the three adversarial configs (ISSUE 2 satellite: fused-resolution
 coverage).  Runs the Mosaic kernel in interpreter mode on CPU; the
-real-TPU path is staged for the next grant window
-(scripts/tpu_next_grant.sh)."""
+v5e compile is tests/test_tpu_compile.py, the chip run chip_smoke.py."""
 import numpy as np
 import pytest
 
@@ -163,16 +162,16 @@ def _small_configs():
 
 
 @pytest.mark.parametrize("cid", sorted(_small_configs()))
-def test_full_merge_pallas_interpret_bit_identity(cid, monkeypatch):
+def test_full_merge_pallas_interpret_bit_identity(cid):
     """merge with use_pallas=True (interpreted Mosaic: mono_gather AND
     the fused_resolve plane sweep) == the lax path, every NodeTable
     field, production exhaustive mode."""
-    monkeypatch.setenv("GRAFT_PALLAS_INTERPRET", "1")
     arrs = _small_configs()[cid]
     t_lax = view.to_host(merge.materialize(arrs, use_pallas=False,
                                            hints="exhaustive"))
     t_pal = view.to_host(merge.materialize(arrs, use_pallas=True,
-                                           hints="exhaustive"))
+                                           hints="exhaustive",
+                                           interpret=True))
     for f in FIELDS:
         np.testing.assert_array_equal(
             np.asarray(getattr(t_pal, f)), np.asarray(getattr(t_lax, f)),
@@ -208,12 +207,12 @@ def test_fallback_path_order_exact_config5(flags_on, monkeypatch, request):
     np.testing.assert_array_equal(seq, exp)
 
 
-def test_full_merge_pallas_interpret_auto_mode(monkeypatch):
+def test_full_merge_pallas_interpret_auto_mode():
     """The verified auto mode rides the same pallas plane sweep."""
-    monkeypatch.setenv("GRAFT_PALLAS_INTERPRET", "1")
     arrs = _small_configs()[5]
     t_lax = view.to_host(merge.materialize(arrs, use_pallas=False))
-    t_pal = view.to_host(merge.materialize(arrs, use_pallas=True))
+    t_pal = view.to_host(merge.materialize(arrs, use_pallas=True,
+                                           interpret=True))
     for f in FIELDS:
         np.testing.assert_array_equal(
             np.asarray(getattr(t_pal, f)), np.asarray(getattr(t_lax, f)),

@@ -8,6 +8,7 @@ sequences, with the pure oracle as the correctness reference.
 """
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,7 +17,6 @@ from crdt_graph_tpu import Add, Batch, Delete
 from crdt_graph_tpu.codec import packed
 from crdt_graph_tpu.core import operation as op_mod
 from crdt_graph_tpu.ops import merge, view
-from crdt_graph_tpu.utils import jaxcompat
 
 OFFSET = 2**32
 
@@ -447,7 +447,7 @@ def test_no_deletes_trace_parity():
     arrs = p.arrays()
     assert merge.host_no_deletes(arrs["kind"])
     import jax
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         lean = view.to_host(merge._materialize(arrs, None, None, True))
         full = view.to_host(merge._materialize(arrs, None, None, False))
     for f in ("ts", "parent", "depth", "value_ref", "exists", "tombstone",
@@ -474,7 +474,7 @@ def test_probe_cuts_run_every_stage():
     for op_set in (ops, [op for op in ops if not isinstance(op, Delete)]):
         arrs = packed.pack(op_set).arrays()
         nd = merge.host_no_deletes(arrs["kind"])
-        with jaxcompat.enable_x64(True):
+        with jax.enable_x64(True):
             for k in range(1, 8):
                 out = merge._materialize(arrs, None, "exhaustive", nd, k)
                 assert np.asarray(out).shape == (), k
@@ -571,7 +571,7 @@ def test_split_pack_roundtrip_edges():
     vals = np.array([0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32,
                      2**32 + 2**31, 5 * 2**32 + (2**32 - 1),
                      merge.BIG - 1, merge.BIG], dtype=np.int64)
-    with jaxcompat.enable_x64(True):     # bare asarray would truncate to i32
+    with jax.enable_x64(True):     # bare asarray would truncate to i32
         v = jnp.asarray(vals)
         h, l = merge._split_u(v)
         assert np.array_equal(np.asarray(merge._pack_u(h, l)), vals)

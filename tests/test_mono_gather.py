@@ -40,10 +40,9 @@ def test_constant_rid():
     np.testing.assert_array_equal(got, want)
 
 
-def test_full_merge_with_pallas_rank_interpret(monkeypatch):
+def test_full_merge_with_pallas_rank_interpret():
     """The whole merge kernel with the pallas rank path (interpreted)
     must match the default lax path on a real log."""
-    monkeypatch.setenv("GRAFT_PALLAS_INTERPRET", "1")
     from crdt_graph_tpu.codec import packed
     from crdt_graph_tpu.ops import merge, view
     from test_merge_kernel import _random_session
@@ -51,7 +50,8 @@ def test_full_merge_with_pallas_rank_interpret(monkeypatch):
     _, ops = _random_session(77, n_replicas=3, steps=70)
     p = packed.pack(ops)
     t_lax = view.to_host(merge.materialize(p.arrays()))
-    t_pal = view.to_host(merge.materialize(p.arrays(), use_pallas=True))
+    t_pal = view.to_host(merge.materialize(p.arrays(), use_pallas=True,
+                                           interpret=True))
     np.testing.assert_array_equal(np.asarray(t_pal.doc_index),
                                   np.asarray(t_lax.doc_index))
     np.testing.assert_array_equal(np.asarray(t_pal.visible_order),

@@ -3,6 +3,7 @@ the pure-Python json_codec.loads → pack path on every input — same columns,
 same values, same rejections."""
 import json
 import math
+import os
 import random
 
 import numpy as np
@@ -283,3 +284,17 @@ def test_parse_encode_round_trip_is_identity():
                '{"op":"del","path":[1]}]}')
     p = native.parse_pack(payload)
     assert native.encode_pack(p).decode() == payload
+
+
+def test_build_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """A copied tree's mtimes say nothing: the extension's file name
+    carries the source's hash, so edited source never loads a stale
+    build."""
+    src = tmp_path / "fastcodec.cpp"
+    src.write_bytes(open(native._SRC, "rb").read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native._so_path()
+    assert os.path.dirname(first) == str(tmp_path)
+    assert native._so_path() == first
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert native._so_path() != first

@@ -22,7 +22,6 @@ from crdt_graph_tpu.bench import workloads  # noqa: E402
 from crdt_graph_tpu.codec import packed  # noqa: E402
 from crdt_graph_tpu.ops import merge, tour_scan  # noqa: E402
 from crdt_graph_tpu.parallel import opsaxis  # noqa: E402
-from crdt_graph_tpu.utils import jaxcompat  # noqa: E402
 
 # the bit-identity suite pins the packed layout like test_shard_map
 os.environ["GRAFT_PACK_GATHER"] = "1"
@@ -142,7 +141,7 @@ def test_windowed_plane_rows_unit_fallback():
         return (part.plane_rows(plane, near),
                 part.plane_rows(plane, far))
 
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(opsaxis.AXIS),),
         out_specs=(P(), P()), check_vma=False)
     g_near, g_far = jax.jit(fn)(jnp.zeros(8, jnp.int32))
@@ -166,7 +165,7 @@ def test_sharded_prefix_sums_bit_identical():
             return tour_scan.sharded_prefix_sums(
                 jnp.asarray(b), jnp.asarray(w), axis=opsaxis.AXIS, k=8)
 
-        fn = jaxcompat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=(P(opsaxis.AXIS),),
             out_specs=(P(), P()), check_vma=False)
         ob, ow = jax.jit(fn)(jnp.zeros(8, jnp.int32))
@@ -331,11 +330,8 @@ def test_bench_opsaxis_headline_reduced(tmp_path):
 
 def test_pallas_ring_carry_interpret():
     """The make_async_remote_copy ring variant of the carry exchange,
-    interpret-mode: validated where the installed pallas can interpret
-    remote DMAs under shard_map; skipped (with the on-chip probe
-    staged in scripts/tpu_next_grant.sh) where it cannot."""
-    if not tour_scan.HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
+    through the TPU interpreter (remote DMAs, barrier semaphore) on
+    the 8-device CPU mesh."""
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(jax.devices()[:8]), (opsaxis.AXIS,))
     vals = np.arange(8, dtype=np.int32) + 1
@@ -343,13 +339,9 @@ def test_pallas_ring_carry_interpret():
     def body(v):
         return tour_scan.ring_exclusive_pallas(v, 8, interpret=True)
 
-    fn = jaxcompat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(opsaxis.AXIS),),
         out_specs=P(opsaxis.AXIS), check_vma=False)
-    try:
-        got = np.asarray(jax.jit(fn)(jnp.asarray(vals)))
-    except Exception as e:  # noqa: BLE001 — interpret-mode remote DMA
-        pytest.skip(f"installed pallas cannot interpret remote DMA "
-                    f"under shard_map: {type(e).__name__}")
+    got = np.asarray(jax.jit(fn)(jnp.asarray(vals)))
     want = np.concatenate([[0], np.cumsum(vals)[:-1]])
     np.testing.assert_array_equal(got, want)

@@ -15,7 +15,6 @@ from crdt_graph_tpu.codec import packed
 from crdt_graph_tpu.ops import merge, view
 from crdt_graph_tpu.parallel import mesh as mesh_mod
 from crdt_graph_tpu.parallel import shard
-from crdt_graph_tpu.utils import jaxcompat
 
 # the 256k bit-identity suite runs with the packed multi-column layout
 # pinned ON (the round-6 default; a hard set so neither an exported
@@ -128,7 +127,7 @@ def test_collective_volume_explicit_vs_auto(ops_mesh):
     mesh = ops_mesh
     padded = mesh_mod._pad_ops_to(
         arrs, mesh_mod.round_up(arrs["kind"].shape[0], 8))
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         dev = {k: jax.device_put(
             v, NamedSharding(mesh, P("ops") if v.ndim == 1
                              else P("ops", None)))
