@@ -1,0 +1,25 @@
+"""The merge's share of its HBM roofline, in %: the least bytes any
+merge of the window's acked ops must move (read each op's input
+columns once, write its node-table row once; ``roofline.py``) over the
+chip's HBM bandwidth (``peaks.json``), divided by the device time of
+the merge executables in the window."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
+sys.path.insert(0, os.path.dirname(__file__))
+import roofline  # noqa: E402
+from _merge import merge_ns  # noqa: E402
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    ns = merge_ns(ctx.trace)
+    if not ns:
+        return None
+    depth = ctx.config.get("depth", 1)
+    nbytes = sum(roofline.merge_bytes(r.n_ops, depth)
+                 for r in ctx.acked_writes)
+    bw = roofline.peak(ctx.device["kind"])["hbm_bytes_per_s"]
+    return 100.0 * (nbytes / bw) / (ns / 1e9)
