@@ -47,6 +47,7 @@ from .oplog import OpLog, PackedBatch
 from .ops import merge as merge_mod
 from .ops import view as view_mod
 from .ops.merge import APPLIED, INVALID_PATH, NOT_FOUND, NodeTable
+from .utils import profiling
 
 # deltas at or under this many leaves apply host-side in O(delta); larger
 # ones re-materialise through the batched kernel
@@ -432,27 +433,29 @@ class TpuTree:
         left one pending (O(tail since artifact) — the cold-path
         collapse), from an existing table when one is materialised,
         through the kernel for big logs, by sequential replay for
-        small ones."""
+        small ones.  A rebuild is the ``engine.mirror`` span."""
         if self._mirror is None:
-            if self._matz_pending and self._table is None:
-                m = self._load_matz_mirror()
-                if m is not None:
-                    self._mirror = m
-                    return m
-            if self._table is None and len(self._log) <= DELTA_THRESHOLD:
-                m = HostTree(self._max_depth)
-                for op in self._log:
-                    if isinstance(op, Add):
-                        m.apply_add(op.ts, tuple(op.path), op.value)
-                    else:
-                        m.apply_delete(tuple(op.path))
-                m.journal.clear()
-                self._mirror = m
-            else:
-                self._mirror = HostTree.from_table(
-                    self.table(), self._ensure_packed().values,
-                    self._max_depth)
+            with profiling.span("engine.mirror", ops=len(self._log)):
+                self._mirror = self._build_mirror()
         return self._mirror
+
+    def _build_mirror(self) -> HostTree:
+        if self._matz_pending and self._table is None:
+            m = self._load_matz_mirror()
+            if m is not None:
+                return m
+        if self._table is None and len(self._log) <= DELTA_THRESHOLD:
+            m = HostTree(self._max_depth)
+            for op in self._log:
+                if isinstance(op, Add):
+                    m.apply_add(op.ts, tuple(op.path), op.value)
+                else:
+                    m.apply_delete(tuple(op.path))
+            m.journal.clear()
+            return m
+        return HostTree.from_table(self.table(),
+                                   self._ensure_packed().values,
+                                   self._max_depth)
 
     def _stale_device(self) -> None:
         """Host-path edit: device view no longer matches the log; mirror
@@ -591,9 +594,12 @@ class TpuTree:
     def _after_commit(self) -> None:
         """Commit-boundary hook: run the cascade's spill/GC unless a
         batch or chunked apply is mid-flight (their rollback paths
-        truncate back into what must still be the hot tier)."""
+        truncate back into what must still be the hot tier).  The
+        ``engine.spill`` span: inside ``engine.finish`` on a single-shot
+        apply, on its own after the last chunk of a chunked one."""
         if self._batch_depth == 0 and not self._defer_spill:
-            self._log.maybe_spill()
+            with profiling.span("engine.spill"):
+                self._log.maybe_spill()
 
     def _apply_host(self, leaves: List[Operation]) -> List[Operation]:
         """Sequential host-path apply; first failure rolls everything back
@@ -669,17 +675,22 @@ class TpuTree:
         return self.apply_packed(
             native.parse_pack(payload, max_depth=self._max_depth))
 
-    def apply_packed(self, pnew: PackedOps) -> "TpuTree":
+    def apply_packed(self, pnew: PackedOps, chunk: int = 0) -> "TpuTree":
         """Remote apply from already-packed columns (the ingest fast
-        path's second half — see :meth:`apply_wire`)."""
+        path's second half — see :meth:`apply_wire`).  ``chunk`` is the
+        chunk's index within :meth:`apply_packed_chunked`, for the
+        trace."""
         # below the bulk kernel crossover, keep apply()'s exact
         # sequence-semantics routing (host path / host-first)
         if not self.packed_route(pnew.num_ops):
             return self.apply(op_mod.from_list(packed_mod.unpack(pnew)))
-        p = self.prepare_packed(pnew)
+        p = self.prepare_packed(pnew, chunk=chunk)
         # device table; only the status column reads back here (table()
-        # converts the rest lazily, off the serving path)
-        table = _routed_materialize(p.arrays(), hints=_mode(p))
+        # converts the rest lazily, off the serving path).  The launch
+        # span holds the dispatch and the host-to-device copy; the
+        # device work itself is waited for in finish_packed
+        with profiling.span("engine.launch", rows=p.num_ops):
+            table = _routed_materialize(p.arrays(), hints=_mode(p))
         return self.finish_packed(pnew, p, table)
 
     def packed_route(self, n: int) -> bool:
@@ -690,23 +701,42 @@ class TpuTree:
         documents into one batched materialization."""
         return n >= max(4 * DELTA_THRESHOLD, len(self._log) // 8)
 
-    def prepare_packed(self, pnew: PackedOps) -> PackedOps:
+    def prepare_packed(self, pnew: PackedOps, chunk: int = 0) -> PackedOps:
         """Stage 1 of the staged kernel apply: the candidate column set
-        (current log ∪ delta) whose materialization yields the new view.
-        Callers either materialize it themselves (possibly batched with
-        other documents — parallel.mesh.batched_materialize) and hand the
-        table to :meth:`finish_packed`, or just call :meth:`apply_packed`."""
-        return packed_mod.concat(self._ensure_packed(), pnew)
+        (current log ∪ delta) whose materialization yields the new view,
+        with its slot hints derived (``p.arrays()`` caches them on the
+        set; every launch path reads them).  Callers either materialize
+        it themselves (possibly batched with other documents —
+        parallel.mesh.batched_materialize) and hand the table to
+        :meth:`finish_packed`, or just call :meth:`apply_packed`.  The
+        ``engine.prepare`` span."""
+        with profiling.span("engine.prepare",
+                            rows=len(self._log) + pnew.num_ops, chunk=chunk):
+            p = packed_mod.concat(self._ensure_packed(), pnew)
+            p.arrays()
+        return p
 
     def finish_packed(self, pnew: PackedOps, p: PackedOps,
                       table: NodeTable) -> "TpuTree":
         """Stage 2 of the staged kernel apply: per-op status check, clock
         bookkeeping, columnar log commit, and view parking for a table
         materialized from :meth:`prepare_packed`'s candidate set.  Raises
-        exactly what :meth:`apply` raises, leaving the replica untouched."""
+        exactly what :meth:`apply` raises, leaving the replica untouched.
+        The status readback, which waits for the device, is the
+        ``engine.sync`` span; the rest is ``engine.finish``."""
         n = pnew.num_ops
         n0 = len(self._log)
-        st = np.asarray(table.status)[n0:n0 + n]
+        with profiling.span("engine.sync", rows=p.num_ops):
+            st = np.asarray(table.status)[n0:n0 + n]
+        with profiling.span("engine.finish", rows=n):
+            self._commit_statuses(pnew, p, table, st)
+        return self
+
+    def _commit_statuses(self, pnew: PackedOps, p: PackedOps,
+                         table: NodeTable, st: np.ndarray) -> None:
+        """:meth:`finish_packed` once the delta's statuses ``st`` are on
+        the host."""
+        n = pnew.num_ops
         failing = np.nonzero((st == NOT_FOUND) | (st == INVALID_PATH))[0]
         if failing.size:
             k = int(failing[0])
@@ -756,7 +786,6 @@ class TpuTree:
             ((ts_col >> 32) == self._replica)))
         self._last_applied_mask = np.asarray(st == APPLIED)
         self._after_commit()
-        return self
 
     def apply_packed_chunked(self, pnew: PackedOps,
                              chunk_ops: int) -> "TpuTree":
@@ -785,10 +814,10 @@ class TpuTree:
         defer0 = self._defer_spill
         self._defer_spill = True
         try:
-            for s in range(0, n, chunk_ops):
+            for k, s in enumerate(range(0, n, chunk_ops)):
                 chunk = packed_mod.select_rows(
                     pnew, np.arange(s, min(s + chunk_ops, n)))
-                self.apply_packed(chunk)
+                self.apply_packed(chunk, chunk=k)
                 masks.append(self._last_applied_mask)
         except (OperationFailedError, InvalidPathError):
             # a chunk rejected: restore the pre-call state and decide

@@ -85,6 +85,10 @@ class CommitRecord:
     # size, or the merge worker's achieved cross-fleet width
     # (docs/MERGETIER.md); None for per-document merges
     batch_width: Optional[int] = None
+    # the longest any member ticket waited in its document's queue,
+    # from admission to the drain of its round (None where no round
+    # drained it: a commit record left by a failed round)
+    queue_wait_ms: Optional[float] = None
     audit: Optional[Dict[str, Any]] = None
     error: Optional[str] = None
     # persisted materialization (docs/DURABILITY.md §Cold paths):

@@ -146,7 +146,7 @@ class CommitTrace:
                  "chunk_count", "applied_ops", "dup_ops", "outcome",
                  "staleness_s", "total_ms", "error", "packed",
                  "wal_deferred", "audit_sampled", "audit_result",
-                 "batch_width")
+                 "batch_width", "queue_wait_ms")
 
     def __init__(self, doc_id: str, tickets) -> None:
         self.doc_id = doc_id
@@ -187,6 +187,9 @@ class CommitTrace:
         # group size, or the merge worker's achieved cross-FLEET width
         # — docs/MERGETIER.md); None for per-document merges
         self.batch_width: Optional[int] = None
+        # the longest any member ticket waited in its queue, admission
+        # to drain (stamped by the scheduler as it fuses the round)
+        self.queue_wait_ms: Optional[float] = None
 
     @contextlib.contextmanager
     def stage(self, name: str, span_name: Optional[str] = None):

@@ -1001,7 +1001,7 @@ class ServingEngine:
             doc.admission_rejected += 1
             raise QueueFull(doc_id, len(doc.queue), doc.retry_after_s())
         t0 = time.perf_counter()
-        with profiling.span("serve.parse"):
+        with profiling.span("serve.parse", doc=doc_id, trace_id=tid):
             packed, n = self._parse(body)
         ticket = WriteTicket(packed, n, trace_id=tid,
                              parse_ms=(time.perf_counter() - t0) * 1e3)
@@ -1109,6 +1109,7 @@ class ServingEngine:
                 "batch_width": ct.batch_width,
                 "chunk_count": ct.chunk_count,
                 "queue_depth_admission": ct.queue_depth_admission,
+                "queue_wait_ms": ct.queue_wait_ms,
                 "stages_ms": ct.stage_breakdown(),
                 "total_ms": round(ct.total_ms, 3),
                 "staleness_s": None if ct.staleness_s is None

@@ -78,7 +78,8 @@ class WriteTicket:
     handler-thread wire-parse time this request cost, and
     ``depth_at_admission`` the queue depth observed when the ticket was
     accepted — together the per-request half of the commit's stage
-    breakdown."""
+    breakdown.  ``enqueued_at`` (admission, monotonic clock) gives the
+    ticket's queue wait when the scheduler drains it."""
 
     __slots__ = ("packed", "n_leaves", "enqueued_at",
                  "done", "accepted", "applied_count", "applied_op",

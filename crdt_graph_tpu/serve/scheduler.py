@@ -35,6 +35,7 @@ itself stays up.
 """
 from __future__ import annotations
 
+import contextlib
 import sys
 import threading
 import time
@@ -209,13 +210,15 @@ class MergeScheduler(threading.Thread):
             # tickets — they are in no queue, so nothing else can —
             # and must not kill the scheduler thread
             try:
-                pending = self._process(self._fuse_all(drained))
-                if pending:
-                    self._barrier_and_submit(pending)
-                elif self.engine.sync_worker is not None:
-                    # rounds with nothing to fsync still service any
-                    # handed-back failures promptly
-                    self._service_failures()
+                with self._round(drained) as drained_at:
+                    pending = self._process(
+                        self._fuse_all(drained, drained_at))
+                    if pending:
+                        self._barrier_and_submit(pending)
+                    elif self.engine.sync_worker is not None:
+                        # rounds with nothing to fsync still service
+                        # any handed-back failures promptly
+                        self._service_failures()
             except Exception as e:  # noqa: BLE001 — thread boundary
                 self.engine.counters.add("scheduler_errors")
                 traceback.print_exc(file=sys.stderr)
@@ -260,7 +263,8 @@ class MergeScheduler(threading.Thread):
         self._round_inline = True
         try:
             if drained:
-                self._process(self._fuse_all(drained))
+                with self._round(drained) as drained_at:
+                    self._process(self._fuse_all(drained, drained_at))
         finally:
             self._round_inline = False
             # the flush() barrier must see a step()-driven round too
@@ -367,13 +371,32 @@ class MergeScheduler(threading.Thread):
         return [(doc, doc.queue.drain())
                 for doc in self.engine.docs() if len(doc.queue)]
 
-    def _fuse_all(self, drained: List[tuple]) -> List[_WorkItem]:
+    @contextlib.contextmanager
+    def _round(self, drained: List[tuple]):
+        """One drained round as the ``serve.round`` span, the root of the
+        round's spans on this thread.  Yields the drain time; each
+        ticket's wait from admission to it goes to the span registry as
+        ``serve.queue_wait``."""
+        drained_at = time.monotonic()
+        waits_ms = [(drained_at - t.enqueued_at) * 1e3
+                    for _, tickets in drained for t in tickets]
+        for ms in waits_ms:
+            profiling.record("serve.queue_wait", ms)
+        with profiling.span("serve.round", docs=len(drained),
+                            tickets=len(waits_ms),
+                            queue_wait_ms_max=round(max(waits_ms), 3)):
+            yield drained_at
+
+    def _fuse_all(self, drained: List[tuple],
+                  drained_at: float) -> List[_WorkItem]:
         """Fuse each document's drained deltas into one packed batch
         (scheduler thread, no locks held)."""
         work: List[_WorkItem] = []
         for doc, tickets in drained:
             doc.coalesce_width.observe(len(tickets))
             ct = CommitTrace(doc.doc_id, tickets)
+            ct.queue_wait_ms = round(max(
+                drained_at - t.enqueued_at for t in tickets) * 1e3, 3)
             spans: List[Tuple[int, int]] = []
             parts = []
             base = 0

@@ -115,6 +115,7 @@ from ..serve import (ECHO_LIMIT, QueueFull, SchedulerError,
                      SchedulerStopped, ServingEngine)
 from ..serve import watch as watch_mod
 from ..serve.watch import WatchClosed, WatchFull
+from ..utils import profiling
 from .store import DocumentStore
 
 # default and ceiling for one watch request's ops window (leaves): a
@@ -296,7 +297,8 @@ def make_handler(store: DocumentStore, max_body: int = DEFAULT_MAX_BODY):
                 return None
 
         def _body(self, n: int) -> bytes:
-            return self.rfile.read(n)
+            with profiling.span("serve.recv", bytes=n):
+                return self.rfile.read(n)
 
         def _read_trace_headers(self, snap, ae_lag_hdr=None) -> dict:
             """Read-path correlation headers (obs/trace.py): the served
@@ -560,6 +562,13 @@ def make_handler(store: DocumentStore, max_body: int = DEFAULT_MAX_BODY):
 
         def do_GET(self):
             doc_id, sub, query = self._route()
+            if doc_id is not None and sub in ("", "/ops"):
+                with profiling.span("serve.read", doc=doc_id):
+                    self._get(doc_id, sub, query)
+            else:
+                self._get(doc_id, sub, query)
+
+        def _get(self, doc_id, sub, query):
             if doc_id is None:
                 if sub == "/metrics":
                     self._send(200, {d: store.get(d).metrics()

@@ -1,4 +1,4 @@
 """Profiling, tracing, and table-introspection utilities."""
-from .profiling import table_stats, timed, trace
+from .profiling import table_stats, trace
 
-__all__ = ["table_stats", "timed", "trace"]
+__all__ = ["table_stats", "trace"]

@@ -1,74 +1,30 @@
 """Profiling and observability harness (SURVEY §5: the reference has none;
 the TPU framework owes timing + tracing around its merge path).
 
-- :func:`timed` — wall-clock statistics for any jitted callable, closed by
-  a forced device→host readback of the result; returns ``(stats,
-  result)`` so the float stats stay JSON-safe.  A readback, unlike
-  ``block_until_ready``, cannot return before the computation finished
-  on any backend (VERDICT round 2, Weak-1).  See bench.honest for the
-  full harness (fingerprint returns, bracketing audit).
+- :func:`span` / :func:`span_stats` — named wall-clock spans aggregated
+  into a process-wide registry (count, total, max) AND emitted as
+  ``jax.profiler.TraceAnnotation`` events, so under a profiler capture
+  every span sits on the device trace's clock with its args as event
+  stats and its parent given by nesting on its thread.  The serving
+  scheduler (serve/scheduler.py), the engine's staged apply
+  (engine.py) and the HTTP front end time their stages with it.
+- :func:`record` — an interval that starts on one thread and ends on
+  another (a ticket's queue wait): registry only.
 - :func:`trace` — context manager around ``jax.profiler`` emitting a
   TensorBoard-loadable trace directory.
 - :func:`table_stats` — structural summary of a merged NodeTable
   (fan-out, depth, tombstone load) for capacity planning and debugging.
-- :func:`span` / :func:`span_stats` — named wall-clock spans aggregated
-  into a process-wide registry; the always-on production counterpart of
-  :func:`trace` used by the serving scheduler (serve/scheduler.py) to
-  attribute commit latency to its stages (parse, merge, publish) without
-  a profiler attached.
 """
 from __future__ import annotations
 
 import contextlib
 import threading
 import time
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Dict
 
 import numpy as np
 
 import jax
-
-
-def _force(x):
-    """Forced device→host readback — the honest timing barrier.
-    Single source of truth: bench.honest.force."""
-    from ..bench.honest import force
-    return force(x)
-
-
-def timed(fn: Callable[..., Any], *args, repeats: int = 5,
-          warmup: int = 1) -> Tuple[Dict[str, float], Any]:
-    """Run ``fn(*args)`` with warmup, return ``(stats, result)``:
-    a pure-float ms stats dict and the last repeat's (forced) result.
-
-    The result used to ride INSIDE the stats dict under a ``"result"``
-    key, which made the "timing stats" a mixed bag of floats and device
-    values — callers that serialized or aggregated the stats dragged an
-    array along (ISSUE 5 satellite).  The two concerns are now separate
-    return values; ``stats`` is JSON-safe by construction.
-
-    Each timed repeat ends with a full readback of the result; for large
-    results prefer returning a scalar fingerprint from ``fn`` (see
-    bench.honest.fingerprint) so transfer cost stays out of the number.
-    """
-    out = None
-    t0 = time.perf_counter()
-    for _ in range(max(1, warmup)):
-        out = _force(fn(*args))
-    first = time.perf_counter() - t0
-    times = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = _force(fn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    stats = {
-        "p50_ms": times[len(times) // 2] * 1e3,
-        "min_ms": times[0] * 1e3,
-        "max_ms": times[-1] * 1e3,
-        "warmup_ms": first * 1e3,
-    }
-    return stats, out
 
 
 _spans: Dict[str, Dict[str, float]] = {}
@@ -76,24 +32,33 @@ _spans_lock = threading.Lock()
 
 
 @contextlib.contextmanager
-def span(name: str):
-    """``with span("serve.merge"): ...`` — accumulate the block's wall
-    time under ``name`` in the process-wide span registry (thread-safe;
-    the registry lock is held only for the counter update, never across
-    the timed block)."""
+def span(name: str, **args):
+    """``with span("serve.merge", doc=d): ...`` — accumulate the block's
+    wall time under ``name`` in the process-wide span registry
+    (thread-safe; the registry lock is held only for the counter
+    update, never across the timed block), and enter a
+    ``jax.profiler.TraceAnnotation(name, **args)`` around it.  ``args``
+    are small scalars; they land as event stats in a profiler
+    capture."""
     t0 = time.perf_counter()
     try:
-        yield
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield
     finally:
-        ms = (time.perf_counter() - t0) * 1e3
-        with _spans_lock:
-            s = _spans.get(name)
-            if s is None:
-                s = _spans[name] = {"count": 0, "total_ms": 0.0,
-                                    "max_ms": 0.0}
-            s["count"] += 1
-            s["total_ms"] += ms
-            s["max_ms"] = max(s["max_ms"], ms)
+        record(name, (time.perf_counter() - t0) * 1e3)
+
+
+def record(name: str, ms: float) -> None:
+    """Add one interval of ``ms`` under ``name`` to the span registry —
+    for an interval no single thread's block spans (it starts on one
+    thread and ends on another), so it has no trace event."""
+    with _spans_lock:
+        s = _spans.get(name)
+        if s is None:
+            s = _spans[name] = {"count": 0, "total_ms": 0.0, "max_ms": 0.0}
+        s["count"] += 1
+        s["total_ms"] += ms
+        s["max_ms"] = max(s["max_ms"], ms)
 
 
 def span_stats(prefix: str = "") -> Dict[str, Dict[str, float]]:

@@ -145,16 +145,6 @@ def test_table_stats():
     assert st["tombstones"] == 1 and st["max_depth"] == 2
 
 
-def test_timed_harness():
-    from crdt_graph_tpu.utils import timed
-    p = packed.pack([crdt.Add(1, (0,), "a")])
-    stats, result = timed(lambda: merge.materialize(p.arrays()).ts,
-                          repeats=2)
-    # stats is pure floats (JSON-safe); the device result rides separately
-    assert stats["p50_ms"] > 0 and result is not None
-    assert all(isinstance(v, float) for v in stats.values())
-
-
 def test_trace_starts_and_stops_profiler(monkeypatch, tmp_path):
     """profiling.trace wraps the block in start_trace/stop_trace, and
     stops the trace even when the block raises."""
