@@ -188,6 +188,9 @@ def render_engine(engine) -> str:
         ("crdt_oplog_segments_gc_total",
          "Cold segments folded into the base and collected",
          "segments_gc"),
+        ("crdt_oplog_direct_folds_total",
+         "Hot-tail spills sealed straight into the checkpoint base",
+         "direct_folds"),
         ("crdt_oplog_segment_loads_total",
          "Cold segment/base-chunk loads (cache misses)",
          "segment_loads"),

@@ -32,7 +32,11 @@ three physical tiers:
   chunks and rewrites at most the trailing partial one — O(1) chunks of
   write amplification — and a mid-history catch-up window loads ONLY
   its covering chunks.  Bootstrap then opens base + tail descriptors
-  instead of replaying history (:meth:`OpLog.open_dir`).
+  instead of replaying history (:meth:`OpLog.open_dir`).  A spill that
+  the fold behind it would take whole (a stable catch-up tail into an
+  empty cold tier) is sealed straight into base chunks — the "direct
+  fold", the same chunks with no segment written, reloaded and deleted
+  in between.
 
 **Reference-stable views.**  Readers never touch the live tier lists:
 :meth:`OpLog.view` freezes the current physical layout into an
@@ -1243,6 +1247,8 @@ class OpLog:
         self.spills = 0
         self.compactions = 0
         self.segments_gc = 0
+        # spills sealed straight into the base (_fold_hot_locked)
+        self.direct_folds = 0
         self.gc_deferred = 0
         # scrub-with-peer-repair counters (crdt_scrub_* families)
         self.quarantines = 0
@@ -1577,7 +1583,8 @@ class OpLog:
                     deferred = True
                 else:
                     inline_fallback = self._defer_cb is not None
-                    self._spill_locked(min(excess, self._hot_len))
+                    self._spill_locked(min(excess, self._hot_len),
+                                       fold=True)
                     spilled = True
             if cfg.auto_stable:
                 self._stable = self._len
@@ -1628,12 +1635,13 @@ class OpLog:
                     max(0, int(extent) - self._tiered_len),
                     self._hot_len)
             if k > 0:
-                self._spill_locked(k)
+                self._spill_locked(k, fold=True)
                 spilled = True
             # chaos site: the background worker's spill landed (new
-            # manifest referencing the sealed segments) but the fold/GC
-            # pass has not run — recovery reopens the manifest and
-            # replays the WAL tail past it (docs/DURABILITY.md)
+            # manifest referencing the sealed segments, or the chunks
+            # of a direct fold) but the fold/GC pass has not run —
+            # recovery reopens the manifest and replays the WAL tail
+            # past it (docs/DURABILITY.md)
             _maybe_crash("mid-bg-fold")
             self._gc_locked()
             self._sweep_tombs_locked()
@@ -1853,14 +1861,32 @@ class OpLog:
             except Exception:   # noqa: BLE001 — owner callback boundary
                 pass
 
-    def _spill_locked(self, k: int) -> None:
+    def _direct_fold_due_locked(self, k: int) -> bool:
+        """Whether the fold behind a spill of the first ``k`` hot ops
+        would fold every segment that spill seals: the cold tier is
+        empty (nothing earlier or quarantined folds first), the whole
+        range lies at or below the stability watermark, and the spill
+        seals at least ``gc_min_segs`` segments."""
+        cfg = self._cfg
+        return (not self._cold
+                and self._tiered_len + k <= self._stable_locked()
+                and -(-k // cfg.hot_ops) >= cfg.gc_min_segs)
+
+    def _spill_locked(self, k: int, fold: bool = False) -> None:
         """Seal the first ``k`` hot ops into ``~hot_ops``-sized cold
         segment files (bounded-segment GC granularity, bounded cold
         catch-up reads) — the whole prefix is taken in ONE pass, so a
         giant backlog costs one consolidation copy, not a re-copy of
         the shrinking remainder per segment.  Split segments are
         REPLACED by copies so views pinning the originals stay
-        intact."""
+        intact.
+
+        ``fold``: the caller runs :meth:`_gc_locked` in this same lock
+        hold.  When that fold would take every segment this spill seals
+        (:meth:`_direct_fold_due_locked`), the rows go straight into
+        checkpoint-base chunks instead — the same chunks the fold would
+        write, without sealing, reloading and deleting the segments in
+        between."""
         cfg = self._cfg
         take: List[Segment] = []
         left = k
@@ -1902,8 +1928,21 @@ class OpLog:
             else:
                 parts.append(packed_mod.select_rows(
                     seg.packed, np.arange(seg.start, seg.stop)))
+        if fold and self._direct_fold_due_locked(k):
+            self._fold_hot_locked(parts, k)
+        else:
+            self._seal_segments_locked(parts, k)
+        # the age clock restarts: the spilled prefix was the oldest
+        self._hot_since = time.monotonic() if self._hot_len else None
+        self._durable_manifest_locked()
+
+    def _seal_segments_locked(self, parts: List[PackedOps],
+                              k: int) -> None:
+        """The segment half of :meth:`_spill_locked`: ``parts`` (the
+        first ``k`` hot ops, in log order) as cold segment files."""
+        cfg = self._cfg
         p = packed_mod.concat_many(parts)
-        seg_ops = max(cfg.hot_ops, 1)
+        seg_ops = cfg.hot_ops
         for s in range(0, k, seg_ops):
             e = min(s + seg_ops, k)
             piece = p if (s == 0 and e == p.num_ops) else \
@@ -1924,9 +1963,72 @@ class OpLog:
             # replay the untruncated WAL over it (the stray files are
             # unreferenced and harmlessly overwritten later)
             _maybe_crash("mid-spill")
-        # the age clock restarts: the spilled prefix was the oldest
-        self._hot_since = time.monotonic() if self._hot_len else None
-        self._durable_manifest_locked()
+
+    def _fold_hot_locked(self, parts: List[PackedOps], k: int) -> None:
+        """The direct fold: ``parts`` (the first ``k`` hot ops, in log
+        order) sealed straight into checkpoint-base chunks — the layout
+        spill-then-fold leaves, with no cold segment in between."""
+        new_bases, rewritten = self._seal_base_locked(parts)
+        self.spills += len(new_bases) - len(self._bases) + len(rewritten)
+        self.compactions += 1
+        self.direct_folds += 1
+        self._bases = new_bases
+        self._tombs.extend(rewritten)
+        self._tiered_len += k
+        self._hot_len -= k
+        # chaos sites of both halves: the chunks are sealed, the
+        # manifest still describes the old layout — recovery reopens
+        # it (every file it names is kept until after the write) and
+        # replays the WAL over it; the new chunks are unreferenced
+        _maybe_crash("mid-spill")
+        _maybe_crash("mid-fold")
+
+    def _seal_base_locked(self, parts: List[PackedOps]
+                          ) -> Tuple[List[_ColdSeg], List[_ColdSeg]]:
+        """The checkpoint-base writer: ``parts`` (the rows that continue
+        the base, in log order) appended as chunks of
+        ``base_chunk_ops``.  The trailing partial chunk, unless
+        quarantined, is merged in first so chunks stay densely packed
+        (bounded catch-up reads).  A chunk that is exactly one whole
+        part with vouched hints is sealed as that part — the chunked
+        apply leaves its hot tail in parts of the chunk size — and the
+        rest are cut from the parts' union.  Returns ``(new_bases,
+        rewritten)``: the caller swaps the layout in and tombs the
+        rewritten chunk."""
+        cfg = self._cfg
+        chunk_ops = cfg.base_chunk_ops
+        new_bases = list(self._bases)
+        rewritten: List[_ColdSeg] = []
+        if new_bases and new_bases[-1].length < chunk_ops \
+                and not new_bases[-1].quarantined:
+            tail = new_bases.pop()
+            rewritten.append(tail)
+            parts = [tail.load(use_cache=False)] + list(parts)
+        start0 = (new_bases[-1].start + new_bases[-1].length) \
+            if new_bases else 0
+        whole: Dict[Tuple[int, int], PackedOps] = {}
+        n = 0
+        for part in parts:
+            if part.hints_vouched:
+                whole[(n, n + part.num_ops)] = part
+            n += part.num_ops
+        merged: Optional[PackedOps] = None
+        for s in range(0, n, chunk_ops):
+            e = min(s + chunk_ops, n)
+            piece = whole.get((s, e))
+            if piece is None:
+                if merged is None:
+                    merged = packed_mod.concat_many(parts)
+                piece = merged if (s == 0 and e == n) else \
+                    packed_mod.select_rows(merged, np.arange(s, e))
+            self._base_gen += 1
+            path = os.path.join(
+                cfg.dir, f"base-{start0 + s:012d}-{e - s}-"
+                         f"g{self._base_gen}.npz")
+            new_bases.append(_ColdSeg.seal(piece, start0 + s, path,
+                                           self._cache,
+                                           fsync=cfg.durable))
+        return new_bases, rewritten
 
     def run_gc(self) -> None:
         """Checkpoint advancement + segment GC, gated by the stability
@@ -1959,32 +2061,8 @@ class OpLog:
         # amplification is capped at one partial last chunk rewritten
         # per fold (never the whole base, which the pre-chunk layout
         # re-copied in full and therefore had to gate at base/2)
-        chunk_ops = cfg.base_chunk_ops
-        parts: List[PackedOps] = []
-        new_bases = list(self._bases)
-        rewritten: List[_ColdSeg] = []
-        if new_bases and new_bases[-1].length < chunk_ops \
-                and not new_bases[-1].quarantined:
-            # merge the trailing partial chunk with the fold input so
-            # chunks stay densely packed (bounded catch-up reads)
-            tail = new_bases.pop()
-            rewritten.append(tail)
-            parts.append(tail.load(use_cache=False))
-        parts.extend(cs.load(use_cache=False) for cs in fold)
-        merged = packed_mod.concat_many(parts)
-        start0 = (new_bases[-1].start + new_bases[-1].length) \
-            if new_bases else 0
-        for s in range(0, merged.num_ops, chunk_ops):
-            e = min(s + chunk_ops, merged.num_ops)
-            piece = merged if (s == 0 and e == merged.num_ops) else \
-                packed_mod.select_rows(merged, np.arange(s, e))
-            self._base_gen += 1
-            path = os.path.join(
-                cfg.dir, f"base-{start0 + s:012d}-{e - s}-"
-                         f"g{self._base_gen}.npz")
-            new_bases.append(_ColdSeg.seal(piece, start0 + s, path,
-                                           self._cache,
-                                           fsync=cfg.durable))
+        new_bases, rewritten = self._seal_base_locked(
+            [cs.load(use_cache=False) for cs in fold])
         # chaos site: the folded chunks exist on disk but the manifest
         # still references the old layout — whose files are only
         # deleted AFTER the manifest write below, so recovery from the
@@ -2562,6 +2640,7 @@ class OpLog:
                 "spills": self.spills,
                 "compactions": self.compactions,
                 "segments_gc": self.segments_gc,
+                "direct_folds": self.direct_folds,
                 "gc_deferred": self.gc_deferred,
                 # scrub & quarantine (docs/DURABILITY.md §Scrub)
                 "quarantines": self.quarantines,

@@ -580,6 +580,172 @@ def test_fold_rewrites_only_trailing_partial_chunk(tmp_path):
     assert list(log) == applied + chain_ops(8, 60)
 
 
+# -- direct fold: a stable spill sealed straight into base chunks -----------
+
+
+def _interleaved_chains(n_per):
+    a, b = chain_ops(1, n_per), chain_ops(2, n_per)
+    return [op for pair in zip(a, b) for op in pair]
+
+
+def _tier_files(dirpath, prefix):
+    return sorted(f for f in os.listdir(dirpath) if f.startswith(prefix))
+
+
+@pytest.mark.parametrize("hot_ops,chunk,n0,shape", [
+    pytest.param(16, 16, 0, "deletes", id="chunk-eq-segment"),
+    pytest.param(8, 24, 36, "deletes", id="trailing-partial-merged"),
+    pytest.param(8, 5, 20, "chains", id="chunk-lt-segment"),
+    pytest.param(16, 1000, 12, "deletes", id="one-chunk"),
+    pytest.param(512, 1024, 0, "chunked", id="chunked-apply-parts"),
+])
+def test_direct_fold_matches_spill_then_fold(tmp_path, hot_ops, chunk,
+                                             n0, shape):
+    """A stable spill sealed straight into base chunks leaves what the
+    segment path's spill-then-fold leaves: the same chunk files and
+    boundaries, the same columns, and reads — object iteration,
+    windows at every seam, the state fingerprint — identical to the
+    untiered ruler.  The reference side is forced onto the segment
+    path by raising ``gc_min_segs`` for the spill, then folded.  In
+    ``chunked`` the chunked apply leaves the hot tail in parts of the
+    chunk size, which the direct fold seals as they are."""
+    ops = {"deletes": lambda: mixed_ops(30),
+           "chains": lambda: _interleaved_chains(36),
+           "chunked": lambda: _interleaved_chains(1100)}[shape]()
+    plain = applied_log_tree(ops)
+    applied = list(plain._log)
+    ruler = plain.log_view().to_packed()
+
+    partial, folds0 = {}, {}
+
+    def build(name, direct):
+        t = engine.init(0)
+        t.enable_log_tiering(str(tmp_path / name), hot_ops=hot_ops,
+                             gc_min_segs=2, base_chunk_ops=chunk)
+        if n0:
+            # identical on both sides: may leave a partial base chunk
+            t.apply(op_mod.from_list(applied[:n0]))
+        partial[name] = [cs.path for cs in t._log._bases
+                         if cs.length < chunk]
+        folds0[name] = t._log.direct_folds
+        cfg = t._log._cfg
+        if not direct:
+            cfg.gc_min_segs = 10**9
+        if shape == "chunked":
+            t.apply_packed_chunked(packed_mod.pack(applied[n0:]), chunk)
+        else:
+            t.apply(op_mod.from_list(applied[n0:]))
+        if not direct:
+            cfg.gc_min_segs = 2
+            t._log.run_gc()
+        return t
+
+    d, s = build("direct", True), build("segs", False)
+    dl, sl = d._log, s._log
+    assert dl.direct_folds - folds0["direct"] == 1
+    assert sl.direct_folds - folds0["segs"] == 0
+    assert sl.segments_gc >= 2
+    if n0 == 36:
+        # the first commit left a partial chunk the direct fold merged
+        # with the new rows and rewrote
+        assert partial["direct"]
+        assert not set(partial["direct"]) & {cs.path for cs in dl._bases}
+    assert not _tier_files(tmp_path / "direct", "seg-")
+    assert not dl._cold and not sl._cold
+    # the same chunks: names (start, length, generation), columns
+    assert [(os.path.basename(cs.path), cs.start, cs.length)
+            for cs in dl._bases] == \
+        [(os.path.basename(cs.path), cs.start, cs.length)
+         for cs in sl._bases]
+    for a, b in zip(dl._bases, sl._bases):
+        pa, pb = a.load(), b.load()
+        for col in ("kind", "ts", "parent_ts", "anchor_ts", "depth",
+                    "paths", "value_ref", "parent_pos", "anchor_pos",
+                    "target_pos", "ts_rank"):
+            assert np.array_equal(getattr(pa, col)[:a.length],
+                                  getattr(pb, col)[:b.length]), col
+        assert pa.values == pb.values
+        assert pa.hints_vouched == pb.hints_vouched
+        assert np.array_equal(a.add_ts, b.add_ts)
+        assert np.array_equal(a.add_pos, b.add_pos)
+    assert dl.telemetry()["base_ops"] == sl.telemetry()["base_ops"] > 0
+    # reads: objects, windows and suffixes at every seam
+    assert list(dl) == list(sl) == applied
+    views = (d.log_view(), s.log_view())
+    adds = [op.ts for op in applied if isinstance(op, Add)]
+    sinces = [0] + adds + adds[-2:]
+    if shape == "chunked":
+        # each chunk seam, either side of it, and a sample between
+        sinces = [0] + adds[::97] + [
+            adds[i] for c in range(chunk, len(adds), chunk)
+            for i in (c - 2, c - 1, c)] + adds[-2:]
+    for since in sinces:
+        for limit in (1, 3, 5, 8, 16, 17, 1000):
+            want = engine.packed_since_window(ruler, since, limit)
+            for v in views:
+                assert v.window(since, limit) == want, (since, limit)
+        want = engine.packed_since_bytes(ruler, since)
+        for v in views:
+            assert v.since_bytes(since) == want, since
+    fps = {snapshot_mod.derive("d", 0, t).state_fingerprint()
+           for t in (d, s, plain)}
+    assert len(fps) == 1
+
+
+def _engagement_log(tmp_path, case):
+    """A log with an 8-op hot budget and ``gc_min_segs`` 4, driven into
+    the spill shape ``case`` names (the engagement test below)."""
+    log = OpLog()
+    log.enable_tiering(str(tmp_path / case), hot_ops=8, gc_min_segs=4,
+                       auto_stable=case != "watermark-below",
+                       base_chunk_ops=16)
+    if case == "cold-not-empty":
+        log.extend(chain_ops(1, 32))
+        log.maybe_spill()                   # 3 segments: no fold yet
+        assert log.telemetry()["segments"]["cold"] == 3
+        log.extend(chain_ops(1, 48, start=33))
+        log.maybe_spill()
+        return log
+    n = {"catchup": 48, "few-segments": 32}.get(case, 48)
+    log.extend_packed(packed_mod.pack(chain_ops(1, n), max_depth=4))
+    if case == "watermark-below":
+        log.set_stable_mark(8)
+    if case == "spill-all":
+        log.spill_all()
+    else:
+        log.maybe_spill()
+    return log
+
+
+@pytest.mark.parametrize("case,want", [
+    # (direct_folds, compactions, segments_gc, cold segments, base ops)
+    ("catchup", (1, 1, 0, 0, 40)),
+    ("few-segments", (0, 0, 0, 3, 0)),
+    ("cold-not-empty", (0, 1, 9, 0, 72)),
+    ("watermark-below", (0, 0, 0, 5, 0)),
+    ("spill-all", (0, 0, 0, 6, 0)),
+])
+def test_direct_fold_engages_only_when_the_fold_would_take_every_segment(
+        tmp_path, case, want):
+    """A catch-up-shaped spill (at least ``gc_min_segs`` segments of
+    rows, all stable, cold tier empty) seals straight into the base:
+    one compaction, no segment collected.  Every other shape keeps the
+    cold segments it sealed before: a spill too small to fold, a cold
+    tier that must fold first, a watermark below the range, and
+    ``spill_all`` (whose caller runs no fold)."""
+    log = _engagement_log(tmp_path, case)
+    tele = log.telemetry()
+    got = (tele["direct_folds"], tele["compactions"],
+           tele["segments_gc"], tele["segments"]["cold"],
+           tele["base_ops"])
+    assert got == want, tele
+    # the sealed cold segments are on disk (a fold deletes them)
+    assert len(_tier_files(tmp_path / case, "seg-")) == want[3]
+    if case == "catchup":
+        assert tele["spills"] == len(log._bases) == 3
+    assert list(log) == chain_ops(1, len(log))
+
+
 # -- serving integration + exposition ----------------------------------------
 
 
@@ -618,6 +784,7 @@ def test_serving_engine_tiers_by_default_and_prom_round_trips():
         for fam in ("crdt_oplog_spills_total",
                     "crdt_oplog_compactions_total",
                     "crdt_oplog_segments_gc_total",
+                    "crdt_oplog_direct_folds_total",
                     "crdt_oplog_segment_loads_total",
                     "crdt_oplog_resident_bytes",
                     "crdt_oplog_stable_mark",
@@ -631,6 +798,10 @@ def test_serving_engine_tiers_by_default_and_prom_round_trips():
                   fams["crdt_oplog_spills_total"]["samples"]
                   if lbl["doc"] == doc_id]
         assert spills and spills[0] >= 1
+        folds = [v for _, lbl, v in
+                 fams["crdt_oplog_direct_folds_total"]["samples"]
+                 if lbl["doc"] == doc_id]
+        assert folds == [tele["direct_folds"]]
         # the spill scratch tier dies with the engine
         spill_dir = eng.oplog_dir
         assert os.path.isdir(spill_dir)

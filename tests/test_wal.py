@@ -469,6 +469,66 @@ def test_crash_point_matrix_zero_acked_loss(tmp_path, site, shared,
     eng2.close()
 
 
+@pytest.mark.parametrize("site", ["mid-spill", "mid-fold"])
+def test_direct_fold_crash_zero_acked_loss(tmp_path, site, monkeypatch):
+    """A catch-up-shaped commit (a fresh document's hot tail at least
+    ``gc_min_segs`` segments past its budget) seals straight into base
+    chunks; a kill at either site between those seals and the manifest
+    write leaves the old manifest plus unreferenced chunk files, and
+    recovery loses no acked write, serves byte-identical windows, and
+    direct-folds again past the strays."""
+    ddir = tmp_path / "dur"
+    eng = _durable_engine(ddir, submit_timeout_s=2.0)
+    ops = chain_ops(1, 80)
+    ok, _ = _submit(eng, "doc", ops[:5])
+    assert ok
+    acked = list(ops[:5])
+    assert eng.flush(30)
+    monkeypatch.setenv("GRAFT_CRASH_POINT", site)
+    crashed = {}
+
+    def doomed():
+        try:
+            crashed["ack"] = _submit(eng, "doc", ops[5:])
+        except SchedulerStopped:
+            crashed["ack"] = None
+
+    th = threading.Thread(target=doomed, daemon=True)
+    th.start()
+    eng.scheduler.join(30)
+    assert not eng.scheduler.is_alive(), f"site {site} never fired"
+    th.join(10)
+    assert crashed.get("ack") is None
+    monkeypatch.delenv("GRAFT_CRASH_POINT")
+    # the direct fold ran: chunks sealed, no segment, none referenced
+    files = os.listdir(ddir / "doc-doc")
+    stray = [f for f in files if f.startswith("base-")]
+    assert stray and not [f for f in files if f.startswith("seg-")]
+    if "manifest.json" in files:
+        with open(ddir / "doc-doc" / "manifest.json") as f:
+            listed = json.dumps(json.load(f))
+        assert not [f for f in stray if f in listed]
+    eng2 = _durable_engine(ddir)
+    doc2 = eng2.get("doc", create=False)
+    assert doc2 is not None and doc2.epoch == 2
+    vals = set(doc2.snapshot())
+    assert not [op.value for op in acked if op.value not in vals]
+    _windows_match_untiered(doc2.tree, sinces=(0, ts(1, 3)),
+                            limits=(0, 6))
+    # a second catch-up on the recovered doc folds directly again
+    log2 = doc2.tree._log
+    folds = log2.direct_folds
+    ok, _ = _submit(eng2, "doc", chain_ops(9, 80))
+    assert ok
+    assert eng2.flush(30)
+    assert log2.direct_folds == folds + 1
+    assert set(doc2.snapshot()) >= vals | {op.value
+                                           for op in chain_ops(9, 80)}
+    _windows_match_untiered(doc2.tree, sinces=(0, ts(9, 40)),
+                            limits=(0, 6))
+    eng2.close()
+
+
 # -- persisted materialization (ISSUE 11) -----------------------------------
 
 
