@@ -347,14 +347,14 @@ def phase_opsaxis(n_replicas: int = N_REPLICAS, n_ops: int = N_OPS,
           opsaxis.mesh_devices() == k,
           f"the serving route shards {n_ops} ops over {k} devices")
     seen = []
-    real = opsaxis._opsaxis_jit
+    real = opsaxis._opsaxis_materialize
 
     def spy(device_ops, *a, **kw):
         seen.extend(len(v.sharding.device_set)
                     for v in device_ops.values())
         return real(device_ops, *a, **kw)
 
-    opsaxis._opsaxis_jit = spy
+    opsaxis._opsaxis_materialize = spy
     try:
         t0 = time.perf_counter()
         sharded = opsaxis.routed_materialize(ops, "exhaustive")
@@ -362,7 +362,7 @@ def phase_opsaxis(n_replicas: int = N_REPLICAS, n_ops: int = N_OPS,
         log(f"ops-axis routed merge s (compile included): "
             f"{time.perf_counter() - t0:.3f}")
     finally:
-        opsaxis._opsaxis_jit = real
+        opsaxis._opsaxis_materialize = real
     check(seen and all(n == k for n in seen),
           f"every sharded operand spans {k} devices")
     t0 = time.perf_counter()

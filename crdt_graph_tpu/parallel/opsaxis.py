@@ -63,7 +63,15 @@ on hosts with ≥ 2 devices whose padded capacity the device count
 divides (engine.py) — the NodeTable shapes are then identical to the
 single-device path, so chunked-apply rollback and
 ``last_applied_mask`` attribution ride through unchanged; fingerprints
-and sync windows are pinned byte-identical flag on/off.
+and sync windows are pinned byte-identical flag on/off.  The sharded
+executable is ``jit__opsaxis_materialize`` in a profile (a merge, by
+name, to every reader that looks for ``materialize``); placing its
+columns on the mesh is the ``opsaxis.place`` span.  The route keeps one
+process-wide tally, counted in :func:`routed_materialize`: the serving
+engine's counters show it as ``opsaxis_merges``, ``opsaxis_rows`` (the
+bucket rows of routed merges) and ``kernel_rows`` (the bucket rows of
+every single-document kernel merge, routed or not), and
+:func:`stats` and the ``crdt_opsaxis_*`` families read the same counts.
 """
 from __future__ import annotations
 
@@ -82,7 +90,7 @@ from ..codec import packed as packed_mod
 from ..ops import fused_resolve, merge as merge_mod
 from ..ops import tour_scan
 from ..ops.merge import NodeTable
-from ..utils import hostenv
+from ..utils import hostenv, profiling
 
 AXIS = "ops"
 
@@ -310,8 +318,8 @@ def _cols_of(ops: Dict[str, np.ndarray]):
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "cols", "hints",
                                     "no_deletes"))
-def _opsaxis_jit(device_ops, mesh: Mesh, cols, hints,
-                 no_deletes: bool) -> NodeTable:
+def _opsaxis_materialize(device_ops, mesh: Mesh, cols, hints,
+                         no_deletes: bool) -> NodeTable:
     k = mesh.shape[AXIS]
 
     def body(*vals):
@@ -335,7 +343,7 @@ def _opsaxis_jit(device_ops, mesh: Mesh, cols, hints,
 
 
 _MESHES: Dict[int, Mesh] = {}
-_STATS = {"merges": 0, "devices": 0, "routed_ops": 0}
+_STATS = {"merges": 0, "devices": 0, "routed_ops": 0, "kernel_rows": 0}
 _STATS_LOCK = threading.Lock()
 
 
@@ -371,18 +379,18 @@ def materialize(ops: Dict[str, np.ndarray], k: Optional[int] = None,
     mesh = _mesh(k)
 
     def run():
-        device_ops = {
-            c: jax.device_put(
-                padded[c],
-                NamedSharding(mesh, P(AXIS) if padded[c].ndim == 1
-                              else P(AXIS, None)))
-            for c in cols}
-        return _opsaxis_jit(device_ops, mesh, cols, hints, no_deletes)
+        with profiling.span("opsaxis.place", rows=n_pad, k=k):
+            device_ops = {
+                c: jax.device_put(
+                    padded[c],
+                    NamedSharding(mesh, P(AXIS) if padded[c].ndim == 1
+                                  else P(AXIS, None)))
+                for c in cols}
+        return _opsaxis_materialize(device_ops, mesh, cols, hints,
+                                    no_deletes)
 
     with _STATS_LOCK:
-        _STATS["merges"] += 1
         _STATS["devices"] = k
-        _STATS["routed_ops"] += int(n)
         # shape signature of the last routed merge, kept so runtime
         # reporters (bench/loadgen) can re-derive the shard audit
         # without holding the arrays (shape-only tracing)
@@ -430,15 +438,30 @@ def routed_materialize(arrays: Dict[str, np.ndarray],
     table either way (pinned by tests/test_opsaxis.py through the
     serving path)."""
     n = int(arrays["kind"].shape[0])
-    if enabled_for(n):
+    routed = enabled_for(n)
+    with _STATS_LOCK:
+        _STATS["kernel_rows"] += n
+        if routed:
+            _STATS["merges"] += 1
+            _STATS["routed_ops"] += n
+    if routed:
         return materialize(arrays, hints=hints)
     return merge_mod.materialize(arrays, hints=hints)
+
+
+def route_counts() -> Dict[str, int]:
+    """The route's tally under the names the serving engine's counters
+    give it (module docstring)."""
+    with _STATS_LOCK:
+        return {"opsaxis_merges": _STATS["merges"],
+                "opsaxis_rows": _STATS["routed_ops"],
+                "kernel_rows": _STATS["kernel_rows"]}
 
 
 def stats() -> dict:
     """Routing counters for the prom scrape + scheduler metrics."""
     with _STATS_LOCK:
-        out = {k: v for k, v in _STATS.items() if k != "last"}
+        out = {k: _STATS[k] for k in ("merges", "devices", "routed_ops")}
     out["enabled"] = hostenv.flag_on("GRAFT_OPSAXIS")
     out["min_ops"] = route_min_ops()
     out["halo_rows"] = HALO
@@ -457,8 +480,8 @@ def _audit_traced(shapes: Dict[str, jax.ShapeDtypeStruct], k: int,
     mesh = _mesh(k)
 
     def fn(device_ops):
-        return _opsaxis_jit.__wrapped__(device_ops, mesh, cols, hints,
-                                        no_deletes)
+        return _opsaxis_materialize.__wrapped__(device_ops, mesh, cols,
+                                                hints, no_deletes)
 
     with jax.enable_x64(True):
         audit = chainaudit.count_mwide(fn, shapes)

@@ -637,6 +637,13 @@ class ServedDoc:
         }
 
 
+def _route_counts() -> Dict[str, int]:
+    """The ops-axis route's process-wide tally (parallel/opsaxis.py),
+    shown among every engine's counters."""
+    from ..parallel import opsaxis
+    return opsaxis.route_counts()
+
+
 class ServingEngine:
     """All documents hosted by this server, plus the merge scheduler.
 
@@ -816,7 +823,7 @@ class ServingEngine:
         self.cross_doc = cross_doc
         self.wire_fast_bytes = wire_fast_bytes
         self.submit_timeout_s = submit_timeout_s
-        self.counters = Counters()
+        self.counters = Counters(_route_counts)
         # the flight recorder is process-wide by default (like the span
         # registry): every commit resolved by this engine lands one
         # record, and dumps trigger on SLO breach / audit failure /

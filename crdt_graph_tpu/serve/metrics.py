@@ -17,7 +17,7 @@ the tail bucket truncates it).
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 # default bounds (ms for latencies, pure counts for widths): log-ish
 # spacing from sub-millisecond to tens of seconds
@@ -99,20 +99,27 @@ class Histogram:
 
 
 class Counters:
-    """A named bag of monotonically increasing integers (thread-safe)."""
+    """A named bag of monotonically increasing integers (thread-safe).
+    ``source``, when given, returns counts kept and counted elsewhere
+    (the ops-axis route's process-wide tally); :meth:`get` and
+    :meth:`snapshot` show them beside the bag's own."""
 
-    def __init__(self):
+    def __init__(self,
+                 source: Optional[Callable[[], Dict[str, int]]] = None):
         self._vals: Dict[str, int] = {}
         self._lock = threading.Lock()
+        self._source = source
 
     def add(self, name: str, n: int = 1) -> None:
         with self._lock:
             self._vals[name] = self._vals.get(name, 0) + n
 
     def get(self, name: str) -> int:
-        with self._lock:
-            return self._vals.get(name, 0)
+        return self.snapshot().get(name, 0)
 
     def snapshot(self) -> Dict[str, int]:
         with self._lock:
-            return dict(self._vals)
+            out = dict(self._vals)
+        if self._source is not None:
+            out.update(self._source())
+        return out

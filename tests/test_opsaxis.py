@@ -345,3 +345,51 @@ def test_pallas_ring_carry_interpret():
     got = np.asarray(jax.jit(fn)(jnp.asarray(vals)))
     want = np.concatenate([[0], np.cumsum(vals)[:-1]])
     np.testing.assert_array_equal(got, want)
+
+
+# -- the route's counters and span through the serving engine --------------
+
+def _chain_body(off, start, n):
+    from crdt_graph_tpu.codec import json_codec
+    from crdt_graph_tpu.core.operation import Add, Batch
+    ops, prev = [], 0
+    for c in range(start, start + n):
+        ops.append(Add(off + c, (prev,), f"v{c}"))
+        prev = off + c
+    return json_codec.dumps(Batch(tuple(ops)))
+
+
+def test_served_route_counts_rows_and_places(monkeypatch):
+    """A chunked push counts its routed merges and their bucket rows in
+    the engine's counters, and each routed merge is one
+    ``opsaxis.place`` span; a push whose candidate set stays under the
+    threshold counts its rows in ``kernel_rows`` only."""
+    from crdt_graph_tpu.obs import flight as flight_mod
+    from crdt_graph_tpu.serve import ServingEngine
+    from crdt_graph_tpu.utils import profiling
+    monkeypatch.setenv("GRAFT_OPSAXIS", "1")
+    monkeypatch.setenv("GRAFT_OPSAXIS_MIN_OPS", "2048")
+    eng = ServingEngine(chunk_ops=1024, flight=flight_mod.FlightRecorder())
+    keys = ("opsaxis_merges", "opsaxis_rows", "kernel_rows")
+
+    def push(doc, n):
+        c0 = eng.counters.snapshot()
+        p0 = profiling.span_stats("opsaxis.").get(
+            "opsaxis.place", {"count": 0})["count"]
+        ok, _ = eng.submit(doc, _chain_body(2 ** 32, 1, n))
+        assert ok and eng.flush(60)
+        c1 = eng.counters.snapshot()
+        p1 = profiling.span_stats("opsaxis.")["opsaxis.place"]["count"]
+        return tuple(c1[k] - c0.get(k, 0) for k in keys), p1 - p0
+
+    try:
+        # three chunks, candidate sets of 1024, 2048 and 3072 rows:
+        # buckets 1024, 2048 and 4096, the last two at the threshold or
+        # past it
+        assert push("routed", 3072) == ((2, 2048 + 4096,
+                                         1024 + 2048 + 4096), 2)
+        # one unchunked merge of a 1024-row bucket, under the threshold
+        assert push("local", 1024) == ((0, 0, 1024), 0)
+        assert eng.get("routed").tree.log_length == 3072
+    finally:
+        eng.close()
