@@ -27,8 +27,8 @@ Traffic shapes (mixed per run, assigned per session):
 - **shed-and-read** — a small admission queue turns bursts into 429s;
   a shed session issues reads while it backs off (reads must stay
   monotone THROUGH shedding);
-- **giant-merge racer** — one session pushes a chunk-spanning delta
-  while everyone else's reads race the chunked merge.
+- **giant-merge racer** — one session pushes a delta past the grouping
+  cap while everyone else's reads race its merge.
 
 Usage: ``python -m crdt_graph_tpu.bench.loadgen [sessions] [writes]``
 (ad hoc; the committed entry points are the tier-1 smoke in
@@ -719,8 +719,8 @@ def _run(cfg: LoadgenConfig, engine: ServingEngine,
     giant_err: List[str] = []
     giant_s = None
     if cfg.giant_ops:
-        # the giant-merge racer: one chunk-spanning push lands on doc 0
-        # mid-run while every session on that document keeps reading.
+        # the giant-merge racer: one push past the grouping cap lands on
+        # doc 0 mid-run while every session on that document keeps reading.
         # Under a small admission queue the giant gets shed like anyone
         # else — it backs off through the 429s until admitted.
         def giant():
@@ -1465,8 +1465,8 @@ def run_fleet(cfg: Optional[LoadgenConfig] = None) -> Dict[str, Any]:
 
 
 def _fleet_giant(h: _FleetHarness, state: Dict[str, Any]) -> None:
-    """The giant-merge racer, fleet flavor: a chunk-spanning push on
-    doc load0 whose primary the killer crashes mid-merge; the giant
+    """The giant-merge racer, fleet flavor: a push past the grouping cap
+    on doc load0 whose primary the killer crashes mid-merge; the giant
     survives by retrying (429 AND failover 503/connection loss) until
     a surviving primary acks it — CRDT idempotence makes the retry
     safe even if the dead primary had partially merged it."""

@@ -75,7 +75,7 @@ def _routed_materialize(arrays, hints):
     enabled and the candidate set is at or past
     GRAFT_OPSAXIS_MIN_OPS on a multi-device host.  The sharded table
     is bit-identical (shapes included — divisibility is part of the
-    route gate), so chunked-apply rollback, ``last_applied_mask``
+    route gate), so commit rollback, ``last_applied_mask``
     attribution, fingerprints, and sync windows ride through
     unchanged (pinned by tests/test_opsaxis.py)."""
     from .parallel import opsaxis
@@ -351,10 +351,6 @@ class TpuTree:
         # per-leaf applied mask of the last successful apply — the serving
         # scheduler's attribution channel for fused multi-client batches
         self._last_applied_mask: Optional[np.ndarray] = None
-        # cascade tiering (oplog.py): spills run only at commit
-        # boundaries; a multi-chunk apply defers them so a failing
-        # chunk's rollback target range is always still hot
-        self._defer_spill = False
         # persisted materialization (docs/DURABILITY.md): True when a
         # restore found a matz artifact in the manifest — the first
         # mirror build loads it and replays only the tail instead of
@@ -556,9 +552,9 @@ class TpuTree:
                 self._last_operation)
 
     def rollback_commit(self, saved: tuple) -> None:
-        """Undo everything since :meth:`begin_commit` (the chunked-
-        apply rollback recipe: truncate the log, restore clocks and
-        provenance, invalidate the materialized view)."""
+        """Undo everything since :meth:`begin_commit`: truncate the
+        log, restore clocks and provenance, invalidate the materialized
+        view."""
         n0, timestamp, replicas, last_op = saved
         self._log.truncate(n0)
         self._timestamp = timestamp
@@ -593,11 +589,10 @@ class TpuTree:
 
     def _after_commit(self) -> None:
         """Commit-boundary hook: run the cascade's spill/GC unless a
-        batch or chunked apply is mid-flight (their rollback paths
-        truncate back into what must still be the hot tier).  The
-        ``engine.spill`` span: inside ``engine.finish`` on a single-shot
-        apply, on its own after the last chunk of a chunked one."""
-        if self._batch_depth == 0 and not self._defer_spill:
+        batch is mid-flight (its rollback path truncates back into what
+        must still be the hot tier).  The ``engine.spill`` span, inside
+        ``engine.finish`` on a kernel apply."""
+        if self._batch_depth == 0:
             with profiling.span("engine.spill"):
                 self._log.maybe_spill()
 
@@ -675,16 +670,20 @@ class TpuTree:
         return self.apply_packed(
             native.parse_pack(payload, max_depth=self._max_depth))
 
-    def apply_packed(self, pnew: PackedOps, chunk: int = 0) -> "TpuTree":
+    def apply_packed(self, pnew: PackedOps) -> "TpuTree":
         """Remote apply from already-packed columns (the ingest fast
-        path's second half — see :meth:`apply_wire`).  ``chunk`` is the
-        chunk's index within :meth:`apply_packed_chunked`, for the
-        trace."""
+        path's second half — see :meth:`apply_wire`).  A delta that
+        routes to the kernel merges in ONE launch over log ∪ delta,
+        whatever its size: SET semantics over the whole batch, atomic,
+        raising exactly what :meth:`apply` raises.  Splitting a push
+        over several calls converges to the same state bit for bit
+        (tests/test_serving.py), but each call re-merges the whole log,
+        so the scheduler never splits one."""
         # below the bulk kernel crossover, keep apply()'s exact
         # sequence-semantics routing (host path / host-first)
         if not self.packed_route(pnew.num_ops):
             return self.apply(op_mod.from_list(packed_mod.unpack(pnew)))
-        p = self.prepare_packed(pnew, chunk=chunk)
+        p = self.prepare_packed(pnew)
         # device table; only the status column reads back here (table()
         # converts the rest lazily, off the serving path).  The launch
         # span holds the dispatch and the host-to-device copy; the
@@ -701,7 +700,7 @@ class TpuTree:
         documents into one batched materialization."""
         return n >= max(4 * DELTA_THRESHOLD, len(self._log) // 8)
 
-    def prepare_packed(self, pnew: PackedOps, chunk: int = 0) -> PackedOps:
+    def prepare_packed(self, pnew: PackedOps) -> PackedOps:
         """Stage 1 of the staged kernel apply: the candidate column set
         (current log ∪ delta) whose materialization yields the new view,
         with its slot hints derived (``p.arrays()`` caches them on the
@@ -711,7 +710,7 @@ class TpuTree:
         :meth:`finish_packed`, or just call :meth:`apply_packed`.  The
         ``engine.prepare`` span."""
         with profiling.span("engine.prepare",
-                            rows=len(self._log) + pnew.num_ops, chunk=chunk):
+                            rows=len(self._log) + pnew.num_ops):
             p = packed_mod.concat(self._ensure_packed(), pnew)
             p.arrays()
         return p
@@ -786,63 +785,6 @@ class TpuTree:
             ((ts_col >> 32) == self._replica)))
         self._last_applied_mask = np.asarray(st == APPLIED)
         self._after_commit()
-
-    def apply_packed_chunked(self, pnew: PackedOps,
-                             chunk_ops: int) -> "TpuTree":
-        """:meth:`apply_packed` with the kernel work split into row
-        chunks of at most ``chunk_ops`` leaves, so one bootstrap-size
-        push never holds the scheduler in a single giant launch (and
-        never compiles a giant jit bucket).  Atomicity is preserved: a
-        failing chunk rolls the log, clocks, and view back to the
-        pre-call state, then the whole batch is retried single-shot —
-        which also covers the one semantic gap (SET-semantics batches
-        whose later rows anchor earlier rows' dependants ACROSS a chunk
-        boundary would reject chunked but absorb single-shot).  On
-        success the converged state is bit-identical to the single-shot
-        apply (pinned by tests/test_serving.py): the log holds the same
-        rows in the same order, only split across more column segments.
-        """
-        n = pnew.num_ops
-        if n <= chunk_ops or not self.packed_route(n):
-            return self.apply_packed(pnew)
-        n0 = len(self._log)
-        saved = (self._timestamp, dict(self._replicas),
-                 self._last_operation)
-        masks: List[np.ndarray] = []
-        # spills defer until the LAST chunk commits: a failing chunk
-        # truncates back to n0, which must still be in the hot tier
-        defer0 = self._defer_spill
-        self._defer_spill = True
-        try:
-            for k, s in enumerate(range(0, n, chunk_ops)):
-                chunk = packed_mod.select_rows(
-                    pnew, np.arange(s, min(s + chunk_ops, n)))
-                self.apply_packed(chunk, chunk=k)
-                masks.append(self._last_applied_mask)
-        except (OperationFailedError, InvalidPathError):
-            # a chunk rejected: restore the pre-call state and decide
-            # with one single-shot apply — identical outcome (applied
-            # set or raised error) to the unchunked path
-            self._log.truncate(n0)
-            (self._timestamp, self._replicas,
-             self._last_operation) = saved
-            self._invalidate()
-            self._defer_spill = defer0
-            return self.apply_packed(pnew)
-        finally:
-            self._defer_spill = defer0
-        mask = np.concatenate(masks) if masks else np.zeros(0, bool)
-        applied = int(mask.sum())
-        if applied == n:
-            self._last_operation = PackedBatch(pnew)
-        elif applied:
-            self._last_operation = PackedBatch(
-                packed_mod.select_rows(pnew, np.nonzero(mask)[0]))
-        else:
-            self._last_operation = Batch(())
-        self._last_applied_mask = mask
-        self._after_commit()
-        return self
 
     def _apply_kernel(self, leaves: List[Operation]) -> List[Operation]:
         p = packed_mod.concat(self._ensure_packed(),

@@ -9,7 +9,7 @@ post-mortem.  This package ties them together per commit:
 
 - :mod:`~crdt_graph_tpu.obs.trace` — a ``trace_id`` minted at HTTP
   admission rides the write ticket through the coalescing scheduler,
-  chunked merges, and snapshot publish; a :class:`CommitTrace` collects
+  merges, and snapshot publish; a :class:`CommitTrace` collects
   the per-commit stage breakdown as the scheduler works.
 - :mod:`~crdt_graph_tpu.obs.flight` — a bounded ring of per-commit
   records with automatic JSONL dumps on SLO breach, chain-audit

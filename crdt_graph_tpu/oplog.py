@@ -1918,17 +1918,23 @@ class OpLog:
         k -= left
         if k <= 0:
             return
-        parts: List[PackedOps] = []
+        direct = fold and self._direct_fold_due_locked(k)
+        parts: List[Union[PackedOps, _PackedSeg]] = []
         for seg in take:
             if isinstance(seg, list):
                 parts.append(packed_mod.pack(
                     seg, max_depth=cfg.max_depth))
             elif seg.start == 0 and seg.stop == seg.packed.num_ops:
                 parts.append(seg.packed)
+            elif direct:
+                # the base writer cuts its chunks from the row range
+                # itself: copying the range first would copy (and
+                # re-derive the hints of) every row twice
+                parts.append(seg)
             else:
                 parts.append(packed_mod.select_rows(
                     seg.packed, np.arange(seg.start, seg.stop)))
-        if fold and self._direct_fold_due_locked(k):
+        if direct:
             self._fold_hot_locked(parts, k)
         else:
             self._seal_segments_locked(parts, k)
@@ -1964,7 +1970,8 @@ class OpLog:
             # unreferenced and harmlessly overwritten later)
             _maybe_crash("mid-spill")
 
-    def _fold_hot_locked(self, parts: List[PackedOps], k: int) -> None:
+    def _fold_hot_locked(self, parts: List[Union[PackedOps, _PackedSeg]],
+                         k: int) -> None:
         """The direct fold: ``parts`` (the first ``k`` hot ops, in log
         order) sealed straight into checkpoint-base chunks — the layout
         spill-then-fold leaves, with no cold segment in between."""
@@ -1983,16 +1990,16 @@ class OpLog:
         _maybe_crash("mid-spill")
         _maybe_crash("mid-fold")
 
-    def _seal_base_locked(self, parts: List[PackedOps]
+    def _seal_base_locked(self, parts: List[Union[PackedOps, _PackedSeg]]
                           ) -> Tuple[List[_ColdSeg], List[_ColdSeg]]:
         """The checkpoint-base writer: ``parts`` (the rows that continue
-        the base, in log order) appended as chunks of
-        ``base_chunk_ops``.  The trailing partial chunk, unless
-        quarantined, is merged in first so chunks stay densely packed
-        (bounded catch-up reads).  A chunk that is exactly one whole
-        part with vouched hints is sealed as that part — the chunked
-        apply leaves its hot tail in parts of the chunk size — and the
-        rest are cut from the parts' union.  Returns ``(new_bases,
+        the base, in log order: batches, or one row range of a batch)
+        appended as chunks of ``base_chunk_ops``.  The trailing partial
+        chunk, unless quarantined, is merged in first so chunks stay
+        densely packed (bounded catch-up reads).  A chunk that is
+        exactly one whole part with vouched hints is sealed as that
+        part, and the rest are cut from the parts' union, or from the
+        batch of a lone row range.  Returns ``(new_bases,
         rewritten)``: the caller swaps the layout in and tombs the
         rewritten chunk."""
         cfg = self._cfg
@@ -2007,20 +2014,30 @@ class OpLog:
         start0 = (new_bases[-1].start + new_bases[-1].length) \
             if new_bases else 0
         whole: Dict[Tuple[int, int], PackedOps] = {}
-        n = 0
-        for part in parts:
-            if part.hints_vouched:
-                whole[(n, n + part.num_ops)] = part
-            n += part.num_ops
-        merged: Optional[PackedOps] = None
+        if len(parts) == 1 and isinstance(parts[0], _PackedSeg):
+            # one row range of one batch: each chunk is cut straight
+            # from the batch, not from a copy of the range
+            merged, off = parts[0].packed, parts[0].start
+            n = parts[0].stop - off
+        else:
+            parts = [packed_mod.select_rows(
+                p.packed, np.arange(p.start, p.stop))
+                if isinstance(p, _PackedSeg) else p for p in parts]
+            merged, off, n = None, 0, 0
+            for part in parts:
+                if part.hints_vouched:
+                    whole[(n, n + part.num_ops)] = part
+                n += part.num_ops
         for s in range(0, n, chunk_ops):
             e = min(s + chunk_ops, n)
             piece = whole.get((s, e))
             if piece is None:
                 if merged is None:
                     merged = packed_mod.concat_many(parts)
-                piece = merged if (s == 0 and e == n) else \
-                    packed_mod.select_rows(merged, np.arange(s, e))
+                piece = merged if (off == 0 and s == 0
+                                   and e == merged.num_ops) else \
+                    packed_mod.select_rows(merged,
+                                           np.arange(off + s, off + e))
             self._base_gen += 1
             path = os.path.join(
                 cfg.dir, f"base-{start0 + s:012d}-{e - s}-"

@@ -65,9 +65,9 @@ ECHO_LIMIT = 4096
 # the legacy and serving ingest routes share one crossover)
 WIRE_FAST_BYTES = 1 << 20
 
-# default kernel-launch chunk: a giant push merges as bounded row chunks
-# so no single launch (or jit bucket) is sized by the largest client
-DEFAULT_CHUNK_OPS = 1 << 17
+# the largest push (in ops) that may join a cross-document grouped
+# launch (serve/scheduler.py); a bigger one merges alone, in one launch
+DEFAULT_GROUP_MAX_OPS = 1 << 17
 
 # cascade op-log defaults (oplog.py; docs/OPLOG.md): served documents
 # tier their logs by default so long-lived docs and sustained write
@@ -267,8 +267,7 @@ class ServedDoc:
                 self.wal = wal_mod.Wal(perdoc_path)
             # raises typed WalError on mid-log corruption — a server
             # must never silently serve a partially replayed log
-            self.replay_stats = self.wal.replay_into(
-                self.tree, engine.chunk_ops)
+            self.replay_stats = self.wal.replay_into(self.tree)
             # replay-time spills noted truncations; nothing is in
             # flight, so fold them into the file now — and seed the
             # artifact cadence (the replay just built the mirror, so
@@ -653,7 +652,7 @@ class ServingEngine:
     def __init__(self, max_depth: int = 16, *,
                  max_queue_requests: int = 256,
                  max_queue_leaves: int = 4_000_000,
-                 chunk_ops: int = DEFAULT_CHUNK_OPS,
+                 group_max_ops: int = DEFAULT_GROUP_MAX_OPS,
                  cross_doc: bool = True,
                  wire_fast_bytes: int = WIRE_FAST_BYTES,
                  submit_timeout_s: float = 600.0,
@@ -819,7 +818,7 @@ class ServingEngine:
         self.external_stability = False
         self.max_queue_requests = max_queue_requests
         self.max_queue_leaves = max_queue_leaves
-        self.chunk_ops = chunk_ops
+        self.group_max_ops = group_max_ops
         self.cross_doc = cross_doc
         self.wire_fast_bytes = wire_fast_bytes
         self.submit_timeout_s = submit_timeout_s

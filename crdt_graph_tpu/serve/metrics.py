@@ -3,7 +3,7 @@
 The service's per-document counters (ops merged, dup absorbed, rejected
 batches — service/store.py) say what the CRDT did; these say what the
 SERVING ENGINE did around it: how deep the admission queues run, how wide
-the coalescer fuses, how many chunks a giant push split into, how long
+the coalescer fuses, how many kernel launches a commit made, how long
 commits take, and how stale the published read snapshot is.  Everything
 here is exported through the existing ``/metrics`` wire (per-doc keys
 plus ``GET /metrics/scheduler``), alongside the coarse stage spans in

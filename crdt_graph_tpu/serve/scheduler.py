@@ -12,13 +12,15 @@ Scheduling policy, per round:
    ticket's row span for per-request attribution.
 3. **Route** — fused batches above the engine's kernel crossover go to
    the batched kernel; when ≥2 documents route to the kernel in the same
-   round (and each fits one chunk), their candidate sets are padded to a
-   shared capacity and materialized in ONE vmapped launch over a
-   ``docs``-sharded mesh (parallel.mesh.batched_materialize) — documents
-   are independent, so this scales linearly across chips.  Everything
-   else merges per-document, with giant pushes split into bounded chunks
-   (``engine.apply_packed_chunked``) so p50 commit latency is set by the
-   chunk size, not the largest client.
+   round (and each holds at most the engine's ``group_max_ops``), their
+   candidate sets are padded to a shared capacity and materialized in ONE
+   vmapped launch over a ``docs``-sharded mesh
+   (parallel.mesh.batched_materialize) — documents are independent, so
+   this scales linearly across chips.  Everything else merges
+   per-document in one ``TpuTree.apply_packed`` call: a push that routes
+   to the kernel is one launch over log ∪ delta, whatever its size.  The
+   round holds the scheduler until that launch ends, so a giant push
+   delays every other document's commit in the round.
 4. **Attribute** — the engine's per-leaf applied mask, sliced by ticket
    span, gives each request its applied count / dup count / echo without
    materializing objects.  A fused batch that REJECTS (causality gap in
@@ -429,13 +431,14 @@ class MergeScheduler(threading.Thread):
                 self.engine.record_commit(doc, ct)
                 continue
             # cross-doc grouping wants one launch per round: batches
-            # that route to the kernel AND fit a single chunk — keyed by
+            # that route to the kernel AND hold at most group_max_ops
+            # ops (a bigger push merges alone) — keyed by
             # CANDIDATE (log ∪ delta) bucket so a big-log document never
             # pads small co-grouped documents up to its own capacity
             # (equal buckets = zero padding waste + shared vmap trace)
             if (self.engine.cross_doc
                     and doc.tree.packed_route(fused.num_ops)
-                    and fused.num_ops <= self.engine.chunk_ops):
+                    and fused.num_ops <= self.engine.group_max_ops):
                 cand = packed_mod._bucket(
                     max(1, doc.tree.log_length + fused.num_ops))
                 groups.setdefault(cand, []).append(item)
@@ -570,17 +573,15 @@ class MergeScheduler(threading.Thread):
 
     def _commit_single(self, item: _WorkItem) -> None:
         doc, tickets, fused, spans, ct = item
-        n = fused.num_ops
         doc._commit_saved = doc.tree.begin_commit()
         try:
             with ct.stage("merge"):
-                doc.tree.apply_packed_chunked(fused, self.engine.chunk_ops)
+                doc.tree.apply_packed(fused)
         except CRDTError:
             self._sequential(doc, tickets, ct)
             return
-        chunks = max(1, -(-n // self.engine.chunk_ops))
-        doc.chunks_launched += chunks
-        ct.chunk_count = chunks
+        doc.chunks_launched += 1
+        ct.chunk_count = 1
         self._attribute_and_publish(doc, tickets, spans,
                                     doc.tree.last_applied_mask, ct)
 
@@ -598,14 +599,12 @@ class MergeScheduler(threading.Thread):
                 continue
             try:
                 with ct.stage("merge"):
-                    doc.tree.apply_packed_chunked(t.packed,
-                                                  self.engine.chunk_ops)
+                    doc.tree.apply_packed(t.packed)
             except CRDTError:
                 self.engine.reject_ticket(doc, t)
                 any_rejected = True
             else:
-                ct.chunk_count += max(
-                    1, -(-t.n_leaves // self.engine.chunk_ops))
+                ct.chunk_count += 1
                 mask = doc.tree.last_applied_mask
                 self.engine.finish_ticket(doc, t, mask)
                 ct.applied_ops += int(mask.sum())

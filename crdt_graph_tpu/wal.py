@@ -612,7 +612,7 @@ class Wal:
 
     # -- recovery ----------------------------------------------------------
 
-    def replay_into(self, tree, chunk_ops: int = 1 << 17) -> Dict:
+    def replay_into(self, tree) -> Dict:
         """Re-apply the WAL tail into ``tree`` (a just-restored
         checkpoint base + cold segments, or a fresh tree) through the
         ordinary apply path, so dedup/ordering semantics are exactly
@@ -633,7 +633,7 @@ class Wal:
                 continue
             _, p = _decode_payload(payload)
             try:
-                tree.apply_packed_chunked(p, chunk_ops)
+                tree.apply_packed(p)
             except CRDTError as e:
                 raise WalError(
                     f"WAL record (end_pos {end_pos}) failed to "
@@ -1115,7 +1115,7 @@ class DocWalView:
         self.truncations += 1
         return dropped
 
-    def replay_into(self, tree, chunk_ops: int = 1 << 17) -> Dict:
+    def replay_into(self, tree) -> Dict:
         """Re-apply this document's pre-scanned shared records (same
         semantics as ``Wal.replay_into``: records at or below the
         restored extent skip, overlaps dup-absorb, a record that
@@ -1129,7 +1129,7 @@ class DocWalView:
                 continue
             _, _, p = _decode_shared_payload(payload)
             try:
-                tree.apply_packed_chunked(p, chunk_ops)
+                tree.apply_packed(p)
             except CRDTError as e:
                 raise WalError(
                     f"shared WAL record for {self.doc_id!r} "

@@ -55,7 +55,6 @@ from crdt_graph_tpu.obs import flight as flight_mod  # noqa: E402
 from crdt_graph_tpu.serve import ServingEngine  # noqa: E402
 from crdt_graph_tpu.serve import snapshot as snapshot_mod  # noqa: E402
 
-CHUNK = 1 << 17          # the serving engine's default kernel chunk
 HOT_OPS = 32768          # the cascade's default hot budget
 
 
@@ -178,14 +177,14 @@ def run(out_path: str = "BENCH_COLDPATH_r01_cpu.json",
     # jit warmup so the fleet legs (and the no-matz restores' merges)
     # measure steady-state work, not compilation
     warm = engine.init(0)
-    warm.apply_packed_chunked(p, CHUNK)
+    warm.apply_packed(p)
     del warm
 
     tiered = engine.init(0)
     tiered.enable_log_tiering(os.path.join(work, "live"),
                               hot_ops=HOT_OPS)
     t0 = time.perf_counter()
-    tiered.apply_packed_chunked(p, CHUNK)
+    tiered.apply_packed(p)
     ingest_s = time.perf_counter() - t0
     fp0 = snapshot_mod.derive("doc", 0, tiered).state_fingerprint()
     t0 = time.perf_counter()
@@ -244,8 +243,7 @@ def run(out_path: str = "BENCH_COLDPATH_r01_cpu.json",
         "rev": "r01_cpu",
         "at": time.strftime("%Y-%m-%d %H:%M:%S"),
         "n_ops": n,
-        "knobs": {"hot_ops": HOT_OPS, "chunk_ops": CHUNK,
-                  "base_chunk_ops": 131072,
+        "knobs": {"hot_ops": HOT_OPS, "base_chunk_ops": 131072,
                   "fleet": {"docs": fleet_docs,
                             "sessions": fleet_sessions,
                             "writes_per_session": fleet_writes}},

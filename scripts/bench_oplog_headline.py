@@ -14,7 +14,7 @@ DEFAULT knobs (GRAFT_OPLOG_HOT_OPS=32768, GC on).  Reports:
   tail, the cold add indexes, and the (empty at measure time) segment
   cache.
 - **restore**, at two milestones against the pre-cascade bootstrap
-  (full chunked replay): (a) SERVING-READY — the restored tree answers
+  (full replay): (a) SERVING-READY — the restored tree answers
   a correct anti-entropy window (the fleet-rejoin scenario; tier
   descriptors + indexes, no materialization) vs the replay reaching
   the same point, and (b) + FIRST READ — one full merge materializes
@@ -53,7 +53,6 @@ from crdt_graph_tpu.bench import workloads  # noqa: E402
 from crdt_graph_tpu.codec import packed as packed_mod  # noqa: E402
 from crdt_graph_tpu.serve import snapshot as snapshot_mod  # noqa: E402
 
-CHUNK = 1 << 17          # the serving engine's default kernel chunk
 HOT_OPS = 32768          # the cascade's default hot budget
 
 
@@ -84,18 +83,18 @@ def run(out_path: str = "BENCH_OPLOG_r01_cpu.json",
     n = p.num_ops
     tier_dir = tempfile.mkdtemp(prefix="graft-bench-oplog-")
 
-    # -- jit warmup: one full untimed chunked ingest compiles every
-    # progressive candidate bucket, so BOTH timed ingests below measure
-    # steady-state work, not compilation (the 2-core box's compile
-    # times would otherwise be billed to whichever ran first)
+    # -- jit warmup: one full untimed ingest compiles the candidate
+    # bucket, so BOTH timed ingests below measure steady-state work,
+    # not compilation (the 2-core box's compile times would otherwise
+    # be billed to whichever ran first)
     warm = engine.init(0)
-    warm.apply_packed_chunked(p, CHUNK)
+    warm.apply_packed(p)
     del warm
 
     # -- untiered twin: what the pre-cascade serving path kept -----------
     flat = engine.init(0)
     t0 = time.perf_counter()
-    flat.apply_packed_chunked(p, CHUNK)
+    flat.apply_packed(p)
     ingest_flat_s = time.perf_counter() - t0
     p_flat = flat.packed_state()
     # first anti-entropy pull builds the full ts→pos index
@@ -106,7 +105,7 @@ def run(out_path: str = "BENCH_OPLOG_r01_cpu.json",
     tiered = engine.init(0)
     tiered.enable_log_tiering(tier_dir, hot_ops=hot_ops)
     t0 = time.perf_counter()
-    tiered.apply_packed_chunked(p, CHUNK)
+    tiered.apply_packed(p)
     ingest_tiered_s = time.perf_counter() - t0
     tele = tiered._log.telemetry()
     tiered_resident = tiered._log.resident_bytes()
@@ -139,11 +138,11 @@ def run(out_path: str = "BENCH_OPLOG_r01_cpu.json",
     restored_values = restored.visible_values()
     restore_first_read_s = time.perf_counter() - t0
 
-    # the pre-cascade bootstrap: full chunked replay of the whole
+    # the pre-cascade bootstrap: full replay of the whole
     # history; sync windows are only correct once the replay finishes
     t0 = time.perf_counter()
     replayed = engine.init(0)
-    replayed.apply_packed_chunked(p, CHUNK)
+    replayed.apply_packed(p)
     body2, meta2 = replayed.log_view().window(probe_ts, 4096)
     replay_serving_s = time.perf_counter() - t0
     assert meta2["found"] and body2 == body
@@ -188,7 +187,7 @@ def run(out_path: str = "BENCH_OPLOG_r01_cpu.json",
         "bench": "oplog_cascade_headline",
         "rev": "r01_cpu",
         "n_ops": n,
-        "knobs": {"hot_ops": hot_ops, "chunk_ops": CHUNK,
+        "knobs": {"hot_ops": hot_ops,
                   "gc_min_segs": int(os.environ.get(
                       "GRAFT_OPLOG_GC_SEGS", 4))},
         "ingest_s": {"tiered": round(ingest_tiered_s, 3),

@@ -323,13 +323,13 @@ def test_slo_breach_dumps_full_stage_breakdown(tmp_path):
         engine.submit("slo", json_codec.dumps(
             Batch(tuple(chain_ops(1, 8)))), trace_id="slo-fast-commit")
         doc = engine.get("slo")
-        real = doc.tree.apply_packed_chunked
+        real = doc.tree.apply_packed
 
         def slow(*a, **k):
             time.sleep(0.3)
             return real(*a, **k)
 
-        doc.tree.apply_packed_chunked = slow
+        doc.tree.apply_packed = slow
         engine.submit("slo", json_codec.dumps(
             Batch(tuple(chain_ops(1, 8, counter0=8,
                                   anchor=OFFSET + 8)))),
@@ -394,7 +394,7 @@ def test_engine_exception_records_error_and_dumps(tmp_path):
         def boom(*a, **k):
             raise RuntimeError("injected launch failure")
 
-        doc.tree.apply_packed_chunked = boom
+        doc.tree.apply_packed = boom
         with pytest.raises(SchedulerError):
             engine.submit("err", json_codec.dumps(
                 Batch(tuple(chain_ops(1, 5, counter0=5,

@@ -598,6 +598,7 @@ def _tier_files(dirpath, prefix):
     pytest.param(8, 5, 20, "chains", id="chunk-lt-segment"),
     pytest.param(16, 1000, 12, "deletes", id="one-chunk"),
     pytest.param(512, 1024, 0, "chunked", id="chunked-apply-parts"),
+    pytest.param(512, 1024, 0, "one-batch", id="one-batch-row-range"),
 ])
 def test_direct_fold_matches_spill_then_fold(tmp_path, hot_ops, chunk,
                                              n0, shape):
@@ -607,11 +608,15 @@ def test_direct_fold_matches_spill_then_fold(tmp_path, hot_ops, chunk,
     windows at every seam, the state fingerprint — identical to the
     untiered ruler.  The reference side is forced onto the segment
     path by raising ``gc_min_segs`` for the spill, then folded.  In
-    ``chunked`` the chunked apply leaves the hot tail in parts of the
-    chunk size, which the direct fold seals as they are."""
+    ``chunked`` several ``apply_packed`` calls in one batch leave the
+    hot tail in parts of the chunk size, which the direct fold seals
+    as they are; in ``one-batch`` one ``apply_packed`` leaves one
+    batch, whose spilled row range the direct fold cuts its chunks
+    from."""
     ops = {"deletes": lambda: mixed_ops(30),
            "chains": lambda: _interleaved_chains(36),
-           "chunked": lambda: _interleaved_chains(1100)}[shape]()
+           "chunked": lambda: _interleaved_chains(1100),
+           "one-batch": lambda: _interleaved_chains(1100)}[shape]()
     plain = applied_log_tree(ops)
     applied = list(plain._log)
     ruler = plain.log_view().to_packed()
@@ -632,7 +637,14 @@ def test_direct_fold_matches_spill_then_fold(tmp_path, hot_ops, chunk,
         if not direct:
             cfg.gc_min_segs = 10**9
         if shape == "chunked":
-            t.apply_packed_chunked(packed_mod.pack(applied[n0:]), chunk)
+            # one batch: the spill waits for the last part's commit
+            p = packed_mod.pack(applied[n0:])
+            parts = [packed_mod.select_rows(
+                p, np.arange(a, min(a + chunk, p.num_ops)))
+                for a in range(0, p.num_ops, chunk)]
+            t.batch([lambda t, q=q: t.apply_packed(q) for q in parts])
+        elif shape == "one-batch":
+            t.apply_packed(packed_mod.pack(applied[n0:]))
         else:
             t.apply(op_mod.from_list(applied[n0:]))
         if not direct:

@@ -360,16 +360,16 @@ def _chain_body(off, start, n):
 
 
 def test_served_route_counts_rows_and_places(monkeypatch):
-    """A chunked push counts its routed merges and their bucket rows in
-    the engine's counters, and each routed merge is one
-    ``opsaxis.place`` span; a push whose candidate set stays under the
-    threshold counts its rows in ``kernel_rows`` only."""
+    """A push counts its routed merge and its bucket rows in the
+    engine's counters, and the routed merge is one ``opsaxis.place``
+    span; a push whose candidate set stays under the threshold counts
+    its rows in ``kernel_rows`` only."""
     from crdt_graph_tpu.obs import flight as flight_mod
     from crdt_graph_tpu.serve import ServingEngine
     from crdt_graph_tpu.utils import profiling
     monkeypatch.setenv("GRAFT_OPSAXIS", "1")
     monkeypatch.setenv("GRAFT_OPSAXIS_MIN_OPS", "2048")
-    eng = ServingEngine(chunk_ops=1024, flight=flight_mod.FlightRecorder())
+    eng = ServingEngine(flight=flight_mod.FlightRecorder())
     keys = ("opsaxis_merges", "opsaxis_rows", "kernel_rows")
 
     def push(doc, n):
@@ -383,12 +383,10 @@ def test_served_route_counts_rows_and_places(monkeypatch):
         return tuple(c1[k] - c0.get(k, 0) for k in keys), p1 - p0
 
     try:
-        # three chunks, candidate sets of 1024, 2048 and 3072 rows:
-        # buckets 1024, 2048 and 4096, the last two at the threshold or
-        # past it
-        assert push("routed", 3072) == ((2, 2048 + 4096,
-                                         1024 + 2048 + 4096), 2)
-        # one unchunked merge of a 1024-row bucket, under the threshold
+        # one merge, a candidate set of 3072 rows in a 4096 bucket,
+        # past the threshold
+        assert push("routed", 3072) == ((1, 4096, 4096), 1)
+        # one merge of a 1024-row bucket, under the threshold
         assert push("local", 1024) == ((0, 0, 1024), 0)
         assert eng.get("routed").tree.log_length == 3072
     finally:

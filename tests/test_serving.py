@@ -1,9 +1,9 @@
 """Serving engine: snapshot-isolated reads, coalescing scheduler,
-admission control, chunked merges, cross-document batched launches.
+admission control, one-launch merges, cross-document batched launches.
 
-The acceptance pins (ISSUE 1): readers see complete, monotonically
-advancing snapshots with sub-10ms latency while a bulk merge commits;
-chunked and single-shot merges are bit-identical; a full queue answers
+The acceptance pins: readers see complete, monotonically advancing
+snapshots with sub-10ms latency while a bulk merge commits; a push split
+over several applies equals one apply bit for bit; a full queue answers
 429 with Retry-After; fused batches attribute per-request outcomes
 exactly like sequential application.
 """
@@ -139,10 +139,9 @@ def _reader_soak(n_merge_ops, reader_seconds_after=0.0):
 
 def test_concurrent_readers_during_merge_soak():
     """Readers never block on (or observe) an in-flight merge: while a
-    multi-chunk catch-up merge commits, every read returns a complete,
-    monotonically advancing snapshot, p99 under 10 ms.  (140k ops = 2
-    chunks — the smallest shape that still exercises mid-chunk reads;
-    the slow 1M variant below holds the acceptance scale, ISSUE 12
+    catch-up merge commits, every read returns a complete, monotonically
+    advancing snapshot, p99 under 10 ms.  (140k ops, past the grouping
+    cap; the slow 1M variant below holds the acceptance scale within the
     tier-1 budget.)"""
     lat_ms, _ = _reader_soak(140_000)
     assert lat_ms, "no reads observed during the merge window"
@@ -187,7 +186,7 @@ def test_snapshot_isolated_reads_are_frozen():
         engine.close()
 
 
-# -- chunked merges --------------------------------------------------------
+# -- one-launch merges ------------------------------------------------------
 
 
 def _tree_fingerprint(t):
@@ -199,55 +198,101 @@ def _tree_fingerprint(t):
             t.log_length, {k: v.tobytes() for k, v in cols.items()})
 
 
-def test_chunked_merge_bit_identical_to_single_shot():
-    """apply_packed_chunked == apply_packed, bit for bit: same column
-    bytes, same clocks, same visible sequence — only the segment split
-    differs."""
+def test_split_push_bit_identical_to_single_shot():
+    """A push applied over several apply_packed calls == one call, bit
+    for bit: same column bytes, same clocks, same visible sequence —
+    only the log's segment split differs."""
     ops = chain_ops(2, 9000) + chain_ops(3, 9000)
     p = packed_mod.pack(ops)
     one = engine_mod.init(0)
     one.apply_packed(p)
-    chunked = engine_mod.init(0)
-    chunked.apply_packed_chunked(p, 2048)
-    f1, f2 = _tree_fingerprint(one), _tree_fingerprint(chunked)
+    split = engine_mod.init(0)
+    masks = []
+    for s in range(0, p.num_ops, 2048):
+        split.apply_packed(packed_mod.select_rows(
+            p, np.arange(s, min(s + 2048, p.num_ops))))
+        masks.append(split.last_applied_mask)
+    f1, f2 = _tree_fingerprint(one), _tree_fingerprint(split)
     assert f1[0] == f2[0] and f1[1] == f2[1] and f1[2] == f2[2] \
         and f1[3] == f2[3]
     assert f1[4] == f2[4], "column bytes diverged"
-    assert np.array_equal(one.last_applied_mask, chunked.last_applied_mask)
+    assert np.array_equal(one.last_applied_mask, np.concatenate(masks))
 
 
-def test_chunked_merge_atomic_rollback():
-    """A failing chunk leaves the tree exactly as before the call, and
-    the error matches the single-shot error."""
+def test_bulk_apply_packed_atomic_rollback():
+    """A failing kernel-size apply_packed leaves the tree exactly as
+    before the call, and raises what apply() raises on the same ops."""
     good = chain_ops(2, 3000)
     bad = good[:2500] + [Add(9 * OFFSET + 1, (123456789,), "orphan")]
     p = packed_mod.pack(bad)
     t = engine_mod.init(0)
     t.apply_packed(packed_mod.pack(chain_ops(1, 50)))
+    assert t.packed_route(p.num_ops)
     before = _tree_fingerprint(t)
-    with pytest.raises(crdt.OperationFailedError):
-        t.apply_packed_chunked(p, 512)
+    with pytest.raises(crdt.OperationFailedError) as packed_err:
+        t.apply_packed(p)
     assert _tree_fingerprint(t) == before
+    ref = engine_mod.init(0)
+    ref.apply_packed(packed_mod.pack(chain_ops(1, 50)))
+    with pytest.raises(crdt.OperationFailedError) as object_err:
+        ref.apply(Batch(tuple(bad)))
+    assert str(packed_err.value) == str(object_err.value)
 
 
-def test_serving_engine_chunked_equivalence():
-    """The same push through a tiny-chunk engine and a single-shot
-    engine publishes identical snapshots."""
-    body = json_codec.dumps(Batch(tuple(chain_ops(2, 6000))))
-    small = ServingEngine(chunk_ops=1024)
-    big = ServingEngine(chunk_ops=1 << 30)
+def test_serving_engine_big_push_one_launch():
+    """A push larger than the engine's grouping cap commits in one
+    kernel launch and publishes what a single apply_packed gives."""
+    from crdt_graph_tpu.obs import flight as flight_mod
+    from crdt_graph_tpu.utils import profiling
+    ops = chain_ops(2, 6000)
+    rec = flight_mod.FlightRecorder()
+    engine = ServingEngine(group_max_ops=1024, flight=rec)
+    c0 = profiling.span_stats().get("engine.launch", {"count": 0})["count"]
     try:
-        small.submit("d", body)
-        big.submit("d", body)
-        s1 = small.get("d").snapshot_view()
-        s2 = big.get("d").snapshot_view()
-        assert s1.values == s2.values
-        assert s1.clock == s2.clock
-        assert small.get("d").chunks_launched >= 6
-        assert big.get("d").chunks_launched == 1
+        engine.submit("d", json_codec.dumps(Batch(tuple(ops))))
+        snap = engine.get("d").snapshot_view()
+        assert engine.get("d").chunks_launched == 1
     finally:
-        small.close()
-        big.close()
+        engine.close()
+    assert profiling.span_stats()["engine.launch"]["count"] - c0 == 1
+    (r,) = rec.records()
+    assert r.chunk_count == 1
+    ref = engine_mod.init(0)
+    ref.apply_packed(packed_mod.pack(ops))
+    assert list(snap.values) == ref.visible_values()
+    assert snap.clock == ref._replicas
+
+
+def test_push_under_an_eighth_of_the_log_takes_one_launch(monkeypatch):
+    """A push of n ops onto a log of L rows with n >= max(1024, L/8),
+    whose 1024-op pieces are each under L/8, merges in ONE kernel
+    launch: no piece goes down the per-op host path."""
+    from crdt_graph_tpu.utils import profiling
+    base, n = 16384, 2048
+    ops = chain_ops(2, base + n)
+    engine = ServingEngine()
+    try:
+        engine.submit("d", json_codec.dumps(Batch(tuple(ops[:base]))))
+        tree = engine.get("d").tree
+        assert tree.packed_route(n) and not tree.packed_route(1024)
+
+        def no_host(*a, **k):
+            raise AssertionError("push took the host path")
+
+        monkeypatch.setattr(tree, "_apply_host", no_host)
+        c0 = profiling.span_stats().get(
+            "engine.launch", {"count": 0})["count"]
+        accepted, _ = engine.submit("d", json_codec.dumps(
+            Batch(tuple(ops[base:]))))
+        launches = profiling.span_stats()["engine.launch"]["count"] - c0
+        assert accepted and launches == 1
+        assert engine.get("d").chunks_launched == 2
+        snap = engine.get("d").snapshot_view()
+    finally:
+        engine.close()
+    ref = engine_mod.init(0)
+    ref.apply_packed(packed_mod.pack(ops))
+    assert list(snap.values) == ref.visible_values()
 
 
 # -- admission control -----------------------------------------------------
@@ -471,12 +516,12 @@ def test_scheduler_infrastructure_error_surfaces_as_scheduler_error():
         engine.submit("err", json_codec.dumps(
             Batch(tuple(chain_ops(1, 5)))))
         doc = engine.get("err")
-        real = doc.tree.apply_packed_chunked
+        real = doc.tree.apply_packed
 
         def boom(*a, **k):
             raise RuntimeError("injected launch failure")
 
-        doc.tree.apply_packed_chunked = boom
+        doc.tree.apply_packed = boom
         with pytest.raises(SchedulerError) as ei:
             engine.submit("err", json_codec.dumps(
                 Batch(tuple(chain_ops(1, 5, counter0=5,
@@ -484,7 +529,7 @@ def test_scheduler_infrastructure_error_surfaces_as_scheduler_error():
         assert isinstance(ei.value.__cause__, RuntimeError)
         assert engine.counters.get("scheduler_errors") == 1
         # scheduler survived: the next submit merges normally
-        doc.tree.apply_packed_chunked = real
+        doc.tree.apply_packed = real
         accepted, _ = engine.submit("err", json_codec.dumps(
             Batch(tuple(chain_ops(1, 5, counter0=5,
                                   anchor=OFFSET + 5)))))

@@ -1,7 +1,7 @@
 """Program spans on the profiler's clock: ``utils.profiling.span`` is a
 ``jax.profiler.TraceAnnotation`` as well as a registry entry, the
 scheduler times each ticket's queue wait, and the engine's staged apply
-times its prepare, launch, device wait and finish per chunk."""
+times its prepare, launch, device wait and finish."""
 import glob
 import threading
 import time
@@ -133,25 +133,24 @@ def test_queue_wait_of_a_held_delta(tmp_path):
 
 
 def test_chunked_apply_spans_each_chunk_inside_the_merge_stage(tmp_path):
-    """A push split into three chunks emits one each of the engine's
-    prepare, launch, sync and finish spans per chunk and one spill after
-    the last, and together they take no longer than the commit's merge
+    """A 3,072-op push merges in one launch: one each of the engine's
+    prepare, launch, sync and finish spans, the spill inside the
+    finish, and together they take no longer than the commit's merge
     stage."""
     rec = recorder(tmp_path)
-    engine = ServingEngine(chunk_ops=1024, flight=rec)
+    engine = ServingEngine(flight=rec)
     try:
         engine.submit("ch", chain_body(2, 3072))
         assert engine.flush(60)
     finally:
         engine.close()
     (r,) = rec.records()
-    assert r.chunk_count == 3
+    assert r.chunk_count == 1
     s = profiling.span_stats()
-    for name in ENGINE_SPANS:
-        assert s[name]["count"] == 3, name
-    assert s["engine.spill"]["count"] == 1
-    inside = sum(s[name]["total_ms"]
-                 for name in ENGINE_SPANS + ("engine.spill",))
+    for name in ENGINE_SPANS + ("engine.spill",):
+        assert s[name]["count"] == 1, name
+    assert s["engine.spill"]["total_ms"] <= s["engine.finish"]["total_ms"]
+    inside = sum(s[name]["total_ms"] for name in ENGINE_SPANS)
     assert 0 < inside <= r.stages_ms["merge"]
 
 
