@@ -97,14 +97,10 @@ def _mode(p: PackedOps) -> Optional[str]:
     tests/conftest.py, so any producer bug that breaks the vouch
     invariant fails loudly there instead of corrupting a merge.
 
-    Round-7 fusion flags: every merge the engine dispatches also honors
-    the trace-time ``GRAFT_FUSED_RESOLVE`` / ``GRAFT_FUSED_TAIL`` /
-    ``GRAFT_FUSED_SUPEROP`` / ``GRAFT_FUSED_SCAN`` kill-switches
-    (default ON; ops/merge._fused_flag) — the exhaustive mode's
-    elementwise resolution now consumes the host-elected ``win_row`` /
-    ``parent_row`` slot-hint columns, which the tripwire above audits
-    alongside the round-6 ones (``derive_slot_hints`` is the single
-    source for all six)."""
+    The exhaustive mode's elementwise resolution consumes the
+    host-elected ``win_row`` / ``parent_row`` slot-hint columns, which
+    the tripwire above audits alongside the others
+    (``derive_slot_hints`` is the single source for all six)."""
     if not p.hints_vouched:
         return None
     if os.environ.get("GRAFT_DEBUG_VOUCH"):

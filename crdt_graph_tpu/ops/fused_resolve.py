@@ -255,18 +255,6 @@ def plane_rows2(plane: jax.Array, idx: jax.Array, hop_col: int,
     run, interpret = pallas_mode.select(use_pallas, interpret)
     if not run or plane.dtype != jnp.int64 or c4 > MAX_LANES:
         return _lax_rows2(plane, idx, hop_col)
-    from ..utils import hostenv
-    if not hostenv.flag_on("GRAFT_FUSED_SUPEROP"):
-        # kill-switch for the 2-hop kernel ALONE: the first hop keeps
-        # the validated round-6 single-hop sweep, the second is the lax
-        # gather — so a superop problem on a live chip can be disabled
-        # without also giving up the host winner-election/parent_row
-        # resolution (GRAFT_FUSED_RESOLVE gates those)
-        g = plane_rows(plane, idx, use_pallas=True, interpret=interpret)
-        hop = g[:, hop_col]
-        i2 = jnp.clip(hop, 0, r - 1).astype(jnp.int32)
-        g2 = jnp.where((hop >= 0)[:, None], _lax_rows(plane, i2), 0)
-        return g, g2
 
     tiles = -(-t // TILE)
     t_pad = tiles * TILE

@@ -177,52 +177,6 @@ S_CAP2_DEFAULT = 1 << 12
 R_CAP2_DEFAULT = 1 << 12
 
 
-def _fused_flag(name: str) -> bool:
-    """Trace-time kill-switch for one round-7 fusion (default ON).
-
-    - ``GRAFT_FUSED_RESOLVE``: host-elected winner frame (``win_row``)
-      + second-hop parent frame (``parent_row``) replace the winner
-      scatter-min and the ``[M, D+1]`` parent-row gather on the vouched
-      fused path.
-    - ``GRAFT_FUSED_TAIL``: structural tail cuts shared by every
-      backend — scatter-free run starts (searchsorted over the sorted
-      run ids), scatter-free crowded-row compaction, the static
-      ``visible_order ≡ order`` identity + single-weight rank pipeline
-      under the no-deletes promise, and the conditional grandvalid
-      status gather.
-    - ``GRAFT_FUSED_SUPEROP``: the two dependent node-frame gathers
-      ride ONE pallas 2-hop bounded-span sweep on TPU
-      (ops/fused_resolve.plane_rows2).
-    - ``GRAFT_FUSED_SCAN``: the tour/weight prefix sums ride ONE pallas
-      sequential-grid scan on TPU (ops/tour_scan).
-
-    ``=0`` restores the round-6 trace for that piece (the A/B's B leg,
-    scripts/probe_fusedab.py runs all four together).  Same trace-time
-    caveats as :func:`_env_cap` (logged on every retrace; parse+log
-    shared with ops/fused_resolve via utils.hostenv.flag_on —
-    GRAFT_FUSED_SUPEROP is consumed there)."""
-    from ..utils import hostenv
-    return hostenv.flag_on(name)
-
-
-def _pack_gather_on() -> bool:
-    """Trace-time flag GRAFT_PACK_GATHER: gathers (and compaction
-    scatters) that share an index vector ride ONE multi-column plane
-    row access instead of one pass per column.  Every M-wide random
-    gather cost ~6 ms of device time at 1M on v5e regardless of
-    payload width in round 5 (scripts/probe_prims.py, its while-loop
-    row), so row-plane packing removes most of stages 1-2's separate
-    memory ops — the chain-length budget (utils/chainaudit.py, pinned
-    ≤16 in CI) assumes it, and it is therefore DEFAULT ON as of round 6
-    (the cost model says plane rows price like one gather;
-    ``GRAFT_PACK_GATHER=0`` remains the one-command B leg of that A/B,
-    scripts/probe_packab.py).  Bit-identity of the two layouts is
-    pinned by tests/test_merge_kernel.py either way.  Same trace-time
-    caveats as _env_cap (logged on every retrace; parse+log shared via
-    utils.hostenv.flag_on)."""
-    from ..utils import hostenv
-    return hostenv.flag_on("GRAFT_PACK_GATHER")
-
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class NodeTable:
@@ -345,9 +299,7 @@ def _fix_min(val: jax.Array, ptr: jax.Array, active: jax.Array,
 def _sorted_slots_impl(is_add, ts, pos, N, M, ROOT, NULL):
     """Sort-based slot assignment (see the SORTED+JOIN contract in
     ``_materialize``): the first six tuple entries plus the sorted
-    timestamp axis the join needs.  Module-level so the explicitly
-    partitioned resolve (parallel/shard.py) shares the one
-    implementation with the whole-array kernel."""
+    timestamp axis the join needs."""
     sort_ts = jnp.where(is_add & (ts > 0), ts, BIG)
     ts_hi, ts_lo = _split_ts(sort_ts)
     # stable sort: equal timestamps keep batch order; per-node fields
@@ -395,9 +347,8 @@ def _join_ops_impl(sorted_ts, parent_ts, at_ts, N, ROOT, NULL):
     for Delete rows: downstream consumes the anchor resolution only at
     canonical Add rows and the target resolution only at Delete rows
     (_finish: af_pack scatter / d_tslot), so one resolution serves both
-    and the join shrinks from 3N to 2N queries.  Module-level so
-    hint-verified merges can defer it into a cond branch that never
-    executes, and so parallel/shard.py's fallback shares it."""
+    and the join shrinks from 3N to 2N queries.  Hint-verified merges
+    defer it into a cond branch that never executes."""
     queries = jnp.concatenate([parent_ts, at_ts])
     qidx = jnp.searchsorted(sorted_ts, queries, side="left",
                             method="sort").astype(jnp.int32)
@@ -425,20 +376,16 @@ def _node_cols_from_row(node_row, src_ts, src_pos, M, ROOT, N):
     each construction keeps; the ts/pos columns derive from it with one
     gather each instead of one scatter each (M-wide scatters have a
     large fixed per-element cost on v5e while random gathers are far
-    cheaper — scripts/probe_prims.py).  Shared by the ranked path, the
-    sorted fallback, and parallel/shard.py so the three constructions
-    cannot drift (their bit-identity is a pinned contract,
-    tests/test_shard_map.py).  Unused slots: ts = BIG (sorts last),
-    pos = IPOS; ROOT's ts overridden to 0."""
+    cheaper — scripts/probe_prims.py).  Shared by the ranked path and
+    the sorted fallback so the two constructions cannot drift.  Unused
+    slots: ts = BIG (sorts last), pos = IPOS; ROOT's ts overridden
+    to 0."""
     is_node_slot = node_row < jnp.int32(N)
     wc = jnp.where(is_node_slot, node_row, 0)
-    if _pack_gather_on():
-        # one [N, 2] i64 row gather instead of two column gathers
-        src = jnp.stack([src_ts, src_pos.astype(jnp.int64)], axis=-1)
-        g = src[wc]
-        got_ts, got_pos = g[:, 0], g[:, 1].astype(jnp.int32)
-    else:
-        got_ts, got_pos = src_ts[wc], src_pos[wc]
+    # one [N, 2] i64 row gather instead of two column gathers
+    src = jnp.stack([src_ts, src_pos.astype(jnp.int64)], axis=-1)
+    g = src[wc]
+    got_ts, got_pos = g[:, 0], g[:, 1].astype(jnp.int32)
     node_ts = jnp.where(is_node_slot, got_ts, BIG)
     node_ts = jnp.where(jnp.arange(M, dtype=jnp.int32) == ROOT,
                         jnp.int64(0), node_ts)
@@ -468,25 +415,6 @@ def _plane_rows2(plane: jax.Array, idx: jax.Array, hop_col: int,
                                      use_pallas=use_pallas)
 
 
-def _resolve_sorted(ops: Dict[str, jax.Array]):
-    """The full SORTED+JOIN resolution: the 10-tuple interface from raw
-    op columns, hint-free.  The whole-array kernel's fallback branch and
-    parallel/shard.py's post-gather fallback both call this."""
-    kind = ops["kind"]
-    ts = ops["ts"].astype(jnp.int64)
-    parent_ts = ops["parent_ts"].astype(jnp.int64)
-    anchor_ts = ops["anchor_ts"].astype(jnp.int64)
-    pos = ops["pos"].astype(jnp.int32)
-    N = kind.shape[0]
-    M = N + 2
-    is_add = kind == KIND_ADD
-    slots, sorted_ts = _sorted_slots_impl(
-        is_add, ts, pos, N, M, 0, M - 1)
-    return slots + _join_ops_impl(
-        sorted_ts, parent_ts, _at_ts(is_add, anchor_ts, ts),
-        N, 0, M - 1)
-
-
 def _pack_slot_or_neg(is_add, op_slot_arr):
     """``is_add`` and ``op_slot`` fused into one gatherable column:
     the op's slot for Add rows, -1 otherwise (op_slot is never negative,
@@ -503,8 +431,7 @@ def _res_hint_impl(hint, want, slot_or_neg, ts, N, ROOT, NULL,
     RANKED+HINTED contract in ``_materialize``).  ``miss`` flags any
     nonzero reference without a verified hint.  ``slot_or_neg`` (from
     :func:`_pack_slot_or_neg`) and ``ts`` are the summary columns the
-    hint indexes into — the local batch in the whole-array kernel, the
-    all-gathered global batch in parallel/shard.py.
+    hint indexes into (the whole batch).
 
     ``check_ts=True`` (auto mode) verifies each hint on device with a
     second gather (``ts[hint] == want``) — required for the "wrong
@@ -526,17 +453,6 @@ def _res_hint_impl(hint, want, slot_or_neg, ts, N, ROOT, NULL,
     return slot.astype(jnp.int32), (want == 0) | ok, miss
 
 
-def _probe_sum(*arrs):
-    """Stage-cut checksum: a scalar depending on every given array, so
-    honest timing (dispatch + forced readback) cannot skip the stage.
-    Only reachable when ``probe`` is set — never in production traces.
-    Delegates to bench.honest.fingerprint (lazy import; honest has no
-    ops dependency) so int64 leaves split into int32 halves on TPU —
-    a wide emulated modulo would bill the HARNESS to the stage."""
-    from ..bench.honest import fingerprint
-    return fingerprint(arrs)
-
-
 def crowding_hinted(ops, hints, no_deletes: bool) -> bool:
     """Trace-time predicate for the sibling-crowding static skip: the
     host derived (and VERIFIED — codec/packed.derive_crowding_hints)
@@ -556,21 +472,20 @@ def crowding_hinted(ops, hints, no_deletes: bool) -> bool:
     n = ops["kind"].shape[0] if "kind" in ops else 0
     compacted = _env_cap("GRAFT_S_CAP", S_CAP_DEFAULT) < n + 2
     return (have_slot and no_deletes and compacted and
-            "crowd_slot" in ops and "crowd_cpos" in ops and
-            _fused_flag("GRAFT_CROWD_HINTS"))
+            "crowd_slot" in ops and "crowd_cpos" in ops)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def _materialize(ops: Dict[str, jax.Array],
                  use_pallas: Optional[bool] = None,
                  hints: Optional[str] = None,
                  no_deletes: bool = False,
-                 probe: Optional[int] = None,
                  part=None) -> NodeTable:
     """``use_pallas``: pallas usage for every kernel of the trace
     (ops/pallas_mode.select: None = auto — Mosaic kernel on TPU
     backends, lax elsewhere; ``"interpret"`` = the interpreter);
-    wrappers whose transforms the pallas call must not see (vmapped batched merges, explicitly sharded merges) pass False —
+    wrappers whose transforms the pallas call must not see (vmapped
+    batched merges, the ops-axis shard_map body) pass False —
     a distinct static-arg jit entry, so traces never leak across.
 
     ``hints``: link-hint policy for timestamp resolution (step 4).
@@ -589,21 +504,6 @@ def _materialize(ops: Dict[str, jax.Array],
     common all-adds serving batch compiles and runs leaner.  A violated
     promise would silently ignore deletes, so only host-checked callers
     set it.
-
-    ``probe``: profiling cut point (scripts/probe_stages.py).  When set
-    to stage k, the trace TRUNCATES right after that stage and returns a
-    CUMULATIVE checksum folding every stage ≤ k — cumulative so the
-    cuts nest strictly (a per-stage-only checksum would let XLA
-    dead-code-eliminate earlier stages nothing downstream consumes, and
-    consecutive differences would misattribute); per-stage device time
-    is then genuinely the difference between consecutive cuts, measured
-    on the exact production trace (the old standalone probe mirrored
-    the kernel and drifted).  Cuts: 1 resolution | 2 frames+local
-    validity | 3 cascade+cycles | 4 deletes+dead | 5 NSA+sibling
-    sort+tour | 6 run contraction+Wyllie+expansion | 7 ranks+orders |
-    None full kernel.  Stage-5 SUB-cuts for adversarial attribution
-    (between 4 and 5, in code order): 41 NSA chase | 42 + lifting cond |
-    43 + sibling links.
 
     ``part``: optional ops-axis partition context
     (parallel/opsaxis.OpsAxisPart).  When set, the trace is being built
@@ -742,7 +642,7 @@ def _materialize(ops: Dict[str, jax.Array],
         has_rank = is_real_add & (rank >= 0) & (rank < N)
         op_slot_r = jnp.where(has_rank, rank + 1, NULL).astype(jnp.int32)
         # duplicate election: host-precomputed first-array-row-wins flag
-        if "win_row" in ops and _fused_flag("GRAFT_FUSED_RESOLVE"):
+        if "win_row" in ops:
             # winner frame host-elected too (codec.packed win_row): the
             # whole resolution stage is elementwise — zero M-wide
             # memory ops (round 7; the scatter-min was the last one)
@@ -831,23 +731,13 @@ def _materialize(ops: Dict[str, jax.Array],
     else:
         sel = _sorted_ops(None)
 
-    acc = _probe_sum(*(x for x in sel if x is not None)) \
-        if probe is not None else None
-    if probe == 1:
-        return acc
-    return _finish(ops, sel, use_pallas, no_deletes, probe=probe,
-                   acc=acc, part=part)
+    return _finish(ops, sel, use_pallas, no_deletes, part=part)
 
 
 def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
-            no_deletes: bool, probe: Optional[int] = None,
-            acc=None, part=None) -> NodeTable:
+            no_deletes: bool, part=None) -> NodeTable:
     """Stages 3-13: node-table construction through per-op statuses,
-    from the resolution interface (the 10-tuple ``sel``).  Extracted
-    from ``_materialize`` so the explicitly partitioned resolve
-    (parallel/shard.py) reuses the exact same downstream trace — bit
-    identity across the whole-array and shard_map paths is structural,
-    not merely tested-in.
+    from the resolution interface (the 10-tuple ``sel``).
 
     FUSED variant: a resolution built from host-derived slot hints
     passes ``node_ts = node_pos = None`` (and ships ``anchor_psl`` in
@@ -878,11 +768,9 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # below from the same plane gather as every other node column, so
     # the whole node-frame construction is ONE M-wide sweep.
     fused = node_ts is None
-    # round-7 structural tail cuts (one trace-time switch for all of
-    # them — scatter-free run starts/compaction, tiny compact levels,
-    # single-weight rank pipeline, conditional grandvalid statuses)
-    tail_on = _fused_flag("GRAFT_FUSED_TAIL")
-    single_w = no_deletes and tail_on
+    # under the no-deletes promise visible ≡ exists: the rank pipeline
+    # carries one weight lane (step 12)
+    single_w = no_deletes
 
     # ---- 3. Node-table construction from the SELECTED assignment —
     # shared across all branches, outside any cond, and SCATTER-FREE:
@@ -914,8 +802,7 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # 202 ms under the 2-hop lax fallback); the lax trace keeps the
     # round-6 fp-plane gather through pslot.
     dev_pallas, _ = pallas_mode.select(use_pallas)
-    fused2 = fused and "parent_row" in ops and _pack_gather_on() and \
-        dev_pallas and _fused_flag("GRAFT_FUSED_RESOLVE")
+    fused2 = fused and "parent_row" in ops and dev_pallas
     if fused:
         # hi = the anchor row's own parent resolution (what the sibling
         # check read as pslot[aslot]); lo = batch position; plus the raw
@@ -929,38 +816,22 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # hardcoded — a wrong hop column would rebuild parent frames from
     # whatever column sits there, silently corrupting validity)
     HOP_COL = 2 + len(extra) - 1
-    g2 = None
-    if _pack_gather_on():
-        # all nsr-indexed gathers ride one [N, D+2(+2|+3)] i64 plane row
-        plane = jnp.concatenate(
-            [dsv_src[:, None], pa[:, None]] + extra + [paths], axis=1)
-        if fused2:
-            g, g2 = _plane_rows2(plane, nsr, HOP_COL, use_pallas)
-        elif part is not None:
-            # ops-axis sharded: each device sweeps only its own slot
-            # range's rows through a halo window (span violation falls
-            # back to this very lax gather — parallel/opsaxis.py)
-            g = part.plane_rows(plane, nsr)
-        else:
-            g = _plane_rows(plane, nsr, use_pallas)
-        k = 2 + len(extra)
-        dsv, pa_g, claimed_raw = g[:, 0], g[:, 1], g[:, k:]
-        if fused:
-            ap_g, ts_g = g[:, 2], g[:, 3]
+    # all nsr-indexed gathers ride one [N, D+2(+2|+3)] i64 plane row
+    plane = jnp.concatenate(
+        [dsv_src[:, None], pa[:, None]] + extra + [paths], axis=1)
+    if fused2:
+        g, g2 = _plane_rows2(plane, nsr, HOP_COL, use_pallas)
     elif part is not None:
-        dsv = part.gather_rows(dsv_src, nsr)
-        pa_g = part.gather_rows(pa, nsr)
-        claimed_raw = part.gather_rows(paths, nsr)
-        if fused:
-            ap_g = part.gather_rows(ap_src, nsr)
-            ts_g = part.gather_rows(ts, nsr)
+        # ops-axis sharded: each device sweeps only its own slot
+        # range's rows through a halo window (span violation falls
+        # back to this very lax gather — parallel/opsaxis.py)
+        g = part.plane_rows(plane, nsr)
     else:
-        dsv = dsv_src[nsr]
-        pa_g = pa[nsr]
-        claimed_raw = paths[nsr]
-        if fused:
-            ap_g, ts_g = ap_src[nsr], ts[nsr]
+        g = _plane_rows(plane, nsr, use_pallas)
+    k = 2 + len(extra)
+    dsv, pa_g, claimed_raw = g[:, 0], g[:, 1], g[:, k:]
     if fused:
+        ap_g, ts_g = g[:, 2], g[:, 3]
         node_ts = jnp.where(is_node_slot, ts_g, BIG)
         node_ts = jnp.where(slot_ids == ROOT, jnp.int64(0), node_ts)
         node_pos = jnp.where(is_node_slot,
@@ -1025,11 +896,12 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             (par_depth[:, None] > 0)
         par_h = jnp.where(put_p, pts_h[:, None], pc_h)
         par_l = jnp.where(put_p, pts_l[:, None], pc_l)
-    elif _pack_gather_on():
+    else:
         # parent path plane + parent depth in one [M, D+1] i64 row
-        # gather through pslot; the fp repack below (the kernel's output
-        # plane, line ~1229) is the same _pack_u expression, so XLA CSEs
-        # it — the pack itself costs nothing extra
+        # gather through pslot; the fp repack of the kernel's output
+        # plane (the NodeTable ``paths``) is the same _pack_u
+        # expression, so XLA CSEs it — the pack itself costs nothing
+        # extra
         pplane_src = jnp.concatenate(
             [_pack_u(fp_h, fp_l), node_depth[:, None].astype(jnp.int64)],
             axis=1)
@@ -1037,13 +909,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             if part is not None else pplane_src[pslot]
         par_h, par_l = _split_u(pplane[:, :D])
         par_depth = pplane[:, D].astype(jnp.int32)
-    elif part is not None:
-        par_h = part.gather_rows(fp_h, pslot)
-        par_l = part.gather_rows(fp_l, pslot)
-        par_depth = part.gather_rows(node_depth, pslot)
-    else:
-        par_h, par_l = fp_h[pslot], fp_l[pslot]
-        par_depth = node_depth[pslot]
     prefix_ok = jnp.all(
         jnp.where(cols < node_depth[:, None] - 1,
                   (claimed_h == par_h) & (claimed_l == par_l),
@@ -1064,10 +929,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
         (afound & (anchor_parent == pslot) & (aslot != ROOT))
     local_ok = is_node_slot & (node_ts > 0) & parent_ok & anchor_ok
     local_ok = local_ok.at[ROOT].set(True)
-    if probe is not None:
-        acc = acc + _probe_sum(local_ok, parent_ok, fp_h, fp_l)
-        if probe == 2:
-            return acc
 
     # ---- 6. Validity cascades along the anchor forest: a node exists only
     # if its anchor chain and tree ancestors all exist.  Parked slots are
@@ -1113,10 +974,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     valid = valid.at[ROOT].set(True)
     # canonical parent pointer for existing nodes; root for itself
     parent_eff = jnp.where(valid, pslot, NULL).at[ROOT].set(ROOT)
-    if probe is not None:
-        acc = acc + _probe_sum(valid, parent_eff)
-        if probe == 3:
-            return acc
 
     # ---- 7. Deletes: tombstone valid targets (first delete per target wins
     # the log; the tree flag is an idempotent OR either way).  Target match
@@ -1163,10 +1020,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
         anc_del = _fix_min(anc_del, parent_eff, jnp.any(d_ok),
                            _ceil_log2(D) + 1)
         dead = valid & (anc_del < IPOS)
-    if probe is not None:
-        acc = acc + _probe_sum(deleted, dead, anc_del)
-        if probe == 4:
-            return acc
 
     # ---- 9. The order forest: each node's T* parent is the nearest node on
     # its within-branch anchor chain with a SMALLER timestamp (-1 = chain
@@ -1205,9 +1058,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
 
     mptr, _ = lax.while_loop(nsv_cond, nsv_body, (mptr0, jnp.int32(0)))
     nsa_unresolved = (mptr >= 0) & (mptr > slot_ids)
-    if probe is not None:
-        if probe == 41:        # stage-5a: NSA chase only
-            return acc + _probe_sum(mptr, nsa_unresolved)
 
     def _nsa_lifting(mptr):
         # up[k][v] = 2^k-th anchor ancestor (ROOT-absorbing; ROOT's slot
@@ -1236,9 +1086,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
                     lambda m: m, mptr)
     star_parent = jnp.where(mptr >= 0, mptr, pslot)
     star_sentinel = mptr < 0
-    if probe is not None:
-        if probe == 42:        # stage-5b: + lifting cond
-            return acc + _probe_sum(star_parent, star_sentinel)
 
     # Sibling sort → Euler-tour successor pointers.  Children of p: child-
     # branch T* roots first (group 0), then same-branch T* children (group
@@ -1287,8 +1134,7 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # STATICALLY.  Gate mirrored by merge.crowding_hinted so the chain
     # auditor records which leg a trace runs.
     crowd_hinted = fused and no_deletes and \
-        "crowd_slot" in ops and "crowd_cpos" in ops and \
-        _fused_flag("GRAFT_CROWD_HINTS")
+        "crowd_slot" in ops and "crowd_cpos" in ops
     if S_CAP >= M:
         sib_next, first_child = _sib_links(skey, ggrp, neg_slot)
     else:
@@ -1320,64 +1166,26 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             XLA-CPU sort time and the unrolled binary search both scale
             with the static width."""
             def br(_):
-                if tail_on:
-                    # scatter-free compaction (round 7): ``cpos`` is a
-                    # nondecreasing ±1-step cumsum, so the k-th crowded
-                    # row is the first index where it reaches k — a
-                    # binary search per compact slot (cap-wide, log M
-                    # unrolled hops: compact-stage cost under the
-                    # width-weighted model) followed by one
-                    # compact-width gather, instead of the M-wide-index
-                    # [cap, 2] scatter (XLA-CPU serializes scatters —
-                    # the same op was also a top cost of the CPU
-                    # fallback bench)
-                    ks = jnp.arange(cap, dtype=cpos.dtype)
-                    src = jnp.searchsorted(
-                        cpos, ks, side="left",
-                        method="scan_unrolled").astype(jnp.int32)
-                    valid_k = ks < n_crowded
-                    srcc = jnp.minimum(src, M - 1)
-                    if _pack_gather_on():
-                        # one [cap, 2] row gather (key+group bit-packed
-                        # — skey ≤ NULL < 2^30); padding detection
-                        # stays ``neg == IPOS`` as before
-                        vals = jnp.stack(
-                            [(skey << 1) | ggrp.astype(jnp.int32),
-                             neg_slot], axis=-1)[srcc]
-                        kp = jnp.where(valid_k, vals[:, 0] >> 1, IPOS)
-                        gg = jnp.where(valid_k, vals[:, 0] & 1,
-                                       0).astype(jnp.int8)
-                        neg = jnp.where(valid_k, vals[:, 1], IPOS)
-                    else:
-                        kp = jnp.where(valid_k, skey[srcc], IPOS)
-                        gg = jnp.where(valid_k, ggrp[srcc],
-                                       0).astype(jnp.int8)
-                        neg = jnp.where(valid_k, neg_slot[srcc], IPOS)
-                else:
-                    at = jnp.where(crowded, cpos, cap)
-                    if _pack_gather_on():
-                        # the three compaction columns share ONE index:
-                        # one [cap, 2] multi-column scatter (key+group
-                        # bit-packed — skey ≤ NULL < 2^30; IPOS padding
-                        # unpacks to a key that still sorts after every
-                        # real row, and padding detection stays
-                        # ``neg == IPOS`` as before)
-                        vals = jnp.stack(
-                            [(skey << 1) | ggrp.astype(jnp.int32),
-                             neg_slot], axis=-1)
-                        kgn = jnp.full((cap, 2), IPOS,
-                                       jnp.int32).at[at].set(
-                            vals, mode="drop", unique_indices=True)
-                        kp = kgn[:, 0] >> 1
-                        gg = (kgn[:, 0] & 1).astype(jnp.int8)
-                        neg = kgn[:, 1]
-                    else:
-                        kp = jnp.full(cap, IPOS, jnp.int32).at[at].set(
-                            skey, mode="drop", unique_indices=True)
-                        gg = jnp.zeros(cap, jnp.int8).at[at].set(
-                            ggrp, mode="drop", unique_indices=True)
-                        neg = jnp.full(cap, IPOS, jnp.int32).at[at].set(
-                            neg_slot, mode="drop", unique_indices=True)
+                # scatter-free compaction: ``cpos`` is a nondecreasing
+                # ±1-step cumsum, so the k-th crowded row is the first
+                # index where it reaches k — a binary search per compact
+                # slot (cap-wide, log M unrolled hops) followed by one
+                # compact-width [cap, 2] row gather (key+group
+                # bit-packed — skey ≤ NULL < 2^30); padding detection
+                # is ``neg == IPOS``
+                ks = jnp.arange(cap, dtype=cpos.dtype)
+                src = jnp.searchsorted(
+                    cpos, ks, side="left",
+                    method="scan_unrolled").astype(jnp.int32)
+                valid_k = ks < n_crowded
+                srcc = jnp.minimum(src, M - 1)
+                vals = jnp.stack(
+                    [(skey << 1) | ggrp.astype(jnp.int32),
+                     neg_slot], axis=-1)[srcc]
+                kp = jnp.where(valid_k, vals[:, 0] >> 1, IPOS)
+                gg = jnp.where(valid_k, vals[:, 0] & 1,
+                               0).astype(jnp.int8)
+                neg = jnp.where(valid_k, vals[:, 1], IPOS)
                 sib, fc = _sib_links(kp, gg, neg)
                 # singleton children: the parent's whole child list
                 return sib, _fc_singletons(fc)
@@ -1431,7 +1239,7 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             full = lambda __: _sib_links(skey, ggrp, neg_slot)  # noqa: E731
             mid = lambda __: lax.cond(              # noqa: E731
                 n_crowded <= S_CAP, _br_compact(S_CAP), full, None)
-            if tail_on and S_CAP2 < S_CAP:
+            if S_CAP2 < S_CAP:
                 return lax.cond(n_crowded <= S_CAP2,
                                 _br_compact(S_CAP2), mid, None)
             return mid(None)
@@ -1442,9 +1250,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # terminal below)
     sib_next = sib_next.at[ROOT].set(-1)
     first_child = first_child.at[NULL].set(-1)
-    if probe is not None:
-        if probe == 43:        # stage-5c: + sibling links
-            return acc + _probe_sum(sib_next, first_child)
 
     # ---- 10. Euler tour: enter(v) = token v, exit(v) = token M + v.
     # Successors form one chain per tree ending in the self-loop at
@@ -1475,10 +1280,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     exit_succ = jnp.where(
         ~in_tour | is_leaf, M + slot_ids, chain_next)
     succ = jnp.concatenate([enter_succ, exit_succ]).astype(jnp.int32)
-    if probe is not None:
-        acc = acc + _probe_sum(succ, sib_next, first_child)
-        if probe == 5:
-            return acc
 
     # ---- 11. Masks (the ranking below counts them as token weights).
     exists = valid & is_node_slot
@@ -1516,15 +1317,15 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # zero-weight — its window reads are clamped and then zeroed by
     # ``run_terminal`` in _expand, so the clamp never mis-weights it.
     #
-    # Round 7 (GRAFT_FUSED_TAIL): under the static no-deletes promise
-    # ``visible ≡ exists``, so the visible lane of the whole rank
-    # pipeline is the doc lane — one weight lane, single-column Wyllie,
-    # a [4, M] expansion plane, and ``visible_order`` aliasing ``order``
-    # (one fewer M-wide scatter).  With deletes both lanes ride as
-    # before.  The run-id prefix sum and the weight lanes fuse into ONE
-    # pallas sequential-grid scan on TPU (ops/tour_scan, T = 2M tokens +
-    # Kw·M weights in the same sweep); elsewhere they are the same lax
-    # cumsums as round 6 — bit-identical (tests/test_tour_scan.py).
+    # Under the static no-deletes promise ``visible ≡ exists``, so the
+    # visible lane of the whole rank pipeline is the doc lane — one
+    # weight lane, single-column Wyllie, a [4, M] expansion plane, and
+    # ``visible_order`` aliasing ``order`` (one fewer M-wide scatter).
+    # With deletes both lanes ride.  The run-id prefix sum and the
+    # weight lanes fuse into ONE pallas sequential-grid scan on TPU
+    # (ops/tour_scan, T = 2M tokens + Kw·M weights in the same sweep);
+    # elsewhere they are lax cumsums — bit-identical
+    # (tests/test_tour_scan.py).
     w_lanes = jnp.stack(
         [exists.astype(jnp.int32)] if single_w else
         [exists.astype(jnp.int32), visible.astype(jnp.int32)])
@@ -1537,8 +1338,7 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             boundary.astype(jnp.int32), w_lanes)
     else:
         rid_incl, w_incl = tour_scan.prefix_sums(
-            boundary.astype(jnp.int32), w_lanes,
-            use_pallas if _fused_flag("GRAFT_FUSED_SCAN") else False)
+            boundary.astype(jnp.int32), w_lanes, use_pallas)
     rid = rid_incl - 1                   # run id per token
     z1 = jnp.zeros(1, jnp.int32)
     cse_doc = jnp.concatenate([z1, w_incl[0]])
@@ -1639,15 +1439,14 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     R_CAP = _env_cap("GRAFT_R_CAP", R_CAP_DEFAULT)
     if R_CAP >= T:
         ex = _expand(*_runs_full())
-    elif tail_on:
-        # scatter-free run starts on the compact path (round 7): rid is
-        # a nondecreasing boundary cumsum hitting every id 0..n_runs-1,
+    else:
+        # scatter-free run starts on the compact path: rid is a
+        # nondecreasing boundary cumsum hitting every id 0..n_runs-1,
         # so run k's first token is a binary search — R_CAP-wide,
-        # log T unrolled hops (compact-stage cost, width-weighted
-        # model) — and the T-wide-index scatter survives only in the
-        # fragmented-tour fallback branch (XLA-CPU serializes scatters;
-        # this one was the single most expensive op of the CPU
-        # fallback bench)
+        # log T unrolled hops — and the T-wide-index scatter survives
+        # only in the fragmented-tour fallback branch (XLA-CPU
+        # serializes scatters; this one was the single most expensive
+        # op of the CPU fallback bench)
         n_runs = rid[T - 1] + 1
 
         def _compact(cap):
@@ -1677,17 +1476,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
             ex = lax.cond(n_runs <= R_CAP2, _compact(R_CAP2), mid, None)
         else:
             ex = mid(None)
-    else:
-        run_s, run_e = _runs_full()
-        n_runs = rid[T - 1] + 1
-        ex = lax.cond(
-            n_runs <= R_CAP,
-            lambda _: _expand(run_s[:R_CAP], run_e[:R_CAP]),
-            lambda _: _expand(run_s, run_e), None)
-    if probe is not None:
-        acc = acc + _probe_sum(ex)
-        if probe == 6:
-            return acc
 
     # E(tok) = weight at-or-after tok along the chain; within-run
     # offsets from the global cumsum (forward runs count from the run
@@ -1724,10 +1512,6 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     else:
         vis_dense = rank_of(ex[4], ex[5], ex[6], cse_vis)
         visible_order = _order_frame(visible, vis_dense)
-    if probe is not None:
-        acc = acc + _probe_sum(doc_index, order, visible_order)
-        if probe == 7:
-            return acc
 
     # ---- 13. Sequential-parity statuses per op.  Per-slot facts pack
     # into one int32 so each op needs two gathers (meta + anc_del), not
@@ -1739,12 +1523,12 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
     # an Add with ts 0 collides with the branch-head sentinel: the reference
     # finds an existing child and reports AlreadyApplied
     a_sentinel = ts <= 0
-    if no_deletes and tail_on:
+    if no_deletes:
         # grandvalid (valid[pslot], the NOT_FOUND/INVALID_PATH split) is
         # only read for INVALID non-sentinel adds — on the production
         # all-valid path that M-wide gather pair moves inside a cond
-        # the fast path never takes (round 7); the always-paid cost is
-        # the one per-op meta gather below
+        # the fast path never takes; the always-paid cost is the one
+        # per-op meta gather below
         meta_s = valid.astype(jnp.int32) | \
             (parent_ok.astype(jnp.int32) << 1)
         a_meta = _prow(meta_s, a_slot)
@@ -1778,11 +1562,7 @@ def _finish(ops: Dict[str, jax.Array], sel, use_pallas: Optional[bool],
         a_valid = (a_meta & 1) != 0
         a_parent_ok = (a_meta & 2) != 0
         a_grandvalid = (a_meta & 4) != 0     # valid[pslot[a_slot]]
-        # statically no ancestor delete under the no-deletes promise:
-        # the anc_del frame is a constant there, so the gather would be
-        # a dead M-wide op the chain budget still counts at trace level
-        a_absorbed = False if no_deletes else \
-            a_valid & (_prow(anc_del, a_slot) < pos)
+        a_absorbed = a_valid & (_prow(anc_del, a_slot) < pos)
         a_status = jnp.where(
             a_sentinel | (a_valid & (op_is_dup | a_absorbed)),
             ALREADY_APPLIED,

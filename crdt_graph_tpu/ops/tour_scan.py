@@ -20,10 +20,9 @@ row offsets are added after the cast, keeping exactness for prefixes
 up to 2^31.
 
 ``prefix_sums`` is the wrapper: the Mosaic kernel on TPU backends, the
-same lax cumsums as round 6 elsewhere — bit-identical either way
-(tests/test_tour_scan.py).  ``GRAFT_NO_PALLAS=1`` and
-``GRAFT_FUSED_SCAN=0`` (read by the caller, ops/merge.py) both force
-the lax path.
+same lax cumsums elsewhere — bit-identical either way
+(tests/test_tour_scan.py).  ``GRAFT_NO_PALLAS=1`` forces the lax path
+(ops/pallas_mode.select).
 """
 from __future__ import annotations
 
@@ -40,7 +39,6 @@ from . import pallas_mode
 
 TILE = 2048      # stream elements per grid step, as an (8, 256) block
 KERNEL_NAME = "tour_scan_prefix"
-RING_KERNEL_NAME = "opsaxis_ring_carry"
 ROWS, LANES = 8, 256
 
 
@@ -197,78 +195,6 @@ def sharded_prefix_sums(boundary: jax.Array, weights: jax.Array, *,
     wg = lax.all_gather(out_w, axis, tiled=False)      # [k, Kw, W]
     ow = jnp.transpose(wg, (1, 0, 2)).reshape(kw, k * w)[:, :m]
     return ob, ow
-
-
-# ---- pallas ring-carry exchange (not yet selected by any caller) -------
-#
-# The ``ring_exclusive`` above is lax.ppermute so the 8-device
-# host-platform CPU mesh executes it for real in tier-1.  On a real TPU
-# slice the same exchange can ride one pallas kernel using
-# ``pltpu.make_async_remote_copy`` (the SNIPPETS.md [1]/[2] ring
-# pattern): each device pushes its carry vector to its right neighbour
-# k-1 times, accumulating the exclusive prefix in VMEM — one kernel
-# launch instead of log2(k)+1 XLA collectives.  Validated in interpret
-# mode on the CPU mesh (tests/test_opsaxis.py) and compiled for a
-# v5e:2x2 mesh (tests/test_tpu_compile.py); its chip time against
-# ``ring_exclusive`` is not measured yet.
-
-
-def _ring_carry_kernel(x_ref, o_ref, comm, send_sem, recv_sem, *,
-                       k: int, axis: str):
-    my = lax.axis_index(axis)
-    right, left = (my + 1) % k, (my + k - 1) % k
-    # neighbours enter the kernel before anyone writes into their VMEM
-    barrier = pltpu.get_barrier_semaphore()
-    for nb in (left, right):
-        pltpu.semaphore_signal(barrier, 1, device_id=nb,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_wait(barrier, 2)
-    acc = jnp.zeros_like(x_ref[...])
-    comm[0] = x_ref[...]
-    for step in range(k - 1):
-        # hop ``step`` relays slot ``step`` into the right neighbour's
-        # slot ``step + 1``: every slot is written once per call, so a
-        # device running ahead never overwrites a payload its
-        # neighbour has not relayed yet
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm.at[step], dst_ref=comm.at[step + 1],
-            send_sem=send_sem.at[step], recv_sem=recv_sem.at[step],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-        rdma.start()
-        rdma.wait()
-        # after ``step+1`` hops the slot holds the carry ORIGINATED by
-        # the device step+1 to our ring-left, which contributes iff
-        # that sender index is below ours (exclusive prefix)
-        acc = acc + jnp.where(my >= step + 1, comm[step + 1], 0)
-    o_ref[...] = acc
-
-
-def ring_exclusive_pallas(vals: jax.Array, k: int,
-                          interpret: bool = False,
-                          axis: str = "ops") -> jax.Array:
-    """Pallas twin of :func:`ring_exclusive` (add only), for use
-    inside shard_map over ``axis``.  Lanes pad to the 128-lane
-    tile; the comm buffer holds one slot per hop."""
-    lanes = vals.shape[0]
-    pad = -lanes % 128
-    x = jnp.pad(vals.astype(jnp.int32), (0, pad)).reshape(1, -1)
-    with jax.enable_x64(False):     # x64 index math fails to lower
-        out = pl.pallas_call(
-            functools.partial(_ring_carry_kernel, k=k, axis=axis),
-            out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((k,) + x.shape, jnp.int32),
-                pltpu.SemaphoreType.DMA((k - 1,)),
-                pltpu.SemaphoreType.DMA((k - 1,)),
-            ],
-            compiler_params=pltpu.CompilerParams(collective_id=0),
-            interpret=pltpu.InterpretParams() if interpret else False,
-            name=RING_KERNEL_NAME,
-        )(x)
-    return out.reshape(-1)[:lanes]
 
 
 def prefix_sums(boundary: jax.Array, weights: jax.Array,

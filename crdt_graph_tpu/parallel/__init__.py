@@ -1,12 +1,10 @@
 """Multi-chip scale-out: device meshes and sharded merge entry points."""
 from .mesh import (DOCS_AXIS, OPS_AXIS, batched_materialize, make_mesh,
-                   sharded_materialize, stack_packed)
-from .shard import collective_stats, measure_collectives, shard_materialize
+                   stack_packed)
 
 __all__ = [
     "DOCS_AXIS", "OPS_AXIS", "batched_materialize", "make_mesh",
-    "sharded_materialize", "stack_packed",
-    "shard_materialize", "collective_stats", "measure_collectives",
+    "stack_packed",
 ]
 from . import distributed  # noqa: E402  (multi-host helpers)
 

@@ -12,28 +12,17 @@ Two orthogonal mesh axes, composable into a 2-D mesh:
   merging many documents batches them on a leading axis and shards that axis;
   merges never communicate across documents, so scaling is linear.  This is
   the realistic serving axis (each collaborative document is independent).
-- ``ops`` — parallelism *within* one merge: the packed op axis is sharded, so
-  each chip holds a slice of the operation set (e.g. the logs of a subset of
-  replicas, concatenated: the semilattice join is insensitive to how ops are
-  distributed).  The kernel is expressed as whole-array ``lax`` ops
-  (sort/scatter/gather); partitioning is delegated to XLA's SPMD partitioner
-  via input shardings — the idiomatic JAX recipe (mesh → shardings → let XLA
-  insert all-to-alls/all-gathers) rather than hand-written per-chip message
-  passing.
+- ``ops`` — the packed op axis within one document.  Here it is the
+  optional second axis of :func:`batched_materialize`; ONE document's
+  merge sharded over the op axis is :mod:`crdt_graph_tpu.parallel.opsaxis`
+  (a ``shard_map`` body with hand-placed collectives, the served
+  single-document multi-device path).
 
 Entry points:
 
 - :func:`make_mesh` — build a 1-D or 2-D device mesh.
-- :func:`sharded_materialize` — one merge, op axis sharded over ``ops``.
 - :func:`batched_materialize` — B independent merges, vmapped on a leading
   doc axis, sharded over ``docs`` (and optionally ``ops``).
-
-This module is the auto-partitioned path (whole-array kernel + input
-shardings; XLA chooses the collectives).  The EXPLICIT schedule — per-
-shard local resolution with hand-placed pmin/all_gather boundary
-exchange, which moved ~2x fewer bytes in ~3x fewer collectives on the
-round-4 CPU mesh — lives in :mod:`crdt_graph_tpu.parallel.shard`;
-both are pinned bit-identical to the single-device kernel.
 """
 from __future__ import annotations
 
@@ -45,7 +34,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..codec.packed import KIND_PAD, PackedOps
+from ..codec.packed import PackedOps
 from ..codec.packed import pad_arrays as packed_pad_arrays
 from ..ops import merge as merge_mod
 from ..ops.merge import NodeTable
@@ -73,37 +62,6 @@ _pad_ops_to = packed_pad_arrays
 
 def round_up(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
-
-
-def sharded_materialize(ops: Dict[str, np.ndarray], mesh: Mesh) -> NodeTable:
-    """One merge with the op axis sharded over the mesh's ``ops`` axis.
-
-    The op arrays are padded to a multiple of the axis size, placed with
-    ``NamedSharding(mesh, P(OPS_AXIS))``, and the stock kernel is jitted with
-    those input shardings; XLA partitions the sorts and scatter/gathers and
-    inserts the ICI collectives.  The resulting table is replicated (every
-    chip holds the converged tree — which is what a serving replica wants).
-    """
-    n_ops = mesh.shape[OPS_AXIS]
-    n = round_up(ops["kind"].shape[0], n_ops)
-    padded = _pad_ops_to(ops, n)
-
-    def run():
-        # device_put must sit inside the x64 scope: outside it JAX silently
-        # downcasts int64 host arrays to int32, truncating timestamps.
-        # The pallas rank gather is not partition-aware, so explicitly
-        # sharded merges pin the lax path; hints keep the default auto
-        # mode (the cond's scalar predicate partitions fine under SPMD,
-        # and the join fallback stays available for hint-less or
-        # mislinked inputs — e.g. restored old checkpoints).
-        device_ops = {k: jax.device_put(v, NamedSharding(mesh, P(OPS_AXIS)))
-                      for k, v in padded.items()}
-        return merge_mod.materialize(device_ops, use_pallas=False)
-
-    if jax.config.jax_enable_x64:
-        return run()
-    with jax.enable_x64(True):
-        return run()
 
 
 def _materialize_batched_safe(ops):

@@ -1,19 +1,18 @@
 """Ops-axis sharded merge: the single-document kernel at M/k width per
-device with ring-collective boundary exchange (ISSUE 13 tentpole).
+device with ring-collective boundary exchange.
 
-The docs axis already shards (8 independent documents via
-``mesh.batched_materialize``, docs/SHARD_TAIL.md §6), and
-``parallel/shard.py`` partitions the RESOLUTION stages — but the tail
-(tour scan, plane sweeps, rank expansion, order scatters) still ran
-replicated, capping a single giant merge at ~1.6× on 8 chips (§2b).
-This module shards the tail's billed memory ops too, following the §4
-design the round-5 doc committed:
+This is the one path that shards ONE document's merge over several
+devices; the docs axis (independent documents,
+``mesh.batched_materialize``, docs/SHARD_TAIL.md §6) is the other
+multi-device axis.  It shards the kernel's billed memory ops — tour
+scan, plane sweeps, rank expansion, order scatters — following the §4
+design of docs/SHARD_TAIL.md:
 
 - **One shard_map, one code path.**  The whole kernel runs inside ONE
   ``shard_map`` over a 1-D ``ops`` mesh; the body all-gathers the op
-  columns once (the same boundary exchange parallel/shard.py performs)
-  and then calls the STOCK kernel — ``merge._materialize`` — with a
-  :class:`OpsAxisPart` partition context threaded through it.  Every
+  columns once (the boundary exchange) and then calls the STOCK
+  kernel — ``merge._materialize`` — with a :class:`OpsAxisPart`
+  partition context threaded through it.  Every
   stage the context does not intercept runs replicated and is therefore
   bit-identical by construction; the intercepted stages are proven-
   equal rewrites (associative scan splits, disjoint-index scatter
@@ -38,15 +37,12 @@ design the round-5 doc committed:
 - **Frame scatters join like semilattices**: order/visible-order/
   first-child scatters write globally-unique targets, so each device
   scatters only its ceil(M/k) local pairs into a default frame and one
-  ``lax.pmin``/``pmax`` joins the frames (the parallel/shard.py winner-
-  frame pattern); scatter-adds join by ``psum``.
+  ``lax.pmin``/``pmax`` joins the frames; scatter-adds join by
+  ``psum``.
 
 On the 8-device host-platform CPU mesh every collective executes for
-real (lax.ppermute/psum/all_gather — tier-1 runs this path); the pallas
-``make_async_remote_copy`` ring variant of the carry exchange
-(ops/tour_scan.ring_exclusive_pallas) is validated in interpret mode and
-compiles for a v5e:2x2 mesh (tests/test_tpu_compile.py); no caller
-selects it yet.
+real (lax.ppermute/psum/all_gather — tier-1 runs this path), and the
+body compiles for a described v5e:2x2 mesh (tests/test_tpu_compile.py).
 
 What stays replicated per device, disclosed: all ELEMENTWISE M-wide
 arithmetic (the cost model bills memory ops, not elementwise lanes —
@@ -76,7 +72,6 @@ every single-document kernel merge, routed or not), and
 from __future__ import annotations
 
 import functools
-import math
 import threading
 from typing import Dict, Optional
 
@@ -204,7 +199,7 @@ class OpsAxisPart:
         the scatter's INDEX axis sharded and the per-device frames
         joined by ``pmin``/``pmax`` — exact when targets are globally
         unique and every scattered value wins ``default`` under the
-        combine (the shard.py winner-frame pattern)."""
+        combine."""
         n = tgt.shape[0]
         w = self._w(n)
         # pad targets with ``size`` (dropped) so pad rows scatter nowhere
@@ -324,8 +319,7 @@ def _opsaxis_materialize(device_ops, mesh: Mesh, cols, hints,
 
     def body(*vals):
         # boundary exchange: the op columns all-gather ONCE (each
-        # device owns a contiguous ops shard on entry — the same
-        # exchange parallel/shard.py's resolve performs), then the
+        # device owns a contiguous ops shard on entry), then the
         # STOCK kernel runs with the partition context intercepting
         # its billed memory ops.  use_pallas pinned False: Mosaic
         # calls must not trace inside shard_map (mesh.py precedent).

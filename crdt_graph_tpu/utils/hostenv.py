@@ -40,13 +40,9 @@ def env_float(name: str, default: float) -> float:
 
 
 def flag_on(name: str, default: str = "1") -> bool:
-    """One boolean env flag, read at TRACE time and logged on every
-    (re)trace — the single parser behind the GRAFT_FUSED_* and
-    GRAFT_PACK_GATHER kill-switches (ops/merge reads most of them;
-    ops/fused_resolve reads GRAFT_FUSED_SUPEROP and cannot import merge
-    without a cycle, so the parse+log lives here).  ``"0"``, ``"off"``
-    and the empty string mean OFF; a stale-jit-cache sweep caveat
-    applies exactly as documented at ops/merge._env_cap."""
+    """One boolean env flag, logged on every read (the GRAFT_OPSAXIS
+    route switch, parallel/opsaxis.py).  ``"0"``, ``"off"`` and the
+    empty string mean OFF."""
     import logging
     on = os.environ.get(name, default).lower() not in ("0", "off", "")
     logging.getLogger("crdt_graph_tpu.flags").info(

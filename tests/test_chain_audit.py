@@ -36,12 +36,11 @@ def _audit(arrs, hints="exhaustive", use_pallas=False):
                                         use_pallas=use_pallas)
 
 
-def test_config5_production_trace_within_budget(monkeypatch):
+def test_config5_production_trace_within_budget():
     """The headline DEVICE trace (1M ops, exhaustive, no deletes,
-    pack-gather default ON, slot hints attached, pallas superops with
-    their in-trace fallbacks) must fit the CI budget, in count AND in
+    slot hints attached, pallas superops with their in-trace
+    fallbacks) must fit the CI budget, in count AND in
     width-weighted modeled ms."""
-    monkeypatch.delenv("GRAFT_PACK_GATHER", raising=False)
     arrs = workloads.chain_workload(64, 1_000_000)
     audit = _audit(arrs, use_pallas=True)
     assert audit.fast_path <= BUDGET, "\n" + audit.table()
@@ -49,42 +48,25 @@ def test_config5_production_trace_within_budget(monkeypatch):
     assert audit.summary()["ok"]
 
 
-def test_config5_lax_trace_within_budget(monkeypatch):
+def test_config5_lax_trace_within_budget():
     """The lax/CPU fallback trace (what the round-end CPU bench runs)
     keeps the sibling machinery and split scans the pallas kernels
     fuse — its own, slightly higher, budget is pinned so CPU-visible
     regressions fail here too."""
-    monkeypatch.delenv("GRAFT_PACK_GATHER", raising=False)
     arrs = workloads.chain_workload(64, 1_000_000)
     audit = _audit(arrs, use_pallas=False)
     assert audit.fast_path <= BUDGET_LAX, "\n" + audit.table()
 
 
 @pytest.mark.parametrize("cid", [6, 7, 8])
-def test_adversarial_shapes_share_the_fast_path_budget(cid, monkeypatch):
+def test_adversarial_shapes_share_the_fast_path_budget(cid):
     """The adversarial generators are still causal logs: their FAST
     path must match the budget too (their extra cost lives in the cond
     fallbacks and loop trips the auditor prices as ``static``)."""
-    monkeypatch.delenv("GRAFT_PACK_GATHER", raising=False)
     _, gen = workloads.CONFIGS[cid]
     audit = _audit(gen(), use_pallas=True)
     assert audit.fast_path <= BUDGET, f"config {cid}\n" + audit.table()
     assert audit.static >= audit.fast_path
-
-
-def test_pack_gather_flag_is_load_bearing(monkeypatch):
-    """GRAFT_PACK_GATHER=0 (the A/B's B leg) must cost extra M-wide
-    ops — pinning that the default-ON packing is what buys the budget,
-    not a counting artifact."""
-    arrs = workloads.chain_workload(8, 65_536)
-    monkeypatch.setenv("GRAFT_PACK_GATHER", "1")
-    on = _audit(arrs)
-    monkeypatch.setenv("GRAFT_PACK_GATHER", "0")
-    off = _audit(arrs)
-    # (the ≤10 budget itself is a headline-SCALE property — at 64k the
-    # S_CAP/R_CAP-compacted stages sit above the relative threshold —
-    # so only the flag's relative effect is pinned here)
-    assert off.fast_path > on.fast_path
 
 
 def test_slot_hints_are_load_bearing():
@@ -99,28 +81,6 @@ def test_slot_hints_are_load_bearing():
         arrs.pop(k)
     unfused = _audit(arrs)
     assert unfused.fast_path > fused.fast_path
-
-
-def test_fused_kill_switches_restore_the_r6_trace(monkeypatch):
-    """GRAFT_FUSED_*=0 (the A/B's B leg, scripts/probe_fusedab.py) must
-    re-add the round-6 passes: the winner scatter-min, the parent-row
-    gather, the T-wide run-start scatter, the visible-order scatter —
-    pinning that the round-7 cuts are the flags' doing, not a counting
-    artifact.  (Flags are read at trace time; merge._materialize is
-    re-traced via __wrapped__ on every audit, so no cache clearing is
-    needed.)"""
-    arrs = workloads.chain_workload(8, 65_536)
-    for flag in ("GRAFT_FUSED_RESOLVE", "GRAFT_FUSED_TAIL",
-                 "GRAFT_FUSED_SCAN"):
-        monkeypatch.delenv(flag, raising=False)
-    on = _audit(arrs)
-    for flag in ("GRAFT_FUSED_RESOLVE", "GRAFT_FUSED_TAIL",
-                 "GRAFT_FUSED_SCAN"):
-        monkeypatch.setenv(flag, "0")
-    off = _audit(arrs)
-    assert off.fast_path > on.fast_path, (
-        f"on={on.fast_path}\n{on.table()}\n\noff={off.fast_path}\n"
-        f"{off.table()}")
 
 
 def test_opsaxis_shard_width_budget_config5_1M():
@@ -147,12 +107,11 @@ def test_opsaxis_shard_width_budget_config5_1M():
     assert st["leg"] == "hinted"
 
 
-def test_crowding_hints_are_load_bearing(monkeypatch):
-    """Dropping the crowd columns (or killing GRAFT_CROWD_HINTS) must
-    re-add the scatter-add + gather + cumsum trio to the lax trace —
-    pinning that the host pre-pass is what removed it — and the audit
-    summary must record which leg compiled."""
-    monkeypatch.delenv("GRAFT_PACK_GATHER", raising=False)
+def test_crowding_hints_are_load_bearing():
+    """Dropping the crowd columns must re-add the scatter-add + gather
+    + cumsum trio to the lax trace — pinning that the host pre-pass is
+    what removed it — and the audit summary must record which leg
+    compiled."""
     arrs = dict(workloads.chain_workload(8, 65_536))
     assert "crowd_slot" in arrs
     hinted = _audit(arrs)
@@ -165,10 +124,7 @@ def test_crowding_hints_are_load_bearing(monkeypatch):
     # exactly the trio returns
     assert counted.fast_path == hinted.fast_path + 3, (
         f"hinted\n{hinted.table()}\n\ncounted\n{counted.table()}")
-    monkeypatch.setenv("GRAFT_CROWD_HINTS", "0")
-    killed = _audit(arrs)
-    assert killed.fast_path == counted.fast_path
-    assert killed.crowding_leg == "counted"
+    assert counted.summary()["crowding_leg"] == "counted"
 
 
 def test_counter_basics():

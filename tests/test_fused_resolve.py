@@ -178,24 +178,9 @@ def test_full_merge_pallas_interpret_bit_identity(cid):
             err_msg=f"config {cid} field {f}")
 
 
-@pytest.mark.parametrize("flags_on", [True, False])
-def test_fallback_path_order_exact_config5(flags_on, monkeypatch, request):
+def test_fallback_path_order_exact_config5():
     """ISSUE 3 acceptance: the config-5 closed-form order must hold on
-    the lax path both with the round-7 fusions on (their lax fallbacks)
-    and with every GRAFT_FUSED_* kill-switch thrown (the round-6
-    trace)."""
-    for f in ("GRAFT_FUSED_RESOLVE", "GRAFT_FUSED_TAIL",
-              "GRAFT_FUSED_SCAN", "GRAFT_FUSED_SUPEROP"):
-        if flags_on:
-            monkeypatch.delenv(f, raising=False)
-        else:
-            monkeypatch.setenv(f, "0")
-    # the flags are read at TRACE time under identical shapes/static
-    # args, so a cached trace from the other parametrization (or from
-    # earlier tests) would silently shadow this leg's flag state — and
-    # this leg's trace would poison later tests the same way
-    jax.clear_caches()
-    request.addfinalizer(jax.clear_caches)
+    the lax path (the lax twins of the pallas superops)."""
     n = 65_536
     arrs = workloads.chain_workload(64, n)
     t = view.to_host(merge.materialize(arrs, use_pallas=False,

@@ -461,27 +461,6 @@ def test_no_deletes_trace_parity():
     assert not merge.host_no_deletes(p2.arrays()["kind"])
 
 
-@pytest.mark.slow
-def test_probe_cuts_run_every_stage():
-    """The kernel's profiling cut points (merge._materialize probe=k,
-    scripts/probe_stages.py) must keep returning a scalar at every
-    stage, with and without deletes — so the on-chip stage profile the
-    r4 verdict asked for can never bit-rot.  Slow-marked (ISSUE 12
-    tier-1 budget): 14 compiles for a profiling-script tripwire, not a
-    production-path invariant."""
-    import jax
-    _, ops = _random_session(23, n_replicas=3, steps=40)
-    for op_set in (ops, [op for op in ops if not isinstance(op, Delete)]):
-        arrs = packed.pack(op_set).arrays()
-        nd = merge.host_no_deletes(arrs["kind"])
-        with jax.enable_x64(True):
-            for k in range(1, 8):
-                out = merge._materialize(arrs, None, "exhaustive", nd, k)
-                assert np.asarray(out).shape == (), k
-            t = merge._materialize(arrs, None, "exhaustive", nd, None)
-        assert hasattr(t, "status")
-
-
 def test_hostile_pos_duplicate_winner_agrees():
     """ADVICE r3: a raw-array producer violating the pos == array-index
     contract must not let the ranked path and the join fallback pick
@@ -603,14 +582,10 @@ def test_high_low_half_timestamps_converge():
         assert vis == exp, f"seed {seed}: {vis} != {exp}"
 
 
-def test_pack_gather_layout_bit_identity(monkeypatch):
-    """GRAFT_PACK_GATHER routes the shared-index gathers of stages 1-2
-    through multi-column plane row-gathers (merge._pack_gather_on); the
-    two layouts are exact integer re-packings, so every NodeTable field
-    must be bit-identical across them in all three hint modes.  The flag
-    is read at trace time, so the caches are cleared between settings."""
-    import jax
-
+@pytest.fixture(scope="module")
+def editor_session_arrs():
+    """One random single-replica editing session (appends, nested
+    branches, deletes) as packed columns."""
     rng = random.Random(77)
     o = crdt.init(5)
     for i in range(300):
@@ -624,32 +599,29 @@ def test_pack_gather_layout_bit_identity(monkeypatch):
                 o = o.delete(list(o.cursor))
             except (crdt.OperationFailedError, crdt.InvalidPathError):
                 pass
-    arrs = packed.pack(o.operations_since(0)).arrays()
+    return packed.pack(o.operations_since(0)).arrays()
+
+
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_session_table_agrees_across_modes_and_opsaxis(
+        editor_session_arrs, k):
+    """The same batch gives one NodeTable, bit for bit, in all three
+    hint modes of the stock kernel and through the ops-axis sharded
+    body at mesh width ``k`` (the plane gathers, frame scatters and
+    scans of ``merge._finish`` run partitioned there)."""
+    from crdt_graph_tpu.parallel import opsaxis
+    arrs = editor_session_arrs
+    assert arrs["kind"].shape[0] % 8 == 0
+    assert np.any(arrs["kind"] == packed.KIND_DELETE)
     fields = ["ts", "parent", "depth", "value_ref", "paths", "exists",
               "tombstone", "dead", "visible", "doc_index", "order",
               "visible_order", "num_nodes", "num_visible", "status"]
-
-    def tables():
-        out = {h: view.to_host(merge.materialize(arrs, hints=h))
-               for h in ("exhaustive", "auto", "join")}
-        # the explicit shard schedule shares _node_cols_from_row/_finish:
-        # the flag must preserve its bit-identity contract there too
-        from crdt_graph_tpu import parallel
-        out["shard"] = parallel.shard_materialize(
-            arrs, parallel.make_mesh(n_ops=8))
-        return out
-
-    # default is ON (round 6): pin the two legs explicitly either way
-    monkeypatch.setenv("GRAFT_PACK_GATHER", "0")
-    jax.clear_caches()
-    base = tables()
-    monkeypatch.setenv("GRAFT_PACK_GATHER", "1")
-    jax.clear_caches()
-    packed_t = tables()
-    monkeypatch.undo()
-    jax.clear_caches()
-    for h in base:
+    want = view.to_host(merge.materialize(arrs, hints="exhaustive"))
+    got = {h: view.to_host(merge.materialize(arrs, hints=h))
+           for h in ("auto", "join")}
+    got["opsaxis"] = view.to_host(
+        opsaxis.materialize(arrs, k=k, hints="exhaustive"))
+    for h, t in got.items():
         for f in fields:
-            assert np.array_equal(np.asarray(getattr(base[h], f)),
-                                  np.asarray(getattr(packed_t[h], f))), \
-                (h, f)
+            assert np.array_equal(np.asarray(getattr(want, f)),
+                                  np.asarray(getattr(t, f))), (h, f)

@@ -1,6 +1,7 @@
 """Ops-axis sharded merge (parallel/opsaxis.py, ISSUE 13): bit-identity
-vs the single-device kernel across the sweep shapes, the mesh-size edge
-cases (1-device no-op, non-divisible padded tail, halo-straddling
+vs the single-device kernel across the sweep shapes, a ≥256k mixed
+batch in every hints mode, hostile and missing hints, the mesh-size
+edge cases (1-device no-op, non-divisible padded tail, halo-straddling
 fallback), and the serving path with the GRAFT_OPSAXIS route on/off
 (fingerprints + byte-identical sync windows + unchanged
 last_applied_mask attribution)."""
@@ -14,7 +15,6 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
-from jax import lax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -22,9 +22,6 @@ from crdt_graph_tpu.bench import workloads  # noqa: E402
 from crdt_graph_tpu.codec import packed  # noqa: E402
 from crdt_graph_tpu.ops import merge, tour_scan  # noqa: E402
 from crdt_graph_tpu.parallel import opsaxis  # noqa: E402
-
-# the bit-identity suite pins the packed layout like test_shard_map
-os.environ["GRAFT_PACK_GATHER"] = "1"
 
 FIELDS = ("ts", "parent", "depth", "value_ref", "paths", "exists",
           "tombstone", "dead", "visible", "doc_index", "order",
@@ -89,6 +86,83 @@ def test_chain_closed_form_through_opsaxis():
     seq = np.asarray(got.ts)[np.asarray(got.visible_order)][
         :int(got.num_visible)]
     np.testing.assert_array_equal(seq, want_seq)
+
+
+@pytest.mark.parametrize("hints", ["exhaustive", "auto", "join"])
+def test_large_mixed_batch_identity(hints):
+    """≥256k ops with deletes over the 8-wide ops axis, in every hints
+    mode: the scale bar for a genuinely partitioned merge."""
+    arrs = workloads.chain_with_deletes(229_376, 8)
+    assert arrs["kind"].shape[0] >= 256_000
+    assert (arrs["kind"] == packed.KIND_DELETE).sum() > 10_000
+    assert_identical(arrs, hints=hints)
+
+
+def test_adversarial_shapes_identity_auto():
+    """The adversarial generators (descending chains, comb pairs, deep
+    paths) at 16k ops in the verified auto mode: worst-case sibling
+    contention and fragmentation through the sharded body."""
+    for arrs in (workloads.chain_workload(64, 16_384),
+                 workloads.descending_chains(256, 16_384),
+                 workloads.comb_pairs(16_384),
+                 workloads.deep_paths(64, 16_384, max_depth=16)):
+        assert_identical(arrs, hints="auto")
+
+
+def test_chain_closed_form_64_chains_auto():
+    """The 64-chain interleave at 64k ops in auto mode matches its
+    closed-form visible sequence."""
+    arrs = workloads.chain_workload(64, 65_536)
+    got = assert_identical(arrs, hints="auto")
+    want_seq = workloads.chain_expected_ts(64, 65_536)
+    seq = np.asarray(got.ts)[np.asarray(got.visible_order)][
+        :int(got.num_visible)]
+    np.testing.assert_array_equal(seq, want_seq)
+
+
+def test_exhaustive_mode_identity_random_session():
+    """Vouched (pack-produced) hints of a random multi-replica session
+    with deletes through the cond-free exhaustive mode."""
+    from test_merge_kernel import _random_session
+    _, ops = _random_session(97, n_replicas=4, steps=400)
+    p = packed.pack(ops)
+    assert p.hints_vouched
+    assert_identical(p.arrays(), hints="exhaustive")
+
+
+def test_hostile_hints_fall_back_identically():
+    """Corrupted ranks and links fail the replicated verification; the
+    batch takes the sorted+join fallback and still matches the stock
+    kernel byte for byte."""
+    from test_merge_kernel import _random_session
+    _, ops = _random_session(98, n_replicas=3, steps=300)
+    arrs = dict(packed.pack(ops).arrays())
+    rng = np.random.default_rng(3)
+    r = arrs["ts_rank"].copy()
+    adds = np.nonzero(r >= 0)[0]
+    r[adds] = rng.permutation(r[adds])
+    arrs["ts_rank"] = r
+    bad = arrs["anchor_pos"].copy()
+    bad[bad >= 0] = 0
+    arrs["anchor_pos"] = bad
+    assert_identical(arrs, hints="auto")
+
+
+def test_missing_hint_columns_take_the_sorted_path():
+    """A batch without ranks, or without any hint column, resolves
+    through the sort (and join) inside the sharded body, identical to
+    the stock kernel; an unknown hints mode is rejected."""
+    from test_merge_kernel import _random_session
+    _, ops = _random_session(99, n_replicas=3, steps=200)
+    arrs = packed.pack(ops).arrays()
+    no_rank = {k: v for k, v in arrs.items() if k != "ts_rank"}
+    assert_identical(no_rank, hints="auto")
+    bare = {k: v for k, v in arrs.items()
+            if k in ("kind", "ts", "parent_ts", "anchor_ts", "depth",
+                     "paths", "value_ref", "pos")}
+    assert_identical(bare, hints="exhaustive")
+    with pytest.raises(ValueError, match="unknown hints mode"):
+        opsaxis.materialize(arrs, k=8, hints="trusted")
 
 
 # -- mesh-size edge cases --------------------------------------------------
@@ -176,29 +250,40 @@ def test_sharded_prefix_sums_bit_identical():
 
 # -- crowding pre-pass hints (ISSUE 13 satellite) --------------------------
 
-def test_crowding_hinted_leg_bit_identical_to_counted(monkeypatch):
+@pytest.mark.parametrize("k", [None, 8], ids=["stock", "opsaxis8"])
+def test_crowding_hinted_leg_bit_identical_to_counted(k, monkeypatch):
     """The host-derived crowd columns must reproduce the device
     counting trio exactly — both legs pinned on a crowded shape (16
-    chain heads under the root) and a contested interleave.  GRAFT_S_CAP
-    is forced below M so the compacted sibling branch — the ONLY place
-    the crowd columns are live (merge.crowding_hinted gate) — actually
-    compiles at these test sizes (at the default 64k cap both legs
-    would trace identically and the comparison would be vacuous)."""
+    chain heads under the root) and a contested interleave, in the
+    stock kernel and in the sharded body (where the counted leg is the
+    ``frame_add`` psum + ring cumsum).  Dropping the crowd columns
+    forces the counted leg.  GRAFT_S_CAP is forced below M so the
+    compacted sibling branch — the ONLY place the crowd columns are
+    live (merge.crowding_hinted gate) — actually compiles at these
+    test sizes (at the default 64k cap both legs would trace
+    identically and the comparison would be vacuous); the cap is read
+    at trace time, so the caches are cleared around it."""
+    def run(arrs):
+        if k is None:
+            return merge.materialize(arrs, hints="exhaustive")
+        return opsaxis.materialize(arrs, k=k, hints="exhaustive")
+
     monkeypatch.setenv("GRAFT_S_CAP", "512")
     for arrs in (workloads.chain_workload(16, 8192),
                  packed.pack(
                      workloads.two_replica_interleaved(2000)).arrays()):
         assert "crowd_slot" in arrs
         no_del = not np.any(arrs["kind"] == packed.KIND_DELETE)
+        counted = {c: v for c, v in arrs.items()
+                   if c not in ("crowd_slot", "crowd_cpos")}
         try:
             jax.clear_caches()
             assert merge.crowding_hinted(arrs, "exhaustive", no_del)
-            want = merge.materialize(arrs, hints="exhaustive")
-            monkeypatch.setenv("GRAFT_CROWD_HINTS", "0")
-            jax.clear_caches()
-            base = merge.materialize(arrs, hints="exhaustive")
+            assert not merge.crowding_hinted(counted, "exhaustive",
+                                             no_del)
+            want = run(arrs)
+            base = run(counted)
         finally:
-            monkeypatch.delenv("GRAFT_CROWD_HINTS", raising=False)
             jax.clear_caches()
         for f in FIELDS:
             np.testing.assert_array_equal(
@@ -324,27 +409,6 @@ def test_bench_opsaxis_headline_reduced(tmp_path):
     assert out["opsaxis_audit"]["ok"]
     assert out["opsaxis_audit"]["leg"] == "hinted"
     assert out["tripwire"]["ok"], out["p50_ms"]
-
-
-# -- staged pallas ring-carry kernel ---------------------------------------
-
-def test_pallas_ring_carry_interpret():
-    """The make_async_remote_copy ring variant of the carry exchange,
-    through the TPU interpreter (remote DMAs, barrier semaphore) on
-    the 8-device CPU mesh."""
-    from jax.sharding import Mesh
-    mesh = Mesh(np.asarray(jax.devices()[:8]), (opsaxis.AXIS,))
-    vals = np.arange(8, dtype=np.int32) + 1
-
-    def body(v):
-        return tour_scan.ring_exclusive_pallas(v, 8, interpret=True)
-
-    fn = jax.shard_map(
-        body, mesh=mesh, in_specs=(P(opsaxis.AXIS),),
-        out_specs=P(opsaxis.AXIS), check_vma=False)
-    got = np.asarray(jax.jit(fn)(jnp.asarray(vals)))
-    want = np.concatenate([[0], np.cumsum(vals)[:-1]])
-    np.testing.assert_array_equal(got, want)
 
 
 # -- the route's counters and span through the serving engine --------------

@@ -1,7 +1,10 @@
 """Sharded-merge parity: the multi-chip paths must produce bit-identical
 tables to the single-device kernel, on the simulated 8-device CPU mesh
 (conftest.py sets XLA_FLAGS=--xla_force_host_platform_device_count=8)."""
+import os
 import random
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -12,6 +15,7 @@ import crdt_graph_tpu as crdt
 from crdt_graph_tpu.codec import packed
 from crdt_graph_tpu.ops import merge, view
 from crdt_graph_tpu.parallel import mesh as mesh_mod
+from crdt_graph_tpu.parallel import opsaxis
 
 from test_merge_kernel import _random_session
 
@@ -25,8 +29,7 @@ def session_ops():
 def test_eight_way_ops_sharding(session_ops):
     want, ops = session_ops
     p = packed.pack(ops)
-    m = mesh_mod.make_mesh(n_docs=1, n_ops=8)
-    t = view.to_host(mesh_mod.sharded_materialize(p.arrays(), m))
+    t = view.to_host(opsaxis.materialize(p.arrays(), k=8))
     ref = view.to_host(merge.materialize(p.arrays()))
     assert view.visible_values(t, p.values) == want
     for field in ("ts", "parent", "doc_index", "order", "visible_order",
@@ -88,3 +91,19 @@ def test_uneven_doc_axis_rejected():
     m = mesh_mod.make_mesh(n_docs=8, n_ops=1)
     with pytest.raises(ValueError):
         mesh_mod.batched_materialize(stacked, m)
+
+
+def test_graft_entry_dryrun_multichip_8():
+    """``__graft_entry__.dryrun_multichip(8)`` end to end in a fresh
+    process (it pins its own 8-device CPU platform): the docs × ops
+    batched merge and one document over the 8-wide ops axis, each
+    equal to the single-device kernel in every table field."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import __graft_entry__ as g; g.dryrun_multichip(8); "
+         "print('dryrun ok')"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "dryrun ok" in out.stdout

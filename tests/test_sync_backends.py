@@ -168,6 +168,10 @@ def _crash_once(ddir, backend, site, monkeypatch):
     out = (tuple(sorted(v for v in vals if v in acked_vals)),
            doc2.epoch)
     eng2.close()
+    # the crashed engine's other lanes (WAL sync, maintenance, reactor)
+    # are still running: stop them, or every later test in this process
+    # inherits their threads
+    eng.close(timeout=5.0)
     return out
 
 

@@ -1,7 +1,9 @@
 """The pallas kernels of the merge path, compiled for a described v5e at
-1M rows (on-chip-measurement guide §2): what Mosaic would refuse on the
-chip fails here, at no chip time.  Nothing runs; each test asserts the
-kernel's ``tpu_custom_call`` in the compiled text.
+1M rows (ahead-of-time, with no chip attached), and the ops-axis sharded body
+compiled for a described v5e:2x2 mesh: what the TPU compiler would
+refuse on the chip fails here, at no chip time.  Nothing runs; each
+kernel test asserts the kernel's ``tpu_custom_call`` in the compiled
+text, the mesh test the collectives of the sharded body.
 
 The topology is described inside a module-scoped fixture, never at
 import: one process at a time may load the TPU library, and under
@@ -115,15 +117,29 @@ def test_prefix_sums_compiles(one_chip, x64):
     _assert_kernel(text, tour_scan.KERNEL_NAME)
 
 
-@pytest.mark.parametrize("x64", [False, True])
-def test_ring_exclusive_pallas_compiles_on_2x2_mesh(topo, x64):
+# the four-chip cell's executable: the ops-axis shard_map body over a
+# 4-wide mesh at a small op count (its structure does not depend on M)
+OPSAXIS_OPS = 1 << 12
+
+
+@pytest.mark.parametrize("no_deletes", [True, False])
+def test_opsaxis_body_compiles_on_2x2_mesh(topo, no_deletes):
+    from crdt_graph_tpu.bench import workloads
+    from crdt_graph_tpu.parallel import opsaxis
     k = 4
-    mesh = Mesh(np.asarray(topo.devices).reshape(k), ("ops",))
-    fn = jax.shard_map(
-        lambda v: tour_scan.ring_exclusive_pallas(v, k), mesh=mesh,
-        in_specs=(P("ops"),), out_specs=P("ops"), check_vma=False)
-    vals = jax.ShapeDtypeStruct((k * 8,), jnp.int32,
-                                sharding=NamedSharding(mesh, P("ops")))
-    with jax.enable_x64(x64):
-        text = _compiled_text(fn, vals)
-    _assert_kernel(text, tour_scan.RING_KERNEL_NAME)
+    mesh = Mesh(np.asarray(topo.devices).reshape(k), (opsaxis.AXIS,))
+    arrs = workloads.chain_workload(16, OPSAXIS_OPS) if no_deletes \
+        else workloads.chain_with_deletes(OPSAXIS_OPS, 8)
+    n = -(-arrs["kind"].shape[0] // k) * k
+    cols = opsaxis._cols_of(arrs)
+    shapes = {
+        c: jax.ShapeDtypeStruct(
+            (n,) + arrs[c].shape[1:], arrs[c].dtype,
+            sharding=NamedSharding(mesh, P(opsaxis.AXIS) if arrs[c].ndim
+                                   == 1 else P(opsaxis.AXIS, None)))
+        for c in cols}
+    with jax.enable_x64(True):
+        text = opsaxis._opsaxis_materialize.lower(
+            shapes, mesh, cols, "exhaustive", no_deletes
+        ).compile().as_text()
+    assert "all-gather" in text and "collective-permute" in text
