@@ -13,7 +13,9 @@ paths share that state, split by delta size:
   (Internal/Node.elm:51-104) on mutable slot arrays, so a 1-op remote
   delta on an n-op document costs O(delta), not a full re-merge.  After a
   kernel merge the mirror is rebuilt from the NodeTable in one vectorised
-  pass; host applies in turn mark the device view stale.
+  pass, on the first host-path edit or node-API read only (the visible
+  sequence reads straight from the table); host applies in turn mark the
+  device view stale.
 
 API parity: method names and semantics mirror the oracle ``CRDTree``
 (core/tree.py) — local edits stamp ``replica_id * 2**32 + counter``
@@ -32,7 +34,10 @@ generation, so views crossing it fail loudly (StaleNodeView).
 from __future__ import annotations
 
 import os
-from typing import Any, Callable, Iterable, List, Optional, Sequence, Tuple
+import threading
+import weakref
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, \
+    Tuple
 
 import numpy as np
 
@@ -52,6 +57,59 @@ from .utils import profiling
 # deltas at or under this many leaves apply host-side in O(delta); larger
 # ones re-materialise through the batched kernel
 DELTA_THRESHOLD = 256
+
+
+# the materialized view's process-wide tally, shown among every serving
+# engine's counters (serve/engine.py; docs/OBSERVABILITY.md)
+_VIEW_STATS = {"visible_from_table": 0, "mirror_builds": 0}
+_VIEW_STATS_LOCK = threading.Lock()
+
+
+def _count_view(name: str) -> None:
+    with _VIEW_STATS_LOCK:
+        _VIEW_STATS[name] += 1
+
+
+def view_counts() -> Dict[str, int]:
+    """``visible_from_table``: visible sequences read straight from a
+    kernel table; ``mirror_builds``: host mirror (re)builds."""
+    with _VIEW_STATS_LOCK:
+        return dict(_VIEW_STATS)
+
+
+# Device tables a visible read left parked, by id: each has its host copy
+# in flight, and the next kernel merge in this process swaps it into its
+# document before it launches, so a document nobody touches again holds
+# no device memory.  Every swap of a tree's ``_table`` takes
+# ``_PARK_LOCK``: the swap may run on another thread than the tree's
+# writer, and must never put back a table a newer commit replaced.
+_PUBLISHED: Dict[int, Tuple["weakref.ref[TpuTree]", NodeTable]] = {}
+_PARK_LOCK = threading.Lock()
+
+
+def _publish_device_table(tree: "TpuTree", table: NodeTable) -> None:
+    """Start ``table``'s host copy and queue its swap into ``tree``."""
+    import jax
+    if id(table) in _PUBLISHED:
+        return
+    for col in jax.tree.leaves(table):
+        col.copy_to_host_async()
+    with _PARK_LOCK:
+        _PUBLISHED[id(table)] = (weakref.ref(tree), table)
+
+
+def release_published() -> None:
+    """Swap every queued device table for its host copy in the document
+    that still holds it, and drop the rest (their documents committed
+    again or are gone).  Called before each kernel merge, so the merge
+    gets the device memory they held."""
+    with _PARK_LOCK:
+        queued = list(_PUBLISHED.values())
+        _PUBLISHED.clear()
+    for ref, table in queued:
+        tree = ref()
+        if tree is not None and tree._table is table:
+            tree._swap_table(table, view_mod.to_host(table))
 
 
 class MatzWarning(UserWarning):
@@ -79,6 +137,7 @@ def _routed_materialize(arrays, hints):
     attribution, fingerprints, and sync windows ride through
     unchanged (pinned by tests/test_opsaxis.py)."""
     from .parallel import opsaxis
+    release_published()
     return opsaxis.routed_materialize(arrays, hints)
 
 
@@ -338,6 +397,9 @@ class TpuTree:
         self._last_operation: Operation = Batch(())
         self._max_depth = max_depth
         self._table: Optional[NodeTable] = None
+        # the value list ``_table.value_ref`` indexes (the packing it was
+        # merged from; a spill may drop ``_packed`` while the table stays)
+        self._table_values: Optional[List[Any]] = None
         self._packed: Optional[PackedOps] = None
         self._mirror: Optional[HostTree] = None
         self._batch_depth = 0
@@ -402,22 +464,48 @@ class TpuTree:
 
     # -- the materialised view -------------------------------------------
 
+    def _view_table(self) -> Tuple[NodeTable, List[Any]]:
+        """The materialized view and the value list its ``value_ref``
+        indexes, left where it lives: a parked kernel table as it is
+        (device, sharded or host), else one kernel merge of the log,
+        parked on the device."""
+        if self._table is None:
+            p = self._ensure_packed()
+            self._park(_routed_materialize(p.arrays(), hints=_mode(p)),
+                       p.values)
+        return self._table, self._table_values
+
+    def _park(self, table: Optional[NodeTable],
+              values: Optional[List[Any]]) -> None:
+        with _PARK_LOCK:
+            self._table, self._table_values = table, values
+
+    def _swap_table(self, table: NodeTable, host: NodeTable) -> None:
+        """Put ``host``, the host copy of ``table``, in its place, unless
+        a commit replaced ``table`` meanwhile."""
+        with _PARK_LOCK:
+            if self._table is table:
+                self._table = host
+
     def table(self) -> NodeTable:
         """The converged node table (host numpy); re-materialised lazily
         through the batched kernel from the op log.
 
         A bulk ingest (apply_packed/_apply_kernel) parks the DEVICE
-        table here after reading back only the status column — the full
-        ~15-column host copy (~0.7 s at 1M ops) is paid on first READ of
-        the document, not on the serving ingest path; the conversion
-        then caches."""
-        if self._table is None:
-            p = self._ensure_packed()
-            self._table = _routed_materialize(p.arrays(),
-                                              hints=_mode(p))
-        if not isinstance(self._table.status, np.ndarray):
-            self._table = view_mod.to_host(self._table)
-        return self._table
+        table here after reading back only the status column, and the
+        publish reads the visible sequence from it without converting
+        it (:meth:`visible_values`).  That read starts the full
+        ~15-column host copy in the background, and the next kernel
+        merge in the process swaps it in (:func:`release_published`).
+        Until then, the copy is paid here, on the first host-path edit
+        or node-API read of a kernel-merged document, when the host
+        mirror is built from it; the conversion then caches."""
+        table, _ = self._view_table()
+        if not isinstance(table.status, np.ndarray):
+            host = view_mod.to_host(table)
+            self._swap_table(table, host)
+            table = host
+        return table
 
     def _ensure_mirror(self) -> HostTree:
         """The host mirror, built lazily: from a persisted
@@ -425,39 +513,50 @@ class TpuTree:
         left one pending (O(tail since artifact) — the cold-path
         collapse), from an existing table when one is materialised,
         through the kernel for big logs, by sequential replay for
-        small ones.  A rebuild is the ``engine.mirror`` span."""
+        small ones.  A rebuild is the ``engine.mirror`` span and, for a
+        document with ops, counts in ``mirror_builds``."""
         if self._mirror is None:
             with profiling.span("engine.mirror", ops=len(self._log)):
                 self._mirror = self._build_mirror()
+            if len(self._log):
+                _count_view("mirror_builds")
         return self._mirror
 
+    def _mirror_from_table(self) -> bool:
+        """True when :meth:`_build_mirror` would build from the kernel
+        table: one is parked, or the log is past DELTA_THRESHOLD with no
+        pending materialization artifact to load instead."""
+        return self._table is not None or (
+            not self._matz_pending and len(self._log) > DELTA_THRESHOLD)
+
     def _build_mirror(self) -> HostTree:
-        if self._matz_pending and self._table is None:
-            m = self._load_matz_mirror()
-            if m is not None:
+        if not self._mirror_from_table():
+            if self._matz_pending:
+                m = self._load_matz_mirror()
+                if m is not None:
+                    return m
+            if len(self._log) <= DELTA_THRESHOLD:
+                m = HostTree(self._max_depth)
+                for op in self._log:
+                    if isinstance(op, Add):
+                        m.apply_add(op.ts, tuple(op.path), op.value)
+                    else:
+                        m.apply_delete(tuple(op.path))
+                m.journal.clear()
                 return m
-        if self._table is None and len(self._log) <= DELTA_THRESHOLD:
-            m = HostTree(self._max_depth)
-            for op in self._log:
-                if isinstance(op, Add):
-                    m.apply_add(op.ts, tuple(op.path), op.value)
-                else:
-                    m.apply_delete(tuple(op.path))
-            m.journal.clear()
-            return m
-        return HostTree.from_table(self.table(),
-                                   self._ensure_packed().values,
+        table = self.table()
+        return HostTree.from_table(table, self._table_values,
                                    self._max_depth)
 
     def _stale_device(self) -> None:
         """Host-path edit: device view no longer matches the log; mirror
         (and outstanding views) stay valid."""
-        self._table = None
+        self._park(None, None)
         self._packed = None
 
     def _invalidate(self) -> None:
         """Full invalidation: slots will be reassigned — views go stale."""
-        self._table = None
+        self._park(None, None)
         self._packed = None
         self._mirror = None
         self._generation += 1
@@ -704,9 +803,12 @@ class TpuTree:
         it themselves (possibly batched with other documents —
         parallel.mesh.batched_materialize) and hand the table to
         :meth:`finish_packed`, or just call :meth:`apply_packed`.  The
-        ``engine.prepare`` span."""
+        ``engine.prepare`` span, which first swaps the tables earlier
+        visible reads left on the device for their host copies
+        (:func:`release_published`)."""
         with profiling.span("engine.prepare",
                             rows=len(self._log) + pnew.num_ops):
+            release_published()
             p = packed_mod.concat(self._ensure_packed(), pnew)
             p.arrays()
         return p
@@ -826,7 +928,8 @@ class TpuTree:
         arrays but not the log, so value_ref indices would skew —
         re-materialise from the log on next read."""
         if all_applied:
-            self._table, self._packed = table, p
+            self._park(table, p.values)
+            self._packed = p
             self._mirror = None
             self._generation += 1
         else:
@@ -1024,10 +1127,24 @@ class TpuTree:
         return self._ensure_packed()
 
     def visible_values(self) -> List[Any]:
-        """Visible values in document order — the render path.  A
-        mirror freshly loaded from a materialization artifact answers
-        from its persisted visible sequence (one list copy) until the
-        first applied mutation invalidates it."""
+        """Visible values in document order — the render path.  With no
+        host mirror, a document whose mirror would be built from the
+        kernel table answers from the table itself: the visible slots'
+        value refs read back (``engine.visible`` span, counted in
+        ``visible_from_table``), and no mirror is built.  A table on the
+        device stays there, its host copy started, until the next kernel
+        merge swaps the copy in (:func:`release_published`).  A mirror
+        freshly loaded from a materialization artifact answers from its
+        persisted visible sequence (one list copy) until the first
+        applied mutation invalidates it; any other mirror by a walk."""
+        if self._mirror is None and self._mirror_from_table():
+            with profiling.span("engine.visible", ops=len(self._log)):
+                table, values = self._view_table()
+                out = view_mod.visible_values(table, values)
+                if not isinstance(table.status, np.ndarray):
+                    _publish_device_table(self, table)
+            _count_view("visible_from_table")
+            return out
         m = self._ensure_mirror()
         if m.vis_cache is not None:
             return list(m.vis_cache)
@@ -1429,17 +1546,35 @@ class TpuTree:
 
     # -- persisted materialization (docs/DURABILITY.md §Cold paths) -------
 
-    def _matz_mirror_cheap(self) -> Optional[HostTree]:
-        """The mirror IF it is derivable without a full-history merge:
-        already built, rebuildable from a parked table, loadable from
-        a pending artifact, or a small log.  None otherwise — a matz
-        write must never INTRODUCE the cold-path cost it exists to
-        remove."""
-        if self._mirror is not None or self._table is not None \
-                or self._matz_pending \
-                or len(self._log) <= DELTA_THRESHOLD:
-            return self._ensure_mirror()
-        return None
+    def _matz_cheap(self) -> bool:
+        """True when the materialization artifact is derivable without
+        a full-history merge: a mirror or a parked kernel table is in
+        hand, an artifact is pending, or the log is small.  A matz write
+        must never INTRODUCE the cold-path cost it exists to remove."""
+        return self._mirror is not None or self._table is not None \
+            or self._matz_pending or len(self._log) <= DELTA_THRESHOLD
+
+    def _matz_export(self, copy: bool = False) -> Optional[dict]:
+        """The materialization artifact's slot arrays (``arrs``), value
+        list and counts, or None unless :meth:`_matz_cheap`.  With no
+        mirror and a parked kernel table, straight from the table
+        (:meth:`HostTree.table_slots`; no mirror is built, the path
+        plane is not read); otherwise from the mirror, built if need
+        be.  ``copy`` snapshots the mirror's live arrays (the table's
+        are fresh)."""
+        if not self._matz_cheap():
+            return None
+        if self._mirror is None and self._table is not None:
+            table, values = self._table, self._table_values
+            arrs, idx = HostTree.table_slots(table)
+            n, order, refs = (np.asarray(c) for c in (
+                table.num_visible, table.visible_order, table.value_ref))
+            arrs["vis_refs"] = refs[order[:int(n)]].astype(np.int32)
+            return {"arrs": arrs, "values": values, "n": idx.size + 1,
+                    "nvis": int(n)}
+        m = self._ensure_mirror()
+        return {"arrs": m.export_arrays(copy=copy), "values": m.values,
+                "n": m.n, "nvis": m.nvis}
 
     def _save_matz_npz(self, target: str, name: str, arrs: dict,
                        values: list, meta: dict, fsync: bool) -> None:
@@ -1466,19 +1601,19 @@ class TpuTree:
                          fsync: bool = False) -> Optional[dict]:
         """Write the materialization artifact (mirror slot arrays +
         values + visible sequence) into ``target`` and return its
-        manifest entry ``{"file", "len"}``, or None when no mirror is
-        cheaply derivable."""
-        m = self._matz_mirror_cheap()
-        if m is None:
+        manifest entry ``{"file", "len"}``, or None when the artifact
+        is not cheaply derivable (:meth:`_matz_export`)."""
+        x = self._matz_export()
+        if x is None:
             return None
         length = len(self._log)
         name = self._log.next_matz_name() \
             if self._log.tiering_enabled else "matz-g1.npz"
-        meta = {"kind": "matz", "matz_len": length, "n": m.n,
-                "nvis": m.nvis, "max_depth": self._max_depth,
-                "values_len": len(m.values)}
-        self._save_matz_npz(target, name, m.export_arrays(),
-                            m.values, meta, fsync)
+        meta = {"kind": "matz", "matz_len": length, "n": x["n"],
+                "nvis": x["nvis"], "max_depth": self._max_depth,
+                "values_len": len(x["values"])}
+        self._save_matz_npz(target, name, x["arrs"], x["values"], meta,
+                            fsync)
         return {"file": name, "len": length}
 
     def matz_snapshot(self) -> Optional[dict]:
@@ -1490,20 +1625,19 @@ class TpuTree:
         O(doc-state) artifact while this thread keeps applying ops to
         the live mirror.  The copies are flat memcpys + one pointer
         copy of the value table (values are immutable JSON leaves) —
-        milliseconds where the serialize is seconds.  None when no
-        mirror is cheaply derivable (never introduces the cost it
-        removes)."""
+        milliseconds where the serialize is seconds.  After a kernel
+        commit the arrays come from the parked table, with no mirror
+        built.  None when the artifact is not cheaply derivable (never
+        introduces the cost it removes)."""
         log = self._log
-        if not matz_enabled() or not log.tiering_enabled:
-            return None
-        m = self._matz_mirror_cheap()
-        if m is None:
+        if not matz_enabled() or not log.tiering_enabled \
+                or not self._matz_cheap():
             return None
         log.spill_all()
-        arrs = m.export_arrays(copy=True)
-        return {"arrs": arrs, "values": list(m.values), "n": m.n,
-                "nvis": m.nvis, "len": len(log),
-                "values_len": len(m.values)}
+        x = self._matz_export(copy=True)
+        return {"arrs": x["arrs"], "values": list(x["values"]),
+                "n": x["n"], "nvis": x["nvis"], "len": len(log),
+                "values_len": len(x["values"])}
 
     def export_matz(self, snap: dict) -> bool:
         """The worker-thread half: serialize a :meth:`matz_snapshot`
@@ -1543,12 +1677,12 @@ class TpuTree:
         an unsynced WAL tail that might not have survived), write the
         artifact, and publish it atomically in the manifest.  Returns
         True when an artifact landed.  Requires tiering; no-op when
-        the mirror is not cheaply derivable or ``GRAFT_MATZ=0``."""
+        the artifact is not cheaply derivable or ``GRAFT_MATZ=0``."""
         from .wal import maybe_crash
         log = self._log
         if not matz_enabled() or not log.tiering_enabled:
             return False
-        if self._matz_mirror_cheap() is None:
+        if not self._matz_cheap():
             return False
         log.spill_all()
         cfg = log._cfg

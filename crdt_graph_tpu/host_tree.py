@@ -90,15 +90,19 @@ class HostTree:
 
     # -- construction ----------------------------------------------------
 
-    @classmethod
-    def from_table(cls, table, values, max_depth: int) -> "HostTree":
-        """Vectorised rebuild from a kernel ``NodeTable`` (host numpy).
+    @staticmethod
+    def table_slots(table) -> Tuple[dict, np.ndarray]:
+        """The slot arrays :meth:`from_table` builds from a kernel
+        ``NodeTable``, over slots 0..k (slot 0 the root), with the table
+        slot of each host slot 1..k.
 
         Existing nodes (tombstones and dead-subtree members included — the
-        traversals below skip them exactly like the kernel's masks do) are
-        compacted into slots 1..n in document order; sibling linked lists
-        come from one (parent, doc_index) lexsort.
-        """
+        traversals skip them exactly like the kernel's masks do) are
+        compacted into slots 1..k in document order; sibling linked lists
+        come from one (parent, doc_index) lexsort.  Reads the table's
+        columns but not its ``paths`` plane, so the materialization
+        artifact (engine.TpuTree.write_matz) takes these arrays as they
+        are, with no mirror built."""
         exists = np.asarray(table.exists)
         doc = np.asarray(table.doc_index)
         idx = np.nonzero(exists)[0]
@@ -106,33 +110,57 @@ class HostTree:
         # load-bearing, but keeps dumps readable and scans cache-friendly
         idx = idx[np.argsort(doc[idx], kind="stable")]
         k = idx.size
-        t = cls(max_depth, capacity=max(64, int(k * 2)))
-        t.n = k + 1
-        remap = np.zeros(np.asarray(table.ts).shape[0], np.int32)
+        remap = np.zeros(exists.shape[0], np.int32)
         remap[idx] = np.arange(1, k + 1, dtype=np.int32)
-        t.ts[1:k + 1] = np.asarray(table.ts)[idx]
-        t.parent[1:k + 1] = remap[np.asarray(table.parent)[idx]]
-        t.depth[1:k + 1] = np.asarray(table.depth)[idx]
-        t.value_ref[1:k + 1] = np.asarray(table.value_ref)[idx]
-        t.tomb[1:k + 1] = np.asarray(table.tombstone)[idx]
-        # the kernel table's path plane is depth-bucketed (codec.packed);
-        # widen into the mirror's full-width zero-padded plane
-        tbl_paths = np.asarray(table.paths)
-        t.paths[1:k + 1, :tbl_paths.shape[1]] = tbl_paths[idx]
+
+        def column(dtype, root, rows):
+            out = np.empty(k + 1, dtype)
+            out[0] = root
+            out[1:] = rows
+            return out
+
+        arrs = {
+            "ts": column(np.int64, 0, np.asarray(table.ts)[idx]),
+            "parent": column(np.int32, ROOT,
+                             remap[np.asarray(table.parent)[idx]]),
+            "depth": column(np.int32, 0, np.asarray(table.depth)[idx]),
+            "value_ref": column(np.int32, -1,
+                                np.asarray(table.value_ref)[idx]),
+            "tomb": column(bool, False, np.asarray(table.tombstone)[idx]),
+            "first": np.full(k + 1, NIL, np.int32),
+            "nxt": np.full(k + 1, NIL, np.int32),
+            "prv": np.full(k + 1, NIL, np.int32),
+        }
         # sibling lists: group children by parent, doc order within group
-        hp = t.parent[1:k + 1]
+        hp = arrs["parent"][1:]
         order = np.lexsort((np.arange(k), hp))      # parent asc, doc asc
         slots = (order + 1).astype(np.int32)
         ps = hp[order]
         same = ps[1:] == ps[:-1]
         if k:
-            t.nxt[slots[:-1]] = np.where(same, slots[1:], NIL)
-            t.nxt[slots[-1]] = NIL
-            t.prv[slots[1:]] = np.where(same, slots[:-1], NIL)
-            t.prv[slots[0]] = NIL
+            nxt, prv = arrs["nxt"], arrs["prv"]
+            nxt[slots[:-1]] = np.where(same, slots[1:], NIL)
+            prv[slots[1:]] = np.where(same, slots[:-1], NIL)
             starts = np.concatenate([[True], ~same])
-            t.first[ps[starts]] = slots[starts]
-        t.ts2slot = dict(zip(t.ts[1:k + 1].tolist(), range(1, k + 1)))
+            arrs["first"][ps[starts]] = slots[starts]
+        return arrs, idx
+
+    @classmethod
+    def from_table(cls, table, values, max_depth: int) -> "HostTree":
+        """Vectorised rebuild from a kernel ``NodeTable`` (host numpy):
+        the slot arrays of :meth:`table_slots`, the path plane, and the
+        timestamp index."""
+        arrs, idx = cls.table_slots(table)
+        k = idx.size
+        t = cls(max_depth, capacity=max(64, int(k * 2)))
+        t.n = k + 1
+        for name, col in arrs.items():
+            getattr(t, name)[:k + 1] = col
+        # the kernel table's path plane is depth-bucketed (codec.packed);
+        # widen into the mirror's full-width zero-padded plane
+        tbl_paths = np.asarray(table.paths)
+        t.paths[1:k + 1, :tbl_paths.shape[1]] = tbl_paths[idx]
+        t.ts2slot = dict(zip(arrs["ts"][1:].tolist(), range(1, k + 1)))
         t.values = list(values)
         t.nvis = int(np.asarray(table.num_visible))
         return t

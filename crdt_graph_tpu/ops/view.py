@@ -30,9 +30,15 @@ def visible_slots(table: NodeTable) -> np.ndarray:
 
 def visible_values(table: NodeTable, values: Sequence[Any]) -> List[Any]:
     """Values of visible nodes in document order — the render path, matching
-    the oracle's ``CRDTree.visible_values``."""
-    refs = np.asarray(table.value_ref)
-    return [values[refs[s]] for s in visible_slots(table)]
+    the oracle's ``CRDTree.visible_values``.  Reads back only
+    ``num_visible``, ``visible_order`` and ``value_ref``, in one transfer,
+    wherever the table lives (one device, the ops-axis mesh, or the host);
+    nothing takes a shape from ``num_visible``, so nothing compiles."""
+    import jax
+    n, order, refs = jax.device_get(
+        (table.num_visible, table.visible_order, table.value_ref))
+    vis = np.asarray(refs)[np.asarray(order)[:int(n)]]
+    return list(map(values.__getitem__, vis.tolist()))
 
 
 def visible_paths(table: NodeTable) -> List[tuple]:

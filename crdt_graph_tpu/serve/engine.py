@@ -636,11 +636,12 @@ class ServedDoc:
         }
 
 
-def _route_counts() -> Dict[str, int]:
-    """The ops-axis route's process-wide tally (parallel/opsaxis.py),
-    shown among every engine's counters."""
+def _process_counts() -> Dict[str, int]:
+    """The process-wide tallies shown among every engine's counters:
+    the ops-axis route's (parallel/opsaxis.py) and the materialized
+    view's (``visible_from_table``, ``mirror_builds``; engine.py)."""
     from ..parallel import opsaxis
-    return opsaxis.route_counts()
+    return {**opsaxis.route_counts(), **engine_mod.view_counts()}
 
 
 class ServingEngine:
@@ -822,7 +823,7 @@ class ServingEngine:
         self.cross_doc = cross_doc
         self.wire_fast_bytes = wire_fast_bytes
         self.submit_timeout_s = submit_timeout_s
-        self.counters = Counters(_route_counts)
+        self.counters = Counters(_process_counts)
         # the flight recorder is process-wide by default (like the span
         # registry): every commit resolved by this engine lands one
         # record, and dumps trigger on SLO breach / audit failure /

@@ -101,7 +101,8 @@ class Histogram:
 class Counters:
     """A named bag of monotonically increasing integers (thread-safe).
     ``source``, when given, returns counts kept and counted elsewhere
-    (the ops-axis route's process-wide tally); :meth:`get` and
+    (process-wide tallies: the ops-axis route's, the materialized
+    view's); :meth:`get` and
     :meth:`snapshot` show them beside the bag's own."""
 
     def __init__(self,

@@ -462,9 +462,13 @@ def derive(doc_id: str, seq: int, tree: "engine_mod.TpuTree",
     """Build the next snapshot from a just-committed tree.  Called by
     the scheduler thread (the tree's only writer) BEFORE resolving the
     merged requests, so a client's follow-up read always sees its own
-    write.  ``visible_values`` is the pre-warm: it forces the host
-    mirror once here so no reader ever pays the first-read
-    materialization.  The log view capture is O(segments) — deriving a
+    write, and no reader ever pays the first-read materialization.
+    ``visible_values`` builds no host mirror here: after a kernel
+    commit it reads the visible sequence straight from the merged table
+    (``TpuTree.visible_values``), and the mirror is built from that
+    table by the document's first host-path edit or node-API read — the
+    cost is paid there, not saved.  The log
+    view capture is O(segments) — deriving a
     snapshot no longer re-packs the whole history on host-path commits,
     and never holds more of the log resident than the cascade already
     does."""

@@ -77,6 +77,41 @@ def test_mixed_deletes_bit_identical():
     assert_identical(arrs, hints="auto")
 
 
+@pytest.mark.parametrize("shape", ["chain_deletes", "nested"])
+def test_visible_values_from_sharded_table_equal_one_device(shape,
+                                                            monkeypatch):
+    """A kernel apply routed over the ops axis parks the sharded table,
+    and the published visible sequence read straight from it (no host
+    mirror) equals the one-device table's, its host copy's and the host
+    mirror's walk: tombstones included."""
+    from crdt_graph_tpu import engine
+    from crdt_graph_tpu.core.operation import Add
+    ops = (workloads.unpack_ops(workloads.chain_with_deletes(8192, 8))
+           if shape == "chain_deletes"
+           else workloads.nested_tree(6000, n_replicas=4))
+    monkeypatch.setenv("GRAFT_OPSAXIS_MIN_OPS", "1024")
+    out = {}
+    for route in ("1", "0"):
+        monkeypatch.setenv("GRAFT_OPSAXIS", route)
+        merges0 = opsaxis.stats()["merges"]
+        t = engine.init(7)
+        t.apply_packed(packed.pack(ops))
+        assert opsaxis.stats()["merges"] - merges0 == int(route)
+        c0 = engine.view_counts()["visible_from_table"]
+        out[route] = t.visible_values()
+        assert engine.view_counts()["visible_from_table"] == c0 + 1
+        assert t._mirror is None
+        engine.release_published()          # the next merge's first step
+        assert isinstance(t._table.status, np.ndarray)
+        assert t.visible_values() == out[route]     # the host copy
+        t._ensure_mirror()
+        assert t.visible_values() == out[route]     # the mirror's walk
+    assert out["1"] == out["0"]
+    n_adds = sum(isinstance(op, Add) for op in ops)
+    n_deletes = len(ops) - n_adds
+    assert len(out["1"]) == n_adds - n_deletes
+
+
 def test_chain_closed_form_through_opsaxis():
     """Not just self-consistency: the sharded result matches the
     closed-form expected visible sequence."""

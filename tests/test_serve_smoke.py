@@ -3,6 +3,7 @@ end-to-end gate (concurrent pushes + reads across documents over real
 HTTP, convergence, clean shutdown) runs fast and unmarked so every
 tier-1 pass exercises the scheduler."""
 import importlib.util
+import json
 import os
 
 import jax
@@ -70,3 +71,61 @@ def test_serve_smoke_end_to_end():
     # fires zero genuine retries
     assert summary["connpool"]["reuses"] > summary["connpool"]["opens"]
     assert summary["transport_retries"] == 0
+
+
+def test_kernel_post_publishes_from_table_until_first_small_delta():
+    """A kernel-size POST publishes a snapshot read straight from the
+    merged table: the served values equal the reference and no host
+    mirror is built; the first small delta to the same document builds
+    it, once, and its ack is read back."""
+    import threading
+    import urllib.request
+
+    import crdt_graph_tpu as crdt
+    from crdt_graph_tpu.codec import json_codec
+    from crdt_graph_tpu.service import make_server
+
+    srv = make_server(port=0)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    url = f"http://127.0.0.1:{srv.server_port}/docs/k"
+    counters = srv.store.counters
+
+    def post(ops):
+        body = json_codec.dumps(crdt.Batch(tuple(ops))).encode()
+        req = urllib.request.Request(url + "/ops", data=body,
+                                     method="POST")
+        with urllib.request.urlopen(req, timeout=120) as r:
+            assert r.status == 200
+
+    def values():
+        with urllib.request.urlopen(url, timeout=60) as r:
+            return json.loads(r.read())["values"]
+
+    def chain(rid, n, start=0):
+        ops, prev = [], start
+        for c in range(1, n + 1):
+            ts = rid * 2**32 + c
+            ops.append(crdt.Add(ts, (prev,), f"r{rid}.{c}"))
+            prev = ts
+        return ops
+
+    try:
+        bulk = chain(3, 700) + chain(5, 700)
+        ref = crdt.init(1).apply(crdt.Batch(tuple(bulk)))
+        c0 = counters.snapshot()
+        post(bulk)
+        assert values() == ref.visible_values()
+        c1 = counters.snapshot()
+        tree = srv.store.get("k").tree
+        assert tree._mirror is None
+        assert c1["visible_from_table"] - c0["visible_from_table"] >= 1
+        assert c1["mirror_builds"] == c0["mirror_builds"]
+        delta = chain(7, 3)
+        post(delta)
+        ref = ref.apply(crdt.Batch(tuple(delta)))
+        assert values() == ref.visible_values()
+        assert tree._mirror is not None
+        assert counters.get("mirror_builds") - c1["mirror_builds"] == 1
+    finally:
+        srv.shutdown()
+        srv.server_close()

@@ -697,6 +697,39 @@ def test_recovered_doc_first_read_from_matz_flight_and_prom(
     eng.close()
 
 
+def test_kernel_push_matz_export_builds_no_mirror(tmp_path, monkeypatch):
+    """A durable server's round-end artifact export after a kernel push
+    reads the slot arrays from the merged table: no host mirror is built
+    on the way, and a restarted engine's first read comes off that
+    artifact with the same values."""
+    monkeypatch.setenv("GRAFT_MATZ_TAIL_OPS", "64")
+    ops = chain_ops(1, 700) + chain_ops(2, 700)
+    ref = engine.init(0)
+    ref.apply(Batch(tuple(ops)))
+    ddir = tmp_path / "dur"
+    eng = _durable_engine(ddir)
+    c0 = engine.view_counts()
+    ok, _ = _submit(eng, "kdoc", ops)
+    assert ok
+    assert eng.flush(30)
+    doc = eng.get("kdoc")
+    assert doc.tree.matz_stats["writes"] >= 1
+    assert doc.tree._log.matz_entry is not None
+    assert doc.tree._mirror is None
+    c1 = engine.view_counts()
+    assert c1["mirror_builds"] == c0["mirror_builds"]
+    assert c1["visible_from_table"] > c0["visible_from_table"]
+    assert doc.snapshot() == ref.visible_values()
+    eng2 = _durable_engine(ddir)
+    doc2 = eng2.get("kdoc", create=False)
+    assert doc2 is not None and doc2.recovered
+    assert doc2.snapshot() == ref.visible_values()
+    assert doc2.tree.matz_stats["loads"] == 1, doc2.tree.matz_stats
+    assert doc2.tree.matz_stats["fallbacks"] == 0
+    eng2.close()
+    eng.close()
+
+
 # -- shared WAL stream (ISSUE 11) --------------------------------------------
 
 
