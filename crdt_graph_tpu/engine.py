@@ -59,20 +59,24 @@ from .utils import profiling
 DELTA_THRESHOLD = 256
 
 
-# the materialized view's process-wide tally, shown among every serving
-# engine's counters (serve/engine.py; docs/OBSERVABILITY.md)
-_VIEW_STATS = {"visible_from_table": 0, "mirror_builds": 0}
+# the materialized view's and the kernel commit's process-wide tally,
+# shown among every serving engine's counters (serve/engine.py;
+# docs/OBSERVABILITY.md)
+_VIEW_STATS = {"visible_from_table": 0, "mirror_builds": 0,
+               "kernel_deletes_applied": 0}
 _VIEW_STATS_LOCK = threading.Lock()
 
 
-def _count_view(name: str) -> None:
+def _count_view(name: str, n: int = 1) -> None:
     with _VIEW_STATS_LOCK:
-        _VIEW_STATS[name] += 1
+        _VIEW_STATS[name] += n
 
 
 def view_counts() -> Dict[str, int]:
     """``visible_from_table``: visible sequences read straight from a
-    kernel table; ``mirror_builds``: host mirror (re)builds."""
+    kernel table; ``mirror_builds``: host mirror (re)builds;
+    ``kernel_deletes_applied``: Delete rows a committed kernel merge
+    applied (:meth:`TpuTree.finish_packed`)."""
     with _VIEW_STATS_LOCK:
         return dict(_VIEW_STATS)
 
@@ -820,13 +824,16 @@ class TpuTree:
         materialized from :meth:`prepare_packed`'s candidate set.  Raises
         exactly what :meth:`apply` raises, leaving the replica untouched.
         The status readback, which waits for the device, is the
-        ``engine.sync`` span; the rest is ``engine.finish``."""
+        ``engine.sync`` span; the rest is ``engine.finish``, which also
+        counts the delta's Deletes the kernel applied."""
         n = pnew.num_ops
         n0 = len(self._log)
         with profiling.span("engine.sync", rows=p.num_ops):
             st = np.asarray(table.status)[n0:n0 + n]
         with profiling.span("engine.finish", rows=n):
             self._commit_statuses(pnew, p, table, st)
+            _count_view("kernel_deletes_applied", int(np.count_nonzero(
+                (pnew.kind[:n] == packed_mod.KIND_DELETE) & (st == APPLIED))))
         return self
 
     def _commit_statuses(self, pnew: PackedOps, p: PackedOps,

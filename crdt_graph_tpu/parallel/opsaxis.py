@@ -65,8 +65,9 @@ name, to every reader that looks for ``materialize``); placing its
 columns on the mesh is the ``opsaxis.place`` span.  The route keeps one
 process-wide tally, counted in :func:`routed_materialize`: the serving
 engine's counters show it as ``opsaxis_merges``, ``opsaxis_rows`` (the
-bucket rows of routed merges) and ``kernel_rows`` (the bucket rows of
-every single-document kernel merge, routed or not), and
+bucket rows of routed merges), ``kernel_rows`` (the bucket rows of
+every single-document kernel merge, routed or not) and
+``kernel_delete_rows`` (those of its rows that are Deletes), and
 :func:`stats` and the ``crdt_opsaxis_*`` families read the same counts.
 """
 from __future__ import annotations
@@ -337,7 +338,8 @@ def _opsaxis_materialize(device_ops, mesh: Mesh, cols, hints,
 
 
 _MESHES: Dict[int, Mesh] = {}
-_STATS = {"merges": 0, "devices": 0, "routed_ops": 0, "kernel_rows": 0}
+_STATS = {"merges": 0, "devices": 0, "routed_ops": 0, "kernel_rows": 0,
+          "kernel_delete_rows": 0}
 _STATS_LOCK = threading.Lock()
 
 
@@ -432,9 +434,12 @@ def routed_materialize(arrays: Dict[str, np.ndarray],
     table either way (pinned by tests/test_opsaxis.py through the
     serving path)."""
     n = int(arrays["kind"].shape[0])
+    n_del = int(np.count_nonzero(
+        np.asarray(arrays["kind"]) == packed_mod.KIND_DELETE))
     routed = enabled_for(n)
     with _STATS_LOCK:
         _STATS["kernel_rows"] += n
+        _STATS["kernel_delete_rows"] += n_del
         if routed:
             _STATS["merges"] += 1
             _STATS["routed_ops"] += n
@@ -449,7 +454,8 @@ def route_counts() -> Dict[str, int]:
     with _STATS_LOCK:
         return {"opsaxis_merges": _STATS["merges"],
                 "opsaxis_rows": _STATS["routed_ops"],
-                "kernel_rows": _STATS["kernel_rows"]}
+                "kernel_rows": _STATS["kernel_rows"],
+                "kernel_delete_rows": _STATS["kernel_delete_rows"]}
 
 
 def stats() -> dict:

@@ -638,8 +638,10 @@ class ServedDoc:
 
 def _process_counts() -> Dict[str, int]:
     """The process-wide tallies shown among every engine's counters:
-    the ops-axis route's (parallel/opsaxis.py) and the materialized
-    view's (``visible_from_table``, ``mirror_builds``; engine.py)."""
+    the kernel route's (``kernel_rows``, ``kernel_delete_rows`` and the
+    ``opsaxis_*`` counts; parallel/opsaxis.py) and the document
+    engine's (``visible_from_table``, ``mirror_builds``,
+    ``kernel_deletes_applied``; engine.py)."""
     from ..parallel import opsaxis
     return {**opsaxis.route_counts(), **engine_mod.view_counts()}
 
